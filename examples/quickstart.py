@@ -27,6 +27,7 @@ import numpy as np
 
 from repro import api
 from repro.api import HurryConfig, NetworkBuilder
+from repro.compile_cache import use_compile_cache
 from repro.models.cnn import make_crossbar_matmul
 
 
@@ -73,6 +74,7 @@ def main():
                              "custom"])
     ap.add_argument("--batch", type=int, default=2)
     args = ap.parse_args()
+    use_compile_cache()
 
     # one config for chip geometry, crossbar numerics, and the executor;
     # 511 rows keeps every ADC read clip-free (DESIGN.md §4) so the

@@ -3,7 +3,8 @@
 ``HurryConfig`` holds everything a user can turn: chip geometry (tiles,
 IMAs, array size — the simulator's knobs), crossbar numerics
 (quantization bit widths, ADC resolution, read noise — the functional
-model's knobs), and executor block sizes (the Pallas kernels' knobs).
+model's knobs), and executor block-size overrides (the Pallas kernels'
+knobs; by default each kernel picks its own per path).
 Every downstream structure is *derived* here and nowhere else:
 
   ``chip()``      -> ``core.simulator.ChipConfig``   (analytical model)
@@ -62,8 +63,9 @@ class HurryConfig:
     noise_sigma_shot: float = 0.0
 
     # -- executor (Pallas kernel block sizes) ------------------------------
-    block_m: int = 512
-    block_n: int = 512
+    # None keeps each kernel's per-path default (kernels/tiling.py)
+    block_m: int | None = None
+    block_n: int | None = None
 
     # -- derivations (the only place these conversions exist) --------------
 

@@ -135,29 +135,39 @@ class NetworkGraph:
         bitwise when both are jitted (DESIGN.md §5).  ``logits=True``
         returns the last GEMM output (pre-softmax).
         """
+        bufs = self.buffers(params, x, mm=mm)
+        gemms = [l.name for l in self.layers if l.kind in GEMM_KINDS]
+        return bufs[gemms[-1] if logits else self.layers[-1].name]
+
+    def buffers(self, params: dict, x: jnp.ndarray, *,
+                mm: Callable = fp_matmul) -> dict[str, jnp.ndarray]:
+        """Every layer's output, keyed by layer name (``"input"`` too).
+
+        The oracle's dataflow laid bare, for setting it beside another
+        implementation stage by stage.  An attention layer also records
+        its inner results as ``<layer>.qkv`` (fused projection,
+        ``(B, T, 3D)``), ``<layer>.probs`` (per batch*head softmax) and
+        ``<layer>.ctx`` (merged context).  Arguments as ``forward``.
+        """
         bufs: dict[str, jnp.ndarray] = {"input": x}
         cur = "input"
-        last_gemm = cur
         for l in self.layers:
             if l.kind == "conv":
                 src = bufs[l.input_from or cur]
                 p = params[l.name]
                 y = conv2d(src, p["w"], p["b"], l.stride, l.padding, mm)
-                last_gemm = l.name
             elif l.kind == "fc":
                 src = bufs[l.input_from or cur]
                 if src.ndim == 4:
                     src = src.reshape(src.shape[0], -1)
                 p = params[l.name]
                 y = mm(src, p["w"]) + p["b"]
-                last_gemm = l.name
             elif l.kind == "linear":
                 src = tokens(bufs[l.input_from or cur])
                 b, t, d = src.shape
                 p = params[l.name]
                 y = (mm(src.reshape(b * t, d), p["w"])
                      + p["b"]).reshape(b, t, -1)
-                last_gemm = l.name
             elif l.kind == "attention":
                 src = tokens(bufs[l.input_from or cur])
                 b, t, d = src.shape
@@ -170,7 +180,9 @@ class NetworkGraph:
                 ctx = merge_heads(jax.vmap(mm)(probs, v), l.heads)
                 y = (mm(ctx.reshape(b * t, d), p["wo"])
                      + p["bo"]).reshape(b, t, d)
-                last_gemm = l.name
+                bufs[f"{l.name}.qkv"] = qkv.reshape(b, t, 3 * d)
+                bufs[f"{l.name}.probs"] = probs
+                bufs[f"{l.name}.ctx"] = ctx
             elif l.kind == "relu":
                 y = jax.nn.relu(bufs[cur])
             elif l.kind == "gelu":
@@ -197,7 +209,7 @@ class NetworkGraph:
                 raise ValueError(f"{l.name}: unknown layer kind {l.kind!r}")
             bufs[l.name] = y
             cur = l.name
-        return bufs[last_gemm if logits else cur]
+        return bufs
 
     @classmethod
     def from_layers(cls, layers, name: str = "custom") -> "NetworkGraph":

@@ -1,6 +1,6 @@
 """Persist compiled models: ``CompiledModel.save`` / ``api.load``.
 
-Format (single ``.npz`` file, version 3):
+Format (single ``.npz`` file, version 4):
 
 * ``__meta__`` — a JSON document holding the graph (name, input spec,
   ``LayerSpec`` list), the ``HurryConfig``, the batch-bucket ladder,
@@ -12,8 +12,8 @@ Format (single ``.npz`` file, version 3):
   in the meta document (``[layer, key]`` pairs).
 * ``w0/wa0/wb0 .. `` — the **packed weight planes** (since version 2):
   per GEMM stage the int8 mount-plane matrix (pre-quantized, im2col
-  layout, K padded to full mounts), the f32 weight ``amax``, and the
-  f32 bias, in ``program.stages()`` order.  A loaded model serves from
+  layout, K in the mount layout of ``kernels.crossbar_gemm``), the f32
+  weight ``amax``, and the f32 bias, in ``program.stages()`` order.  A loaded model serves from
   these directly — ``api.load(...).run(...)`` never quantizes a weight
   (the analogue of shipping a programmed chip, not a netlist).
 * ``wg{i}/wh{i}`` — (version 3) the fused layer-norm FB's gamma/beta
@@ -35,9 +35,18 @@ rounds, quantization config, packed planes — round-trips exactly, so a
 loaded model's ``run`` is bit-identical to the in-memory one and a
 serving process never invokes the compiler or the packer.
 
+Version 4 changes only the planes' K layout: each mount's
+``tile_rows`` rows are followed by zero rows up to a multiple of 128
+(``mount_layout``), where versions 2-3 padded K once, at its end, to
+whole mounts.  It also stores ``block_m``/``block_n`` as ``None`` when
+the kernels pick their own tiles.
+
 Version-1 files (pre-packing) still load: the packed planes are
 re-derived once from the saved params at load time (repack fallback).
-Version-2 files load unchanged (no sequence fields, no ln stages).
+Version-2/3 files load without requantizing: their planes are re-laid
+into the mount layout (exact — both paddings are zero rows), and the
+old 512x512 block-size defaults they stored become ``None``.  Version-2
+files have no sequence fields and no ln stages.
 """
 
 from __future__ import annotations
@@ -49,8 +58,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.workload import LayerSpec
-from repro.program.compile import (GEMM_OPS, CrossbarProgram, MountRound,
-                                   ProgramOp)
+from repro.kernels.crossbar_gemm import mount_layout
+from repro.program.compile import CrossbarProgram, MountRound, ProgramOp
 from repro.program.pack import (PackedProgram, PackedStage, pack_program)
 from repro.program.serve import BUCKETS
 
@@ -58,8 +67,9 @@ from .config import HurryConfig
 from .graph import NetworkGraph
 
 FORMAT = "repro.api/compiled-model"
-VERSION = 3
-_LOADABLE = (1, 2, 3)
+VERSION = 4
+_LOADABLE = (1, 2, 3, 4)
+_OLD_BLOCK_DEFAULT = 512      # versions <= 3 stored it for "no override"
 
 
 def _program_meta(program: CrossbarProgram) -> dict:
@@ -90,6 +100,16 @@ def _program_from_meta(meta: dict) -> CrossbarProgram:
         output=meta["output"], logits=meta["logits"],
         in_hw=meta["in_hw"], in_ch=meta["in_ch"],
         in_features=meta["in_features"], in_seq=meta.get("in_seq", 0))
+
+
+def _relayout(w8: jnp.ndarray, op: ProgramOp) -> jnp.ndarray:
+    """A version 2/3 plane (K zero-padded at its end to whole
+    ``tile_rows`` mounts) in the mount layout: cut to the real K, then
+    lay out.  Exact, as the old padding was zero rows."""
+    if w8.size == 0:                      # dynamic-stage placeholder
+        return w8
+    k = max(r.k1 for r in op.mount_rounds)
+    return mount_layout(w8[:k], op.tile_rows, 0)
 
 
 def save_model(model, path: str) -> str:
@@ -153,19 +173,27 @@ def load_model(path: str):
                         ln_b=jnp.asarray(z[f"wh{i}"]) if i in ln else None)
             for i in range(meta.get("packed_stages", 0)))
     program = _program_from_meta(meta["program"])
+    config = dict(meta["config"])
+    if version < 4:
+        for key in ("block_m", "block_n"):
+            if config.get(key) == _OLD_BLOCK_DEFAULT:
+                config[key] = None
     if version == 1:   # pre-packing save: re-derive planes once, now
         packed = pack_program(program, params)
     else:
-        n_gemm = sum(1 for op in program.ops if op.kind in GEMM_OPS)
-        if len(stages) != n_gemm:
+        gemms = [gemm for gemm, _ in program.stages()]
+        if len(stages) != len(gemms):
             raise ValueError(f"{path}: corrupt file — {len(stages)} packed "
-                             f"weight planes for {n_gemm} GEMM stages")
+                             f"weight planes for {len(gemms)} GEMM stages")
+        if version < 4:
+            stages = tuple(dataclasses.replace(st, w8=_relayout(st.w8, op))
+                           for st, op in zip(stages, gemms))
         packed = PackedProgram(stages=stages, program=program)
     gm = meta["graph"]
     graph = NetworkGraph(
         name=gm["name"], in_hw=gm["in_hw"], in_ch=gm["in_ch"],
         in_features=gm["in_features"], in_seq=gm.get("in_seq", 0),
         layers=tuple(LayerSpec(**d) for d in gm["layers"]))
-    return CompiledModel(graph=graph, config=HurryConfig(**meta["config"]),
+    return CompiledModel(graph=graph, config=HurryConfig(**config),
                          program=program, params=params, packed=packed,
                          buckets=tuple(meta.get("buckets", BUCKETS)))

@@ -31,16 +31,33 @@ Two statically-dispatched compute paths (DESIGN.md §"Exact fast path"):
   ``clip_possible``).  When clipping *can* fire the fast path is
   refused and the sliced path runs.
 
-Grid: (M/bm, N/bn, K/rows) — K blocks are the "arrays"; both paths do a
-single MXU dispatch per tile.
+Grid: (M/bm, N/bn, n_mounts) — each K block is one mount (one
+"array" read); both paths do a single MXU dispatch per tile.
 
-Block activation is pad-to-block: operands whose M/N/K are not multiples
-of the (clamped) block sizes are zero-padded up to the next multiple,
-full-size tiles run, and the result is sliced back to (M, N).  Zero rows
-contribute zero bitline counts (digitized exactly: ``clip(0) == 0``) and
-padded output rows/columns are independent of the kept region, so the
-padding is slice-exact on both compute paths — callers with odd spatial
-dims never see a divisibility assert.
+**Mount layout.** A stage's ``tile_rows`` is the array height minus the
+rows its functional blocks reserve, so it is rarely a multiple of the
+TPU's 128-lane tiling (485, 493, ...), and Mosaic refuses a K block of
+that height.  Operands therefore carry K in the *mount layout*
+(``mount_layout``): each mount's ``rows`` real rows followed by zero
+rows up to ``mount_rows(rows)``, the next multiple of 128.  Zero rows
+add nothing to any bitline count (``clip(0) == 0``), so a K block of
+``mount_rows(rows)`` keeps per-mount ADC semantics over exactly ``rows``
+real rows.  A contraction that fits one mount (``K <= rows``) keeps its
+full length as the block, which Mosaic accepts at any size.
+``pack.plane_pack`` lays weights out this way once at compile time;
+``mounted_gemm`` takes such weights and lays out the streamed
+activation itself, and ``crossbar_gemm`` also lays out the weights for
+callers holding plain ``(K, N)`` operands.
+
+Block activation is pad-to-block: operands whose M/N are not multiples
+of the block sizes (clamped to M, N rounded up to the (8, 128) tiling)
+are zero-padded up to the next multiple,
+full-size tiles run, and the result is sliced back to (M, N).  Padded
+output rows/columns are independent of the kept region, so the padding
+is slice-exact on both compute paths — callers with odd spatial dims
+never see a divisibility assert.
+
+``block_m``/``block_n`` default per compute path (``tiling.py``).
 """
 
 from __future__ import annotations
@@ -51,6 +68,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .tiling import default_blocks
 
 def _plane_weights(shape, dim):
     """Two's-complement plane weights 2^i (MSB negative) along ``dim``.
@@ -145,45 +164,103 @@ def _kernel_exact(x_ref, w_ref, o_ref, acc_ref, *, n_k: int, f32_dot: bool):
         o_ref[...] = acc_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("adc_bits", "rows", "block_m",
-                                             "block_n", "interpret", "exact"))
+LANE = 128          # TPU lane width: K blocks are multiples of it
+
+
+def mount_rows(rows: int) -> int:
+    """Height of one mount in the mount layout: ``rows`` rounded up to
+    the 128-lane tiling (module docstring)."""
+    return -(-rows // LANE) * LANE
+
+
+def mount_layout(a: jnp.ndarray, rows: int, axis: int) -> jnp.ndarray:
+    """Lay ``a``'s contraction ``axis`` out as full mounts.
+
+    K is cut into ``ceil(K / rows)`` mounts of ``rows`` real rows (the
+    last zero-filled), and each mount is zero-padded to
+    ``mount_rows(rows)``.  A contraction that fits one mount is
+    returned unchanged: its block is the whole axis.
+    """
+    k = a.shape[axis]
+    if k <= rows:
+        return a
+    n, height = -(-k // rows), mount_rows(rows)
+    widths = [(0, 0)] * (a.ndim + 1)
+    widths[axis] = (0, n * rows - k)
+    a = jnp.pad(a, widths[:-1])
+    a = a.reshape(a.shape[:axis] + (n, rows) + a.shape[axis + 1:])
+    if height != rows:
+        widths[axis] = (0, 0)
+        widths[axis + 1] = (0, height - rows)
+        a = jnp.pad(a, widths)
+    return a.reshape(a.shape[:axis] + (n * height,) + a.shape[axis + 2:])
+
+
 def crossbar_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
-                  rows: int = 512, block_m: int = 128, block_n: int = 128,
-                  interpret: bool = False,
+                  rows: int = 512, block_m: int | None = None,
+                  block_n: int | None = None, interpret: bool = False,
                   exact: bool | None = None) -> jnp.ndarray:
     """(M, K) int8 x (K, N) int8 -> (M, N) int32 with HURRY semantics.
 
-    ``exact=None`` (default) auto-dispatches: the clip-free single-GEMM
-    fast path when ``rows <= 2^adc_bits - 1`` (bit-identical, see
-    ``clip_possible``), else the plane-packed sliced path.  ``exact=False``
-    forces the faithful sliced path; ``exact=True`` asserts clip-freeness
-    and raises if ADC saturation could fire.
+    ``mounted_gemm`` on the weights put into the mount layout here (its
+    docstring has the dispatch rules).
+    """
+    return mounted_gemm(x, mount_layout(w, rows, 0), adc_bits=adc_bits,
+                        rows=rows, block_m=block_m, block_n=block_n,
+                        interpret=interpret, exact=exact)
 
-    M, N, and K need not divide the (clamped) block sizes: operands are
+
+@functools.partial(jax.jit, static_argnames=("adc_bits", "rows", "block_m",
+                                             "block_n", "interpret", "exact"))
+def mounted_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
+                 rows: int = 512, block_m: int | None = None,
+                 block_n: int | None = None, interpret: bool = False,
+                 exact: bool | None = None) -> jnp.ndarray:
+    """(M, K) int8 activation x mounted int8 weights -> (M, N) int32.
+
+    ``w`` is already in the mount layout of ``rows``-row mounts
+    (``mount_layout``, as ``pack.plane_pack`` stores it); ``x`` is laid
+    out the same way here.  ``rows`` is the real row count of each mount
+    (the ADC chunk).  ``exact=None`` (default) auto-dispatches: the
+    clip-free single-GEMM fast path when ``rows <= 2^adc_bits - 1``
+    (bit-identical, see ``clip_possible``), else the plane-packed sliced
+    path.  ``exact=False`` forces the faithful sliced path;
+    ``exact=True`` asserts clip-freeness and raises if ADC saturation
+    could fire.  Block sizes default per path (``tiling.py``).
+
+    M and N need not divide the (clamped) block sizes: operands are
     zero-padded up to the block multiple, full tiles run, and the output
     is sliced back to (M, N) — slice-exact (see module docstring).
     """
     assert x.dtype == jnp.int8 and w.dtype == jnp.int8
     M, K = x.shape
-    Kw, N = w.shape
-    assert K == Kw
-    block_m = min(block_m, M)
-    block_n = min(block_n, N)
     rows = min(rows, K)
-    # pad-to-block activation: zero rows/cols are slice-exact (docstring)
-    pm, pn, pk = -M % block_m, -N % block_n, -K % rows
-    if pm or pk:
-        x = jnp.pad(x, ((0, pm), (0, pk)))
-    if pn or pk:
-        w = jnp.pad(w, ((0, pk), (0, pn)))
-    Mp, Np, Kp = M + pm, N + pn, K + pk
-    n_k = Kp // rows
+    x = mount_layout(x, rows, 1)
+    K = x.shape[1]
+    Kw, N = w.shape
+    if K != Kw:
+        raise ValueError(f"weights have {Kw} rows; the activation laid out "
+                         f"as {rows}-row mounts has {K} (see mount_layout)")
+    block_k = min(K, mount_rows(rows))
     if exact is None:
         exact = not clip_possible(rows, adc_bits)
     elif exact and clip_possible(rows, adc_bits):
         raise ValueError(
             f"exact=True but ADC clipping can fire: rows={rows} > "
             f"2^{adc_bits} - 1 = {(1 << adc_bits) - 1}; use the sliced path")
+    bm, bn = default_blocks("exact" if exact else "sliced")
+    # clamp to the operand rounded up to the (8, 128) tiling: the sliced
+    # path's plane reshapes need aligned tiles even for tiny M or N
+    block_m = min(block_m or bm, -(-M // 8) * 8)
+    block_n = min(block_n or bn, -(-N // LANE) * LANE)
+    # pad-to-block activation: zero rows/cols are slice-exact (docstring)
+    pm, pn = -M % block_m, -N % block_n
+    if pm:
+        x = jnp.pad(x, ((0, pm), (0, 0)))
+    if pn:
+        w = jnp.pad(w, ((0, 0), (0, pn)))
+    Mp, Np = M + pm, N + pn
+    n_k = K // block_k
     if exact:
         # f32 chunk dots are exact iff |partial| <= rows * 128^2 <= 2^24
         kernel = functools.partial(_kernel_exact, n_k=n_k,
@@ -195,8 +272,8 @@ def crossbar_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
         kernel,
         grid=(Mp // block_m, Np // block_n, n_k),
         in_specs=[
-            pl.BlockSpec((block_m, rows), lambda i, j, k: (i, k)),
-            pl.BlockSpec((rows, block_n), lambda i, j, k: (k, j)),
+            pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
+            pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),

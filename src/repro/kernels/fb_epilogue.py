@@ -33,15 +33,23 @@ the functional oracle (`api/graph.py::NetworkGraph.forward`) evaluates
 the *same expression tree* — bit-identical under jit (DESIGN.md §5).
 
 Pooling layout: rows of the (M, N) GEMM output are im2col vectors in
-(image, row, col) order, so one grid step owns one image's ``ih*ih`` rows
-and reduces ``window x window`` blocks via a leading-axis reshape — the
-column-parallel window tiling of Fig 5c.  Only ``stride == window``
-(non-overlapping) pooling is supported, which covers the paper's
-workloads (2x2/2 max pool, 4x4/4 global avg pool).  ``seqmean`` treats
-``window`` as the token count: one grid step owns one sequence's rows
-and mean-reduces them to a single output row.  Softmax and layer norm
-need the full feature axis in-tile, so ``block_n`` is forced to N in
-those modes.
+(image, row, col) order, so one grid step owns whole images' ``ih*ih``
+rows and reduces ``window x window`` blocks via a leading-axis reshape
+— the column-parallel window tiling of Fig 5c.  Only ``stride ==
+window`` (non-overlapping) pooling is supported, which covers the
+paper's workloads (2x2/2 max pool, 4x4/4 global avg pool).  ``seqmean``
+treats ``window`` as the token count: a grid step owns whole sequences'
+rows and mean-reduces each to a single output row.  A step takes the
+fewest images (sequences) whose output rows fill a multiple of 8
+sublanes (``_groups_per_step``; the TPU tiling refuses an output block
+of 1 or 4 rows), or all of them when there are fewer; a batch that is
+not a multiple of that count is padded with zero images (sequences).
+Softmax and layer norm need the full feature axis in-tile, so
+``block_n`` is forced to N in those modes.
+
+The per-column operands (bias, layer-norm gamma/beta) enter the kernel
+as ``(1, N)`` rows with ``(1, block_n)`` blocks: a 1-D block has no
+layout the TPU tiling and XLA agree on.
 
 Block activation is pad-to-block: when (M, N) do not divide the
 (clamped) block sizes, operands are zero-padded up to the block
@@ -49,18 +57,22 @@ multiple, full-size tiles run, and the result is sliced back — every
 row/column is processed independently by the FB chain, so the padding
 is slice-exact and callers never tune divisor blocks.  The structural
 constraints remain: pooling fixes M to ``B * img_hw^2`` (or ``B * T``
-for seqmean — rows are never padded there), and softmax / layer norm
-need the full feature axis in-tile (``block_n = N``, never padded).  On
-TPU proper, multiples of (8, 128) pick the fast path.
+for seqmean — rows are padded by whole images or sequences there), and
+softmax / layer norm need the full feature axis in-tile (``block_n =
+N``, never padded).  On TPU proper, multiples of (8, 128) pick the fast
+path.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .tiling import default_blocks
 
 _GELU_C = 0.7978845608028654          # sqrt(2/pi)
 LN_EPS = 1e-5
@@ -115,14 +127,14 @@ def _kernel(y_ref, scale_ref, b_ref, res_ref, g_ref, bt_ref, o_ref, *,
     if norm == "layer":
         y = layer_norm_rows(y, g_ref[...].astype(jnp.float32),
                             bt_ref[...].astype(jnp.float32))
-    if pool == "seqmean":
-        y = jnp.mean(y, axis=0, keepdims=True)
+    bn = y.shape[-1]
+    if pool == "seqmean":                # window = tokens per sequence
+        y = jnp.mean(y.reshape(-1, window, bn), axis=1)
     elif pool != "none":
         oh = img_hw // window
-        bn = y.shape[-1]
-        y = y.reshape(oh, window, oh, window, bn)
-        y = jnp.max(y, axis=(1, 3)) if pool == "max" else jnp.mean(y, axis=(1, 3))
-        y = y.reshape(oh * oh, bn)
+        y = y.reshape(-1, oh, window, oh, window, bn)
+        y = jnp.max(y, axis=(2, 4)) if pool == "max" else jnp.mean(y, axis=(2, 4))
+        y = y.reshape(-1, bn)
     if softmax:
         y = softmax_rows(y)
     o_ref[...] = y
@@ -138,12 +150,12 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
                 softmax: bool = False, norm: str = "none",
                 gamma: jnp.ndarray | None = None,
                 beta: jnp.ndarray | None = None, post_scale: float = 0.0,
-                block_m: int = 256, block_n: int = 128,
+                block_m: int | None = None, block_n: int | None = None,
                 interpret: bool = False) -> jnp.ndarray:
     """y (M, N) int32 crossbar output -> fused FB chain -> f32.
 
     ``scale`` is the (1, 1) f32 shift-and-add requant factor (input scale
-    x weight scale); ``bias`` is (N,).  ``act`` in {"none", "relu",
+    x weight scale); ``bias`` is (N,) (passed on as a (1, N) row).  ``act`` in {"none", "relu",
     "gelu"}; ``pool`` in {"none", "max", "avg", "seqmean"} — max/avg use
     ``window == stride`` over an ``img_hw x img_hw`` spatial grid per
     image (M = B * img_hw^2, output (B * (img_hw//window)^2, N));
@@ -153,7 +165,8 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     activation.  ``post_scale`` (static) multiplies the dequantized tile
     before the activation — attention scores fold `1/sqrt(hd)` here.
     ``softmax=True`` (exclusive with pool) normalizes over the full
-    feature axis -> (M, N).
+    feature axis -> (M, N).  Block sizes default to ``tiling.py``'s
+    epilogue entry.
     """
     M, N = y.shape
     assert scale.shape == (1, 1) and bias.shape == (N,)
@@ -163,46 +176,53 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     has_residual = residual is not None
     res = residual if has_residual else jnp.zeros((1, 1), jnp.float32)
     has_norm = norm == "layer"
+    bias = bias.reshape(1, N)
     if has_norm:
         assert gamma is not None and beta is not None
         assert gamma.shape == (N,) and beta.shape == (N,)
-    g = gamma if has_norm else jnp.zeros((1,), jnp.float32)
-    bt = beta if has_norm else jnp.zeros((1,), jnp.float32)
+        g, bt = gamma.reshape(1, N), beta.reshape(1, N)
+    else:
+        g = bt = jnp.zeros((1, 1), jnp.float32)
 
-    # pad-to-block activation (module docstring): pad rows unless pooling
-    # fixes the image/sequence structure, pad cols unless softmax or
+    dm, dn = default_blocks("epilogue")
+    block_m, block_n = block_m or dm, block_n or dn
+    # pad-to-block activation (module docstring): pad rows (by whole
+    # images or sequences when pooling), pad cols unless softmax or
     # layer norm span the full feature axis; run full tiles, slice back.
     if softmax or has_norm:
         block_n = N              # the row reduction needs every column
     block_n = min(block_n, N)
-    pm = 0 if pool != "none" else -M % min(block_m, M)
+    if pool == "seqmean":
+        assert not softmax, "pool and softmax FBs never chain directly"
+        assert window >= 1 and M % window == 0, (M, window)
+        group_rows, out_rows = window, 1
+    elif pool != "none":
+        assert not softmax, "pool and softmax FBs never chain directly"
+        assert window > 1 and img_hw % window == 0, (img_hw, window)
+        group_rows, out_rows = img_hw * img_hw, (img_hw // window) ** 2
+        assert M % group_rows == 0, (M, img_hw)
+    if pool != "none":           # pad by whole images / sequences
+        n_groups = M // group_rows
+        k = _groups_per_step(n_groups, out_rows)
+        pm = -n_groups % k * group_rows
+    else:
+        pm = -M % min(block_m, M)
     pn = -N % block_n
     if pm or pn:
         y = jnp.pad(y, ((0, pm), (0, pn)))
-        bias = jnp.pad(bias, (0, pn))
+        bias = jnp.pad(bias, ((0, 0), (0, pn)))
         if has_residual:
             res = jnp.pad(res, ((0, pm), (0, pn)))
     Mp, Np = M + pm, N + pn
 
-    if pool == "seqmean":
-        assert not softmax, "pool and softmax FBs never chain directly"
-        assert window >= 1 and M % window == 0, (M, window)
-        n_seq = M // window
-        grid = (n_seq, Np // block_n)
-        row_spec = pl.BlockSpec((window, block_n), lambda i, j: (i, j))
-        out_spec = pl.BlockSpec((1, block_n), lambda i, j: (i, j))
-        out_shape = jax.ShapeDtypeStruct((n_seq, Np), jnp.float32)
-    elif pool != "none":
-        assert not softmax, "pool and softmax FBs never chain directly"
-        assert window > 1 and img_hw % window == 0, (img_hw, window)
-        img_rows = img_hw * img_hw
-        assert M % img_rows == 0, (M, img_hw)
-        n_img = M // img_rows
-        oh = img_hw // window
-        grid = (n_img, Np // block_n)
-        row_spec = pl.BlockSpec((img_rows, block_n), lambda i, j: (i, j))
-        out_spec = pl.BlockSpec((oh * oh, block_n), lambda i, j: (i, j))
-        out_shape = jax.ShapeDtypeStruct((n_img * oh * oh, Np), jnp.float32)
+    if pool != "none":
+        n_steps = Mp // (k * group_rows)
+        grid = (n_steps, Np // block_n)
+        row_spec = pl.BlockSpec((k * group_rows, block_n),
+                                lambda i, j: (i, j))
+        out_spec = pl.BlockSpec((k * out_rows, block_n), lambda i, j: (i, j))
+        out_shape = jax.ShapeDtypeStruct((n_steps * k * out_rows, Np),
+                                         jnp.float32)
     else:
         block_m = min(block_m, Mp)
         grid = (Mp // block_m, Np // block_n)
@@ -210,10 +230,8 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         out_spec = row_spec
         out_shape = jax.ShapeDtypeStruct((Mp, Np), jnp.float32)
 
-    res_spec = (row_spec if has_residual
-                else pl.BlockSpec((1, 1), lambda i, j: (0, 0)))
-    col_spec = (pl.BlockSpec((block_n,), lambda i, j: (j,)) if has_norm
-                else pl.BlockSpec((1,), lambda i, j: (0,)))
+    one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
+    col_spec = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
     kernel = functools.partial(_kernel, act=act, pool=pool, window=window,
                                img_hw=img_hw, softmax=softmax, norm=norm,
                                post_scale=post_scale,
@@ -223,11 +241,11 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         grid=grid,
         in_specs=[
             row_spec,
-            pl.BlockSpec((1, 1), lambda i, j: (0, 0)),
-            pl.BlockSpec((block_n,), lambda i, j: (j,)),
-            res_spec,
+            one,
             col_spec,
-            col_spec,
+            row_spec if has_residual else one,
+            col_spec if has_norm else one,
+            col_spec if has_norm else one,
         ],
         out_specs=out_spec,
         out_shape=out_shape,
@@ -235,6 +253,17 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     )(y, scale, bias, res, g, bt)
     if pn:
         out = out[:, :N]
-    if pm:                       # never set in pool mode (out rows differ)
-        out = out[:M]
+    if pm:                       # drop the padded rows (images, sequences)
+        out = out[:M if pool == "none" else n_groups * out_rows]
     return out
+
+
+def _groups_per_step(n_groups: int, out_rows: int) -> int:
+    """Images (or sequences) per pooled grid step.
+
+    The fewest whose ``out_rows``-row outputs fill a multiple of 8
+    sublanes, or all ``n_groups`` when there are fewer (a block equal to
+    the whole array is always accepted).  The caller pads the batch to
+    a multiple of the result.
+    """
+    return min(8 // math.gcd(8, out_rows), n_groups)

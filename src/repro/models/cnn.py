@@ -75,15 +75,24 @@ def make_program_forward(net: str, cfg: Optional[CrossbarConfig] = None,
 # ---------------------------------------------------------------------------
 
 def im2col(x: jnp.ndarray, k: int, stride: int, pad: int) -> jnp.ndarray:
-    """NHWC -> (N, OH, OW, k*k*C) patches."""
+    """NHWC -> (N, OH, OW, C*k*k) patches, feature order (c, i, j).
+
+    Built from ``k*k`` strided slices — pure data movement, so every
+    patch entry is an exact copy of an input element on every backend
+    (a patches *convolution* at default precision rounds its f32 inputs
+    to bf16 on the TPU).  The one im2col of the repo: the compiled
+    program's executor imports it, so program and oracle trace the same
+    expression.
+    """
     n, h, w, c = x.shape
     xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
     oh = (h + 2 * pad - k) // stride + 1
     ow = (w + 2 * pad - k) // stride + 1
-    patches = jax.lax.conv_general_dilated_patches(
-        xp.transpose(0, 3, 1, 2), (k, k), (stride, stride), "VALID")
-    # (N, C*k*k, OH, OW) -> (N, OH, OW, C*k*k)
-    return patches.transpose(0, 2, 3, 1).reshape(n, oh, ow, c * k * k)
+    taps = [xp[:, i:i + stride * (oh - 1) + 1:stride,
+               j:j + stride * (ow - 1) + 1:stride, :]
+            for i in range(k) for j in range(k)]
+    # (N, OH, OW, C, k*k) -> (N, OH, OW, C*k*k)
+    return jnp.stack(taps, axis=-1).reshape(n, oh, ow, c * k * k)
 
 
 def conv2d(x: jnp.ndarray, w: jnp.ndarray, b: jnp.ndarray, stride: int,

@@ -7,13 +7,14 @@ scheduled program is *the* thing that computes:
   out, and K-pads every stage's weight matrix ONCE (the numeric
   analogue of programming conductances), so the hot loop only
   quantizes *activations* — the data-dependent quantities;
-* every GEMM is ONE ``crossbar_gemm`` Pallas dispatch: the kernel's K
+* every GEMM is ONE ``mounted_gemm`` Pallas dispatch: the kernel's K
   grid activates all row mounts of the stage in a single call
-  (``rows=tile_rows`` — each K block is one physical array read with
-  per-mount ADC chunk semantics, partial sums chained in int32 inside
-  the kernel's accumulator: SnA across stacked arrays, bit-identical
-  to the former per-mount ``lax.scan`` because int32 addition is
-  associative);
+  (``rows=tile_rows``, the kernel lays the streamed activation out as
+  mounts like the packed weights — each K block is one physical array
+  read with per-mount ADC chunk semantics, partial sums chained in
+  int32 inside the kernel's accumulator: SnA across stacked arrays,
+  bit-identical to the former per-mount ``lax.scan`` because int32
+  addition is associative);
 * every post-op chain (shift-and-add requant -> bias -> residual ->
   ReLU/GELU -> layer norm -> max/avg/seq-mean pool window | softmax)
   runs in ONE pass of the fused ``fb_epilogue`` Pallas kernel over the
@@ -25,7 +26,7 @@ scheduled program is *the* thing that computes:
 the same machinery to attention's activation-side GEMMs: per (batch,
 head), the Q·Kᵀ / P·V right-hand operand is quantized and mounted
 IN-GRAPH with the same ``plane_pack`` helper that mounts weights at
-compile time, then dispatched through the same ``crossbar_gemm`` kernel
+compile time, then dispatched through the same ``mounted_gemm`` kernel
 with the K grid sized to the *runtime* contraction length (head dim for
 scores, seq_len for context — the paper's block-activation scheme on
 dynamically sized mounts).  The per-mount loop is a ``jax.vmap`` over
@@ -54,28 +55,29 @@ re-derives the weight planes every call — the pre-PR-4 cost profile.
 
 from __future__ import annotations
 
+from typing import Callable, Iterator, Mapping, NamedTuple
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.crossbar import quantize_scale, quantize_symmetric
-from repro.kernels.crossbar_gemm import crossbar_gemm
+from repro.kernels.crossbar_gemm import mounted_gemm
 from repro.kernels.fb_epilogue import fb_epilogue
 from repro.kernels.ops import interpret_default
+from repro.models.cnn import im2col
 
 from .compile import CrossbarProgram, ProgramOp
 from .pack import PackedProgram, PackedStage, pack_program, plane_pack
 from .sequence import merge_heads, split_qkv_heads, tokens
 
 
-def im2col(x: jnp.ndarray, k: int, stride: int, pad: int) -> jnp.ndarray:
-    """NHWC -> (N, OH, OW, k*k*C) patches (same layout as models.cnn)."""
-    n, h, w, c = x.shape
-    xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
-    oh = (h + 2 * pad - k) // stride + 1
-    ow = (w + 2 * pad - k) // stride + 1
-    patches = jax.lax.conv_general_dilated_patches(
-        xp.transpose(0, 3, 1, 2), (k, k), (stride, stride), "VALID")
-    return patches.transpose(0, 2, 3, 1).reshape(n, oh, ow, c * k * k)
+class Kernels(NamedTuple):
+    """The two kernels every stage dispatches.  A reference pair with
+    the same signatures can stand in to check them stage by stage
+    (``stage_outputs``)."""
+
+    gemm: Callable
+    epilogue: Callable
 
 
 def _last_reads(stages) -> dict[str, int]:
@@ -91,14 +93,16 @@ def _last_reads(stages) -> dict[str, int]:
     return last
 
 
-def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: dict,
-               cfg, *, block_m: int, block_n: int,
-               interpret: bool) -> jnp.ndarray:
-    """One dynamic-operand GEMM stage (attention Q·Kᵀ or P·V).
+def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
+               cfg, *, block_m: int | None, block_n: int | None,
+               interpret: bool, kernels: Kernels
+               ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One dynamic-operand GEMM stage (attention Q·Kᵀ or P·V) ->
+    (output, int32 GEMM result per batch*head).
 
     Mounts the right-hand activation per (batch, head) with
     ``plane_pack`` — the same helper that mounts weights at compile
-    time — and dispatches the same ``crossbar_gemm`` kernel, its K grid
+    time — and dispatches the same ``mounted_gemm`` kernel, its K grid
     sized to the runtime contraction length (module docstring).
     """
     if gemm.dyn == "qk":
@@ -116,31 +120,29 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: dict,
         aq, ascale = quantize_symmetric(a2, cfg.input_bits)
         w8, wamax = plane_pack(w2, tile_rows=rows,
                                weight_bits=cfg.weight_bits)
-        a8 = aq.astype(jnp.int8)
-        kp = w8.shape[0] - a8.shape[1]
-        if kp:   # mirror the mount padding on the streaming side
-            a8 = jnp.pad(a8, ((0, 0), (0, kp)))
-        y = crossbar_gemm(a8, w8, adc_bits=cfg.adc_bits, rows=rows,
-                          block_m=block_m, block_n=block_n,
-                          interpret=interpret)
+        y = kernels.gemm(aq.astype(jnp.int8), w8, adc_bits=cfg.adc_bits,
+                         rows=rows, block_m=block_m, block_n=block_n,
+                         interpret=interpret)
         ws = quantize_scale(wamax, cfg.weight_bits)
         scale = (ascale * ws).astype(jnp.float32).reshape(1, 1)
-        return fb_epilogue(y, scale, jnp.zeros((w2.shape[1],), jnp.float32),
-                           None, softmax=softmax,
-                           post_scale=gemm.post_scale, block_m=block_m,
-                           block_n=block_n, interpret=interpret)
+        return kernels.epilogue(
+            y, scale, jnp.zeros((w2.shape[1],), jnp.float32), None,
+            softmax=softmax, post_scale=gemm.post_scale, block_m=block_m,
+            block_n=block_n, interpret=interpret), y
 
-    out = jax.vmap(one)(a, w)
+    out, acc = jax.vmap(one)(a, w)
     if gemm.dyn == "pv":                         # heads rejoin the model dim
         out = merge_heads(out, gemm.heads)
-    return out
+    return out, acc
 
 
 def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
-                  st: PackedStage, bufs: dict, cfg, *, block_m: int,
-                  block_n: int, interpret: bool,
-                  drop_softmax: bool) -> tuple[str, jnp.ndarray]:
-    """One weight-mounted GEMM stage + fused epilogue -> (dst, buffer)."""
+                  st: PackedStage, bufs: Mapping, cfg, *,
+                  block_m: int | None, block_n: int | None, interpret: bool,
+                  drop_softmax: bool, kernels: Kernels
+                  ) -> tuple[str, jnp.ndarray, jnp.ndarray]:
+    """One weight-mounted GEMM stage + fused epilogue -> (dst, buffer,
+    int32 GEMM result)."""
     src = bufs[gemm.src]
     b = src.shape[0]
     t = 0
@@ -158,13 +160,9 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
             xin = src
 
     xq, xs = quantize_symmetric(xin, cfg.input_bits)
-    x8 = xq.astype(jnp.int8)
-    kp = st.w8.shape[0] - x8.shape[1]
-    if kp:   # K was padded to full mounts at pack time; mirror it
-        x8 = jnp.pad(x8, ((0, 0), (0, kp)))
-    y_int = crossbar_gemm(x8, st.w8, adc_bits=cfg.adc_bits,
-                          rows=gemm.tile_rows, block_m=block_m,
-                          block_n=block_n, interpret=interpret)
+    y_int = kernels.gemm(xq.astype(jnp.int8), st.w8, adc_bits=cfg.adc_bits,
+                         rows=gemm.tile_rows, block_m=block_m,
+                         block_n=block_n, interpret=interpret)
     # the weight scale divides out of the stored amax IN-GRAPH so the
     # dequant product keeps the functional reference's HLO shape
     # (quantize_scale docstring; DESIGN.md §5)
@@ -197,20 +195,79 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
     if softmax and drop_softmax:
         softmax = False
         dst = gemm.dst
-    out = fb_epilogue(y_int, scale, st.bias, res, act=act, pool=pool,
-                      window=window, img_hw=img_hw, softmax=softmax,
-                      norm=norm, gamma=st.ln_g, beta=st.ln_b,
-                      block_m=block_m, block_n=block_n,
-                      interpret=interpret)
+    out = kernels.epilogue(y_int, scale, st.bias, res, act=act, pool=pool,
+                           window=window, img_hw=img_hw, softmax=softmax,
+                           norm=norm, gamma=st.ln_g, beta=st.ln_b,
+                           block_m=block_m, block_n=block_n,
+                           interpret=interpret)
     if gemm.is_conv:
         out = out.reshape(b, out_hw, out_hw, -1)
     elif gemm.seq and pool != "seqmean":
         out = out.reshape(b, t, -1)
-    return dst, out
+    return dst, out, y_int
+
+
+class StageOutput(NamedTuple):
+    """What one stage wrote: its buffer name and value, and the int32
+    crossbar GEMM result its epilogue consumed (per batch*head for
+    dynamic attention stages)."""
+
+    name: str
+    value: jnp.ndarray
+    acc: jnp.ndarray
+
+
+def stage_outputs(packed: PackedProgram, x: jnp.ndarray, *,
+                  block_m: int | None = None, block_n: int | None = None,
+                  interpret: bool | None = None,
+                  return_logits: bool = False,
+                  feed: Mapping[str, jnp.ndarray] | None = None,
+                  kernels: Kernels | None = None
+                  ) -> Iterator[StageOutput]:
+    """Run a packed program stage by stage, yielding a ``StageOutput``
+    per stage, in program order.
+
+    Buffer names are the graph's layer names (plus ``@``-suffixed ones
+    inside attention).  With ``feed`` every stage reads its inputs from
+    ``feed`` (e.g. the functional oracle's buffers) instead of from the
+    stages before it, so each stage can be held to a reference on
+    identical inputs; ``kernels`` swaps in reference kernels for such
+    checks (default: ``mounted_gemm`` and ``fb_epilogue``).  Other
+    arguments as ``execute_packed``.
+    """
+    if interpret is None:
+        interpret = interpret_default()
+    if kernels is None:
+        kernels = Kernels(mounted_gemm, fb_epilogue)
+    program = packed.program
+    cfg = program.cfg
+    bufs: dict[str, jnp.ndarray] = {program.input: x}
+    src = bufs if feed is None else feed
+    stages = program.stages()
+    last = _last_reads(stages)
+    for si, ((gemm, posts), st) in enumerate(zip(stages, packed.stages)):
+        if gemm.kind == "dyn_gemm":
+            dst = posts[-1].dst if posts else gemm.dst
+            out, acc = _dyn_stage(gemm, posts, src, cfg, block_m=block_m,
+                                  block_n=block_n, interpret=interpret,
+                                  kernels=kernels)
+        else:
+            dst, out, acc = _static_stage(
+                gemm, posts, st, src, cfg, block_m=block_m,
+                block_n=block_n, interpret=interpret,
+                drop_softmax=return_logits and si == len(stages) - 1,
+                kernels=kernels)
+        bufs[dst] = out
+        yield StageOutput(dst, out, acc)
+        # drop buffers no later stage reads: eager forwards hold only
+        # the live dataflow frontier
+        for name in [n for n, li in last.items() if li <= si]:
+            bufs.pop(name, None)
+            del last[name]
 
 
 def execute_packed(packed: PackedProgram, x: jnp.ndarray,
-                   *, block_m: int = 512, block_n: int = 512,
+                   *, block_m: int | None = None, block_n: int | None = None,
                    interpret: bool | None = None,
                    return_logits: bool = False) -> jnp.ndarray:
     """Run a packed program on a batch ``x`` (B, H, W, C) float32 — or
@@ -218,44 +275,29 @@ def execute_packed(packed: PackedProgram, x: jnp.ndarray,
 
     The steady-state hot path: weights are already chip-resident int8
     mount planes (see ``pack.py``), so each stage quantizes its input,
-    makes one ``crossbar_gemm`` dispatch activating every mount (one
+    makes one ``mounted_gemm`` dispatch activating every mount (one
     per batch*head for dynamic attention stages), and one fused
     ``fb_epilogue`` dispatch.  Returns the program output buffer —
     softmax probabilities, or the pre-softmax logits with
     ``return_logits=True`` (the final stage is re-fused without its
-    softmax FB, mirroring the functional forward).  Block sizes are
-    interpret-mode defaults; on TPU proper prefer (128, 128) MXU tiles.
+    softmax FB, mirroring the functional forward).  ``block_m`` /
+    ``block_n`` override the kernels' per-path tile defaults
+    (``kernels/tiling.py``); ``None`` keeps them.
     """
-    if interpret is None:
-        interpret = interpret_default()
     program = packed.program
-    cfg = program.cfg
-    bufs: dict[str, jnp.ndarray] = {program.input: x}
-    stages = program.stages()
-    last = _last_reads(stages)
     ret = program.logits if return_logits else program.output
-    for si, ((gemm, posts), st) in enumerate(zip(stages, packed.stages)):
-        if gemm.kind == "dyn_gemm":
-            dst = posts[-1].dst if posts else gemm.dst
-            bufs[dst] = _dyn_stage(gemm, posts, bufs, cfg, block_m=block_m,
-                                   block_n=block_n, interpret=interpret)
-        else:
-            dst, out = _static_stage(
-                gemm, posts, st, bufs, cfg, block_m=block_m,
-                block_n=block_n, interpret=interpret,
-                drop_softmax=return_logits and si == len(stages) - 1)
-            bufs[dst] = out
-        # drop buffers no later stage reads: eager forwards hold only
-        # the live dataflow frontier
-        for name in [n for n, li in last.items() if li <= si]:
-            if name != ret:
-                bufs.pop(name, None)
-                del last[name]
-    return bufs[ret]
+    out = None
+    for stage in stage_outputs(packed, x, block_m=block_m, block_n=block_n,
+                               interpret=interpret,
+                               return_logits=return_logits):
+        if stage.name == ret:
+            out = stage.value
+    return out
 
 
 def execute_program(program: CrossbarProgram, params: dict, x: jnp.ndarray,
-                    *, block_m: int = 512, block_n: int = 512,
+                    *, block_m: int | None = None,
+                    block_n: int | None = None,
                     interpret: bool | None = None,
                     return_logits: bool = False) -> jnp.ndarray:
     """Params-consuming compatibility entry (pre-packing cost profile).

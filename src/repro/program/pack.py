@@ -15,9 +15,12 @@ call:
   ``quantize_scale`` so the dequant product keeps the exact HLO shape
   of the functional reference — see that helper's docstring);
 * the conv im2col layout (``w.transpose(2, 0, 1, 3).reshape(kk, -1)``);
-* K zero-padded up to ``n_mounts * tile_rows`` so every mount round is a
-  full ``tile_rows`` ADC chunk and the executor activates ALL mounts of
-  a stage in one ``crossbar_gemm`` K-grid dispatch (block activation).
+* the mount layout (``kernels.crossbar_gemm.mount_layout``): K cut into
+  ``tile_rows``-row mounts, each zero-padded to the next multiple of 128
+  rows, so every mount round is one ADC chunk of exactly ``tile_rows``
+  real rows in a K block the TPU tiling accepts, and the executor
+  activates ALL mounts of a stage in one ``mounted_gemm`` K-grid
+  dispatch (block activation).
 
 The quantize+pad core is the standalone ``plane_pack`` helper — the
 SAME function the executor invokes **in-graph, per batch** on the
@@ -39,7 +42,7 @@ float math again.  Packing eagerly and quantizing under jit produce
 bit-identical planes: ``quantize_symmetric`` is abs/max/divide/round —
 none of it subject to FMA contraction (DESIGN.md §5).
 
-``repro.api`` persists the packed planes in its save format (version 3),
+``repro.api`` persists the packed planes in its save format (version 4),
 so ``api.load(...).run(...)`` never re-derives them (DESIGN.md §7).
 """
 
@@ -52,6 +55,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.crossbar import quantize_symmetric
+from repro.kernels.crossbar_gemm import mount_layout
 
 from .compile import CrossbarProgram
 
@@ -61,9 +65,9 @@ from .compile import CrossbarProgram
 class PackedStage:
     """One GEMM stage's chip-resident weights.
 
-    ``w8`` is the int8 mount-plane matrix ``(K_padded, N)`` — im2col
-    layout applied, K padded to ``n_mounts * tile_rows`` so the kernel's
-    K grid is exactly the stage's mount rounds; ``w_amax`` is the f32
+    ``w8`` is the int8 mount-plane matrix ``(K_mounted, N)`` — im2col
+    layout applied, K in the mount layout (``mount_layout``) so the
+    kernel's K grid is exactly the stage's mount rounds; ``w_amax`` is the f32
     per-tensor ``max(|w|)`` from which the executor derives the
     symmetric quantization scale in-graph (``quantize_scale``);
     ``bias`` the f32 per-column bias.  ``ln_g``/``ln_b`` are the fused
@@ -108,11 +112,12 @@ class PackedProgram:
 
 def plane_pack(w: jnp.ndarray, *, tile_rows: int,
                weight_bits: int = 8) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Mount a (K, N) float matrix: -> (int8 planes (K_pad, N), f32 amax).
+    """Mount a (K, N) float matrix: -> (int8 planes (K_mounted, N), f32 amax).
 
-    Symmetric per-tensor int8 quantization at ``weight_bits``, K
-    zero-padded up to the next ``tile_rows`` multiple so every mount is
-    a full ADC row chunk (zero rows add nothing to any bitline count).
+    Symmetric per-tensor int8 quantization at ``weight_bits``, K laid
+    out as full ``tile_rows``-row mounts, each zero-padded to a multiple
+    of 128 rows (``mount_layout``; zero rows add nothing to any bitline
+    count).
     Invoked once per weight at pack time — and **in-graph, per batch**
     on the quantized K/V head matrices of dynamic attention stages, the
     run-time analogue of programming conductances (DESIGN.md §9).  The
@@ -120,15 +125,13 @@ def plane_pack(w: jnp.ndarray, *, tile_rows: int,
     derives the scale through ``quantize_scale``'s traced expression.
     """
     wq, _ = quantize_symmetric(w, weight_bits)
-    kp = -w.shape[0] % tile_rows
-    if kp:
-        wq = jnp.pad(wq, ((0, kp), (0, 0)))
-    return wq.astype(jnp.int8), jnp.max(jnp.abs(w)).astype(jnp.float32)
+    return (mount_layout(wq.astype(jnp.int8), tile_rows, 0),
+            jnp.max(jnp.abs(w)).astype(jnp.float32))
 
 
 def pack_weight(w: jnp.ndarray, *, is_conv: bool, tile_rows: int,
                 weight_bits: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Float weight -> (int8 mount planes (K_pad, N), f32 amax)."""
+    """Float weight -> (int8 mount planes (K_mounted, N), f32 amax)."""
     if is_conv:                 # (k, k, in_ch, out_ch) -> (in_ch*k*k, N)
         kk = w.shape[0] * w.shape[1] * w.shape[2]
         w = w.transpose(2, 0, 1, 3).reshape(kk, -1)

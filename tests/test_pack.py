@@ -22,7 +22,8 @@ from repro import api
 from repro.api import HurryConfig, NetworkBuilder
 from repro.core.crossbar import CrossbarConfig, quantize_symmetric
 from repro.kernels import ref
-from repro.kernels.crossbar_gemm import crossbar_gemm
+from repro.kernels.crossbar_gemm import (crossbar_gemm, mount_rows,
+                                        mounted_gemm)
 from repro.kernels.fb_epilogue import fb_epilogue
 from repro.models.cnn import CNN_MODELS, make_crossbar_matmul
 from repro.program import compile_network, execute_packed, pack_program
@@ -39,19 +40,55 @@ def test_packed_planes_match_traced_quantization():
     program = compile_network("alexnet", cfg=CLIP_FREE)
     packed = pack_program(program, params)
     assert packed.program.plans == ()       # executor never reads plans
+    multi_mount_unaligned = 0
     for (gemm, _), st in zip(program.stages(), packed.stages):
         w = params[gemm.param]["w"]
         if gemm.is_conv:
             kk = w.shape[0] * w.shape[1] * w.shape[2]
             w = w.transpose(2, 0, 1, 3).reshape(kk, -1)
-        wq = jax.jit(lambda v: quantize_symmetric(v, 8)[0])(w)
+        wq = np.asarray(jax.jit(lambda v: quantize_symmetric(v, 8)[0])(w))
         assert st.w8.dtype == jnp.int8
-        assert st.w8.shape[0] % gemm.tile_rows == 0          # full mounts
-        np.testing.assert_array_equal(np.asarray(st.w8[:w.shape[0]]),
-                                      np.asarray(wq))
-        assert not np.asarray(st.w8[w.shape[0]:]).any()      # zero pad
         np.testing.assert_array_equal(
             np.asarray(st.w_amax), np.asarray(jnp.max(jnp.abs(w))))
+        rows, k = gemm.tile_rows, wq.shape[0]
+        if k <= rows:            # one mount: the whole contraction, unpadded
+            np.testing.assert_array_equal(np.asarray(st.w8), wq)
+            continue
+        # mount layout: tile_rows real rows per mount, then zero rows up
+        # to the 128-row tiling
+        n, height = -(-k // rows), mount_rows(rows)
+        assert st.w8.shape[0] == n * height and height % 128 == 0
+        mounts = np.asarray(st.w8).reshape(n, height, -1)
+        real = np.pad(wq, ((0, n * rows - k), (0, 0))).reshape(n, rows, -1)
+        np.testing.assert_array_equal(mounts[:, :rows], real)
+        assert not mounts[:, rows:].any()
+        multi_mount_unaligned += rows % 128 != 0
+    assert multi_mount_unaligned     # alexnet has 485/493-row mounts
+
+
+def test_multi_mount_stage_keeps_sliced_adc_semantics():
+    """A multi-mount stage whose ``tile_rows`` is off the 128-row tiling
+    (alexnet conv2: K=576 in two 486-row mounts, each laid out as 512
+    rows), packed at compile time and streamed the way the executor
+    streams it: the sliced kernel still clips per mount over exactly
+    ``tile_rows`` real rows — bit-exact against ``ref.crossbar_gemm_ref``
+    chunked at ``tile_rows``, and genuinely clipping."""
+    params = CNN_MODELS["alexnet"].init(jax.random.PRNGKey(1))
+    program = compile_network("alexnet", cfg=CrossbarConfig(adc_bits=7))
+    packed = pack_program(program, params)
+    (gemm, _), st = program.stages()[1], packed.stages[1]
+    rows = gemm.tile_rows
+    assert gemm.name == "conv2" and rows % 128 and rows < 576
+    w = params["conv2"]["w"]
+    w = w.transpose(2, 0, 1, 3).reshape(576, -1)
+    wq = jax.jit(lambda v: quantize_symmetric(v, 8)[0])(w).astype(jnp.int8)
+    x = jax.random.randint(jax.random.PRNGKey(2), (16, 576), -128, 128,
+                           jnp.int32).astype(jnp.int8)
+    y = mounted_gemm(x, st.w8, adc_bits=7, rows=rows, interpret=True)
+    want = ref.crossbar_gemm_ref(x, wq, adc_bits=7, rows=rows)
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
+    assert not np.array_equal(np.asarray(y),
+                              np.asarray(ref.crossbar_gemm_exact_ref(x, wq)))
 
 
 def test_buffer_lifetime_dropping_never_changes_results():
@@ -115,6 +152,33 @@ def test_fb_epilogue_pad_to_block_slice_exact():
     oracle = jax.jit(lambda *a: ref.fb_epilogue_ref(
         *a, act="relu", pool="max", window=2, img_hw=ih))(y, scale, bias,
                                                           None)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
+
+
+@pytest.mark.parametrize("n,kw", [
+    (9, dict(pool="seqmean", window=4, norm="layer")),   # 8 per step
+    (9, dict(act="relu", pool="avg", window=4, img_hw=4)),
+    (3, dict(act="relu", pool="max", window=2, img_hw=4)),  # 2 per step
+], ids=["seqmean", "avgpool_4x4", "maxpool_4to2"])
+def test_fb_epilogue_pads_pooled_batches(n, kw):
+    """A batch that is not a multiple of the images (sequences) per grid
+    step is padded with whole zero images and sliced back exactly."""
+    rows = kw.get("img_hw", 2) ** 2 if kw["pool"] != "seqmean" \
+        else kw["window"]
+    M, N = n * rows, 24
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    y = jax.random.randint(ks[0], (M, N), -20000, 20000, dtype=jnp.int32)
+    scale = jnp.array([[0.017]], jnp.float32)
+    bias = jax.random.normal(ks[1], (N,), jnp.float32)
+    res = jax.random.normal(ks[2], (M, N), jnp.float32)
+    ln = {}
+    if kw.get("norm") == "layer":
+        ln = dict(gamma=1 + 0.1 * jax.random.normal(ks[3], (N,)),
+                  beta=0.1 * jax.random.normal(ks[4], (N,)))
+    out = fb_epilogue(y, scale, bias, res, interpret=True, **kw, **ln)
+    oracle = jax.jit(lambda *a: ref.fb_epilogue_ref(*a, **kw, **ln))(
+        y, scale, bias, res)
+    assert out.shape == oracle.shape
     np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
 
 
@@ -203,6 +267,54 @@ def test_version1_file_loads_via_repack_fallback(tmp_path):
         with open(bad, "wb") as f:
             np.savez(f, __meta__=np.asarray(json.dumps(meta)), **arrays)
         api.load(bad)
+
+
+def _v3_plane(w8: np.ndarray, op) -> np.ndarray:
+    """A mount-layout plane as versions 2-3 stored it: the real K rows,
+    zero-padded at the end to whole ``tile_rows`` mounts."""
+    k, rows = max(r.k1 for r in op.mount_rounds), op.tile_rows
+    if k > rows:
+        n = -(-k // rows)
+        w8 = w8.reshape(n, mount_rows(rows), -1)[:, :rows]
+    w8 = w8.reshape(-1, w8.shape[-1])[:k]
+    return np.pad(w8, ((0, -k % rows), (0, 0)))
+
+
+@pytest.mark.parametrize("version", [2, 3])
+def test_pre_mount_layout_file_loads_bit_identical(tmp_path, version):
+    """Files saved before the mount layout (versions 2-3: K padded once,
+    at its end, to whole mounts; the old 512x512 block defaults stored
+    explicitly) load and run bit-identically, on a multi-mount stage
+    whose ``tile_rows`` is off the 128-row tiling."""
+    nb = NetworkBuilder("tiny", input_hw=8, input_ch=4)
+    nb.conv(16, name="c1")
+    nb.relu(name="r1")
+    nb.maxpool(name="p1")
+    nb.fc(10, name="fc")
+    graph = nb.build()
+    model = api.compile(graph, HurryConfig(array_rows=100), seed=1)
+    x = jax.random.normal(jax.random.PRNGKey(0), graph.input_shape(3))
+    gemms = [g for g, _ in model.program.stages()]
+    # fc: K=256 in 100-row mounts
+    assert any(max(r.k1 for r in g.mount_rounds) > g.tile_rows
+               and g.tile_rows % 128 for g in gemms)
+    path = model.save(str(tmp_path / "m.npz"))
+    with np.load(path, allow_pickle=False) as z:
+        meta = json.loads(str(z["__meta__"][()]))
+        arrays = {k: z[k] for k in z.files if k != "__meta__"}
+    meta["version"] = version
+    meta["config"].update(block_m=512, block_n=512)
+    for i, op in enumerate(gemms):
+        arrays[f"w{i}"] = _v3_plane(arrays[f"w{i}"], op)
+    old = str(tmp_path / f"v{version}.npz")
+    with open(old, "wb") as f:
+        np.savez(f, __meta__=np.asarray(json.dumps(meta)), **arrays)
+    loaded = api.load(old)
+    assert loaded.config == model.config        # block sizes back to None
+    for a, b in zip(model._packed().stages, loaded.packed.stages):
+        np.testing.assert_array_equal(np.asarray(a.w8), np.asarray(b.w8))
+    np.testing.assert_array_equal(np.asarray(model.run(x, logits=True)),
+                                  np.asarray(loaded.run(x, logits=True)))
 
 
 def test_packed_program_is_a_jit_arg():

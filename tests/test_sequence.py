@@ -4,7 +4,7 @@ ISSUE 5 acceptance: ``api.compile(zoo.vit_tiny(), cfg).run(x)`` is
 bit-exact against the jitted functional-oracle forward under a
 clip-free config (both sides jitted — FMA contraction, DESIGN.md §5),
 a save→load roundtrip of the same model agrees bit-exactly (npz format
-v3 with dynamic stages), and the satellites: the fused epilogue's
+with dynamic stages), and the satellites: the fused epilogue's
 softmax survives ±1e4-magnitude logits (max-subtraction), crossbar
 attention tracks the ``flash_attention`` reference across a seq-len
 sweep within clip-free int8 tolerance, and ``core.workload.WORKLOADS``
@@ -49,13 +49,13 @@ def _oracle(graph, logits=False):
 
 
 # ---------------------------------------------------------------------------
-# acceptance: vit_tiny bit-exact + v3 save/load roundtrip
+# acceptance: vit_tiny bit-exact + save/load roundtrip
 # ---------------------------------------------------------------------------
 
 def test_vit_tiny_bit_exact_and_roundtrip(tmp_path):
     """The compiled packed ViT — patchify conv, dynamic-operand
     attention stages, MLP, pooled head — reproduces the functional
-    crossbar oracle bitwise (probs AND logits), and survives a v3
+    crossbar oracle bitwise (probs AND logits), and survives a
     save→load roundtrip bit-exactly without recompiling."""
     graph = vit_tiny()
     model = api.compile(graph, CLIP_FREE)
@@ -70,7 +70,7 @@ def test_vit_tiny_bit_exact_and_roundtrip(tmp_path):
 
     path = model.save(str(tmp_path / "vit.npz"))
     meta_version = VERSION
-    assert meta_version == 3
+    assert meta_version == 4
     loaded = api.load(path)
     assert loaded.program.ops == model.program.ops
     assert loaded.program.has_dynamic_stages

@@ -1,0 +1,116 @@
+"""Compile-only tests for a described TPU v5e chip: no chip needed.
+
+The TPU compiler is installed even where no chip is attached, and it
+refuses what interpret mode accepts: K blocks off the 128-lane tiling,
+output blocks of fewer than 8 rows, 1-D operand blocks, tiles that
+overflow VMEM.  These tests compile the main path's kernels at real
+stage shapes, and two whole programs, for one chip of a described
+``v5e:2x2`` topology, and check that the Pallas kernels lowered to
+Mosaic (``tpu_custom_call``) rather than to the interpreter.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load the TPU library, so every pytest
+worker must collect the same tests and only the one given this file
+may load it.  All such tests live in this one file for that reason.
+"""
+
+import importlib.util
+import os
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro import api
+from repro.api.zoo import vit_tiny_graph
+from repro.kernels.crossbar_gemm import mount_layout, mounted_gemm
+from repro.kernels.fb_epilogue import fb_epilogue
+from repro.program.execute import execute_packed
+
+_PATH = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+_spec = importlib.util.spec_from_file_location("chip_smoke", _PATH)
+smoke = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(smoke)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:       # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args) -> str:
+    """Lower + compile ``fn`` for the described chip; its HLO text."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text       # Mosaic kernels, not interpreted
+    return text
+
+
+def _shape(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.mark.parametrize("path", ["exact", "sliced"])
+@pytest.mark.parametrize("case", smoke.GEMM_CASES, ids=lambda c: c[0])
+def test_crossbar_gemm_compiles(one_chip, case, path):
+    _, m, k, n, rows = case
+    # the weights' K in the mount layout: what pack.plane_pack stores
+    k_mounted = jax.eval_shape(lambda a: mount_layout(a, rows, 0),
+                               jax.ShapeDtypeStruct((k, n), jnp.int8)).shape[0]
+    exact = path == "exact"
+    _compile(lambda x, w: mounted_gemm(x, w, adc_bits=9 if exact else 7,
+                                       rows=rows, exact=exact,
+                                       interpret=False),
+             _shape(one_chip, (m, k), jnp.int8),
+             _shape(one_chip, (k_mounted, n), jnp.int8))
+
+
+# batches off the pooled modes' images-per-step count and past the
+# serving bucket ladder (256): padded by whole images or sequences
+ODD_BATCH_CASES = (
+    ("seqmean_t64_b300", 300 * 64, 192, dict(pool="seqmean", window=64,
+                                             norm="layer"), True, True),
+    ("avgpool_4x4_b301", 301 * 16, 512, dict(act="relu", pool="avg",
+                                             window=4, img_hw=4), True, False),
+    ("maxpool_4to2_b301", 301 * 16, 512, dict(act="relu", pool="max",
+                                              window=2, img_hw=4), False,
+     False),
+)
+
+
+@pytest.mark.parametrize("case", smoke.EPILOGUE_CASES + ODD_BATCH_CASES,
+                         ids=lambda c: c[0])
+def test_fb_epilogue_compiles(one_chip, case):
+    _, m, n, kw, has_res, has_ln = case
+    f32 = jnp.float32
+    vec = _shape(one_chip, (n,), f32) if has_ln else None
+    res = _shape(one_chip, (m, n), f32) if has_res else None
+    _compile(lambda y, s, b, r, g, bt: fb_epilogue(
+        y, s, b, r, gamma=g, beta=bt, interpret=False, **kw),
+        _shape(one_chip, (m, n), jnp.int32), _shape(one_chip, (1, 1), f32),
+        _shape(one_chip, (n,), f32), res, vec, vec)
+
+
+@pytest.mark.parametrize("net", ["resnet18", "vit_tiny"])
+def test_whole_program_compiles(one_chip, net):
+    graph = vit_tiny_graph(depth=2) if net == "vit_tiny" else net
+    model = api.compile(graph, smoke.CLIP_FREE)
+    packed = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
+                          model.packed)
+    x = _shape(one_chip, model.program.input_shape(8), jnp.float32)
+    text = _compile(lambda pk, v: execute_packed(pk, v, interpret=False),
+                    packed, x)
+    # one crossbar GEMM and one fused epilogue per static stage at least
+    assert text.count("tpu_custom_call") >= 2 * len(model.program.stages())
