@@ -27,6 +27,7 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.baselines import SimReport, simulate_isaac, simulate_misca
 from repro.core.simulator import simulate_hurry
@@ -55,6 +56,11 @@ class CompiledModel:
     buckets: tuple[int, ...] = BUCKETS
     _fns: dict = dataclasses.field(default_factory=dict, repr=False,
                                    compare=False)
+    # (logits, bucket) pairs ``run`` has called, and requests served:
+    # what the ``repro.run`` trace spans report
+    _called: set = dataclasses.field(default_factory=set, repr=False,
+                                     compare=False)
+    _requests: int = dataclasses.field(default=0, repr=False, compare=False)
 
     # -- numeric execution -------------------------------------------------
 
@@ -62,6 +68,17 @@ class CompiledModel:
         if self.packed is None:   # models built before packing existed
             self.packed = pack_program(self.program, self.params)
         return self.packed
+
+    def _fn(self, logits: bool):
+        """The jitted executor of one output flavor, built once."""
+        fn = self._fns.get(logits)
+        if fn is None:
+            cfg = self.config
+            fn = jax.jit(lambda pk, v: execute_packed(
+                pk, v, block_m=cfg.block_m, block_n=cfg.block_n,
+                return_logits=logits))
+            self._fns[logits] = fn
+        return fn
 
     def run(self, x: jnp.ndarray, *, logits: bool = False) -> jnp.ndarray:
         """Execute the packed program on a batch.
@@ -72,17 +89,45 @@ class CompiledModel:
         batches pad up to the model's bucket ladder (slice-exact edge
         replication) and XLA caches one executable per bucket — varying
         traffic shapes stay pure execution on ~10 executables.
+
+        Under a ``jax.profiler`` trace each call writes a host span
+        ``repro.run`` with three children: ``repro.run.pad`` (padding
+        to the bucket, which copies a host array to the device; an
+        unpadded one is copied in the call), ``repro.run.call`` (the
+        executor's dispatch) and ``repro.run.slice`` (the ``[:b]``).
+        All four carry ``request``, one id per call of this model;
+        ``repro.run`` also ``batch`` and ``bucket``; ``repro.run.call``
+        ``bucket`` and ``new`` (1 on the first call of a flavor and
+        bucket: the one that compiles or loads from the compile cache).
+        Without a trace they cost a few microseconds a call.
         """
-        fn = self._fns.get(logits)
-        if fn is None:
-            cfg = self.config
-            fn = jax.jit(lambda pk, v: execute_packed(
-                pk, v, block_m=cfg.block_m, block_n=cfg.block_n,
-                return_logits=logits))
-            self._fns[logits] = fn
+        fn = self._fn(logits)
         b = x.shape[0]
-        x = pad_batch(x, bucket_batch(b, self.buckets))
-        return fn(self._packed(), x)[:b]
+        bucket = bucket_batch(b, self.buckets)
+        self._requests += 1
+        req = self._requests
+        with TraceAnnotation("repro.run", request=req, batch=b,
+                             bucket=bucket):
+            with TraceAnnotation("repro.run.pad", request=req):
+                x = pad_batch(x, bucket)
+            new = (logits, bucket) not in self._called
+            with TraceAnnotation("repro.run.call", request=req,
+                                 bucket=bucket, new=int(new)):
+                y = fn(self._packed(), x)
+            self._called.add((logits, bucket))
+            with TraceAnnotation("repro.run.slice", request=req):
+                return y[:b]
+
+    def compiled_text(self, x, *, logits: bool = False) -> str:
+        """The optimized HLO text of the executable ``run`` calls for a
+        request shaped like ``x`` (an array or ``jax.ShapeDtypeStruct``):
+        its bucket's program, each instruction's ``op_name`` carrying the
+        stage and phase scopes of ``program/execute.py``.  Read-only;
+        after ``run`` has served that bucket the compile is a load from
+        JAX's compile cache, where one is on."""
+        shape = (bucket_batch(x.shape[0], self.buckets),) + x.shape[1:]
+        v = jax.ShapeDtypeStruct(shape, x.dtype)
+        return self._fn(logits).lower(self._packed(), v).compile().as_text()
 
     def warmup(self, batch: int = 1, *, logits: bool = False,
                seq_len: int = 16) -> None:

@@ -250,6 +250,9 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
+        # names the custom call (``%fb_epilogue.N``) in the compiled
+        # HLO, which trace readers match on
+        name="fb_epilogue",
     )(y, scale, bias, res, g, bt)
     if pn:
         out = out[:, :N]

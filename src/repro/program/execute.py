@@ -46,6 +46,22 @@ functional-model forward when both are jitted (identical FMA
 contraction; DESIGN.md §5).  Read noise is a functional-model-only
 experiment: the program path models a clean chip.
 
+**Named scopes.**  Every op a stage issues carries one stage scope,
+``s<NN>.<buffer>`` (the stage's index and the buffer it writes), and
+one phase scope inside it, so a compiled program's ``op_name``
+metadata says which stage and which phase each instruction serves:
+
+* ``im2col`` — im2col, token and flatten reshapes, head splits;
+* ``quantize`` — the activation's amax reduction and int8 convert;
+* ``mount`` — ``mounted_gemm``'s mount layout, block pads and slice
+  back, and ``plane_pack`` of a dynamic stage's right-hand operand;
+* ``gemm`` — the ``mounted_gemm`` kernel;
+* ``epilogue`` — the scale product, the ``fb_epilogue`` kernel and the
+  output reshape.
+
+Scopes are metadata only: they change no op and cost nothing at run
+time.
+
 ``execute_packed`` is trace-pure; wrap it in ``jax.jit`` with the
 program closed over (see ``serve.ProgramServer``) to compile once and
 execute per request batch.  ``execute_program`` is the
@@ -105,34 +121,41 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
     time — and dispatches the same ``mounted_gemm`` kernel, its K grid
     sized to the runtime contraction length (module docstring).
     """
-    if gemm.dyn == "qk":
-        q, k, _ = split_qkv_heads(tokens(bufs[gemm.src]), gemm.heads)
-        a, w = q, jnp.swapaxes(k, 1, 2)          # (BH, T, hd), (BH, hd, T)
-    elif gemm.dyn == "pv":
-        a = bufs[gemm.src]                       # (BH, T, T) probabilities
-        _, _, w = split_qkv_heads(tokens(bufs[gemm.dyn_src]), gemm.heads)
-    else:  # pragma: no cover - compile_network emits only qk/pv
-        raise ValueError(gemm.dyn)
+    with jax.named_scope("im2col"):
+        if gemm.dyn == "qk":
+            q, k, _ = split_qkv_heads(tokens(bufs[gemm.src]), gemm.heads)
+            a, w = q, jnp.swapaxes(k, 1, 2)      # (BH, T, hd), (BH, hd, T)
+        elif gemm.dyn == "pv":
+            a = bufs[gemm.src]                   # (BH, T, T) probabilities
+            _, _, w = split_qkv_heads(tokens(bufs[gemm.dyn_src]),
+                                      gemm.heads)
+        else:  # pragma: no cover - compile_network emits only qk/pv
+            raise ValueError(gemm.dyn)
     softmax = any(p.kind == "softmax" for p in posts)
     rows = min(gemm.tile_rows, a.shape[-1])      # dynamic mount height
 
     def one(a2, w2):
-        aq, ascale = quantize_symmetric(a2, cfg.input_bits)
-        w8, wamax = plane_pack(w2, tile_rows=rows,
-                               weight_bits=cfg.weight_bits)
-        y = kernels.gemm(aq.astype(jnp.int8), w8, adc_bits=cfg.adc_bits,
-                         rows=rows, block_m=block_m, block_n=block_n,
+        with jax.named_scope("quantize"):
+            aq, ascale = quantize_symmetric(a2, cfg.input_bits)
+            aq = aq.astype(jnp.int8)
+        with jax.named_scope("mount"):
+            w8, wamax = plane_pack(w2, tile_rows=rows,
+                                   weight_bits=cfg.weight_bits)
+        y = kernels.gemm(aq, w8, adc_bits=cfg.adc_bits, rows=rows,
+                         block_m=block_m, block_n=block_n,
                          interpret=interpret)
-        ws = quantize_scale(wamax, cfg.weight_bits)
-        scale = (ascale * ws).astype(jnp.float32).reshape(1, 1)
-        return kernels.epilogue(
-            y, scale, jnp.zeros((w2.shape[1],), jnp.float32), None,
-            softmax=softmax, post_scale=gemm.post_scale, block_m=block_m,
-            block_n=block_n, interpret=interpret), y
+        with jax.named_scope("epilogue"):
+            ws = quantize_scale(wamax, cfg.weight_bits)
+            scale = (ascale * ws).astype(jnp.float32).reshape(1, 1)
+            return kernels.epilogue(
+                y, scale, jnp.zeros((w2.shape[1],), jnp.float32), None,
+                softmax=softmax, post_scale=gemm.post_scale,
+                block_m=block_m, block_n=block_n, interpret=interpret), y
 
     out, acc = jax.vmap(one)(a, w)
     if gemm.dyn == "pv":                         # heads rejoin the model dim
-        out = merge_heads(out, gemm.heads)
+        with jax.named_scope("epilogue"):
+            out = merge_heads(out, gemm.heads)
     return out, acc
 
 
@@ -146,29 +169,25 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
     src = bufs[gemm.src]
     b = src.shape[0]
     t = 0
-    if gemm.is_conv:
-        cols = im2col(src, gemm.ksize, gemm.stride, gemm.padding)
-        xin = cols.reshape(-1, cols.shape[-1])
-    elif gemm.seq:
-        src = tokens(src)
-        t = src.shape[1]
-        xin = src.reshape(-1, src.shape[-1])
-    else:
-        if src.ndim == 4:
+    with jax.named_scope("im2col"):
+        if gemm.is_conv:
+            cols = im2col(src, gemm.ksize, gemm.stride, gemm.padding)
+            xin = cols.reshape(-1, cols.shape[-1])
+        elif gemm.seq:
+            src = tokens(src)
+            t = src.shape[1]
+            xin = src.reshape(-1, src.shape[-1])
+        elif src.ndim == 4:
             xin = src.reshape(b, -1)             # NHWC flatten
         else:
             xin = src
 
-    xq, xs = quantize_symmetric(xin, cfg.input_bits)
-    y_int = kernels.gemm(xq.astype(jnp.int8), st.w8, adc_bits=cfg.adc_bits,
+    with jax.named_scope("quantize"):
+        xq, xs = quantize_symmetric(xin, cfg.input_bits)
+        xq = xq.astype(jnp.int8)
+    y_int = kernels.gemm(xq, st.w8, adc_bits=cfg.adc_bits,
                          rows=gemm.tile_rows, block_m=block_m,
                          block_n=block_n, interpret=interpret)
-    # the weight scale divides out of the stored amax IN-GRAPH so the
-    # dequant product keeps the functional reference's HLO shape
-    # (quantize_scale docstring; DESIGN.md §5)
-    ws = quantize_scale(st.w_amax, cfg.weight_bits)
-    scale = (xs * ws).astype(jnp.float32).reshape(1, 1)
-
     act, pool, window, img_hw, norm = "none", "none", 0, 0, "none"
     softmax, res = False, None
     out_hw = gemm.out_hw
@@ -181,8 +200,7 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
         elif op.kind == "layernorm":
             norm = "layer"
         elif op.kind == "residual":
-            r = bufs[op.res_src]
-            res = r.reshape(-1, r.shape[-1])
+            res = bufs[op.res_src]
         elif op.kind in ("maxpool", "avgpool"):
             pool = "max" if op.kind == "maxpool" else "avg"
             window, img_hw, out_hw = op.window, op.in_hw, op.out_hw
@@ -195,15 +213,23 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
     if softmax and drop_softmax:
         softmax = False
         dst = gemm.dst
-    out = kernels.epilogue(y_int, scale, st.bias, res, act=act, pool=pool,
-                           window=window, img_hw=img_hw, softmax=softmax,
-                           norm=norm, gamma=st.ln_g, beta=st.ln_b,
-                           block_m=block_m, block_n=block_n,
-                           interpret=interpret)
-    if gemm.is_conv:
-        out = out.reshape(b, out_hw, out_hw, -1)
-    elif gemm.seq and pool != "seqmean":
-        out = out.reshape(b, t, -1)
+    with jax.named_scope("epilogue"):
+        # the weight scale divides out of the stored amax IN-GRAPH so the
+        # dequant product keeps the functional reference's HLO shape
+        # (quantize_scale docstring; DESIGN.md §5)
+        ws = quantize_scale(st.w_amax, cfg.weight_bits)
+        scale = (xs * ws).astype(jnp.float32).reshape(1, 1)
+        if res is not None:
+            res = res.reshape(-1, res.shape[-1])
+        out = kernels.epilogue(y_int, scale, st.bias, res, act=act,
+                               pool=pool, window=window, img_hw=img_hw,
+                               softmax=softmax, norm=norm, gamma=st.ln_g,
+                               beta=st.ln_b, block_m=block_m,
+                               block_n=block_n, interpret=interpret)
+        if gemm.is_conv:
+            out = out.reshape(b, out_hw, out_hw, -1)
+        elif gemm.seq and pool != "seqmean":
+            out = out.reshape(b, t, -1)
     return dst, out, y_int
 
 
@@ -246,17 +272,18 @@ def stage_outputs(packed: PackedProgram, x: jnp.ndarray, *,
     stages = program.stages()
     last = _last_reads(stages)
     for si, ((gemm, posts), st) in enumerate(zip(stages, packed.stages)):
-        if gemm.kind == "dyn_gemm":
-            dst = posts[-1].dst if posts else gemm.dst
-            out, acc = _dyn_stage(gemm, posts, src, cfg, block_m=block_m,
-                                  block_n=block_n, interpret=interpret,
-                                  kernels=kernels)
-        else:
-            dst, out, acc = _static_stage(
-                gemm, posts, st, src, cfg, block_m=block_m,
-                block_n=block_n, interpret=interpret,
-                drop_softmax=return_logits and si == len(stages) - 1,
-                kernels=kernels)
+        dst = posts[-1].dst if posts else gemm.dst
+        with jax.named_scope(f"s{si:02d}.{dst}"):
+            if gemm.kind == "dyn_gemm":
+                out, acc = _dyn_stage(gemm, posts, src, cfg,
+                                      block_m=block_m, block_n=block_n,
+                                      interpret=interpret, kernels=kernels)
+            else:
+                dst, out, acc = _static_stage(
+                    gemm, posts, st, src, cfg, block_m=block_m,
+                    block_n=block_n, interpret=interpret,
+                    drop_softmax=return_logits and si == len(stages) - 1,
+                    kernels=kernels)
         bufs[dst] = out
         yield StageOutput(dst, out, acc)
         # drop buffers no later stage reads: eager forwards hold only

@@ -275,3 +275,54 @@ def test_buckets_roundtrip_and_packed_by_default(tmp_path):
     assert loaded.buckets == model.buckets
     assert loaded.packed is not None and len(loaded.packed.stages) == \
         len(model.packed.stages)
+
+
+# ---------------------------------------------------------------------------
+# tracing: run's host spans
+# ---------------------------------------------------------------------------
+
+def _spans(trace_dir):
+    """The ``repro.`` host spans of the trace under ``trace_dir``:
+    (name, start_ns, end_ns, args)."""
+    import glob
+    import os
+
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats))
+                   for plane in data.planes for line in plane.lines
+                   for e in line.events if e.name.startswith("repro.")),
+                  key=lambda s: (s[1], -s[2]))     # parents first
+
+
+def test_run_writes_spans_under_a_trace(tmp_path):
+    """Under a profiler trace, a padded request writes ``repro.run`` and
+    its ``pad``, ``call`` and ``slice`` children, which share the
+    request's id; the first call of a bucket says ``new=1``."""
+    graph, model, _ = _model_and_input()
+    jax.block_until_ready(model.run(jnp.zeros(graph.input_shape(5))))
+    with jax.profiler.trace(str(tmp_path)):
+        jax.block_until_ready(model.run(jnp.zeros(graph.input_shape(7))))
+        jax.block_until_ready(model.run(jnp.zeros(graph.input_shape(3))))
+    spans = _spans(str(tmp_path))
+    names = [s[0] for s in spans]
+    assert names == ["repro.run", "repro.run.pad", "repro.run.call",
+                     "repro.run.slice"] * 2
+    for req, (parent, pad, call, slc) in zip(
+            (2, 3), (spans[:4], spans[4:])):
+        assert all(s[3]["request"] == req for s in (parent, pad, call, slc))
+        assert parent[1] <= pad[1] <= pad[2] <= call[1] <= call[2] \
+            <= slc[1] <= slc[2] <= parent[2]
+    assert [(s[3]["batch"], s[3]["bucket"]) for s in spans[::4]] == [
+        (7, 8), (3, 4)]
+    assert [(s[3]["bucket"], s[3]["new"]) for s in spans[2::4]] == [
+        (8, 0), (4, 1)]
+
+
+def test_compiled_text_is_the_scoped_program():
+    graph, model, x = _model_and_input()
+    text = model.compiled_text(x)
+    assert text.startswith("HloModule")
+    assert "/s00." in text and "/quantize/" in text

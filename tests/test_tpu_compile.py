@@ -17,6 +17,7 @@ may load it.  All such tests live in this one file for that reason.
 import importlib.util
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -103,14 +104,41 @@ def test_fb_epilogue_compiles(one_chip, case):
         _shape(one_chip, (n,), f32), res, vec, vec)
 
 
+@pytest.fixture(scope="module")
+def whole_program(one_chip):
+    """net -> (model, HLO text of its whole program at batch 8), each
+    compiled once for the file."""
+    done = {}
+
+    def get(net):
+        if net not in done:
+            graph = vit_tiny_graph(depth=2) if net == "vit_tiny" else net
+            model = api.compile(graph, smoke.CLIP_FREE)
+            packed = jax.tree.map(
+                lambda a: _shape(one_chip, a.shape, a.dtype), model.packed)
+            x = _shape(one_chip, model.program.input_shape(8), jnp.float32)
+            done[net] = model, _compile(
+                lambda pk, v: execute_packed(pk, v, interpret=False),
+                packed, x)
+        return done[net]
+    return get
+
+
 @pytest.mark.parametrize("net", ["resnet18", "vit_tiny"])
-def test_whole_program_compiles(one_chip, net):
-    graph = vit_tiny_graph(depth=2) if net == "vit_tiny" else net
-    model = api.compile(graph, smoke.CLIP_FREE)
-    packed = jax.tree.map(lambda a: _shape(one_chip, a.shape, a.dtype),
-                          model.packed)
-    x = _shape(one_chip, model.program.input_shape(8), jnp.float32)
-    text = _compile(lambda pk, v: execute_packed(pk, v, interpret=False),
-                    packed, x)
+def test_whole_program_compiles(whole_program, net):
+    model, text = whole_program(net)
     # one crossbar GEMM and one fused epilogue per static stage at least
     assert text.count("tpu_custom_call") >= 2 * len(model.program.stages())
+
+
+def test_kernel_names_survive_in_the_compiled_program(whole_program):
+    """Trace readers classify kernels by their custom call's instruction
+    name, which the kernels' ``pallas_call(name=...)`` sets: one
+    ``mounted_gemm.*`` and one ``fb_epilogue.*`` per ResNet-18 stage."""
+    model, text = whole_program("resnet18")
+    stages = len(model.program.stages())
+    assert stages == 21
+    for kernel in ("mounted_gemm", "fb_epilogue"):
+        calls = re.findall(rf"^\s*(?:ROOT )?%{kernel}(?:\.\d+)? = .*"
+                           r"custom-call\(", text, re.M)
+        assert len(calls) == stages, kernel
