@@ -9,8 +9,8 @@ network of the zoo at its full geometry: ``alexnet``, ``vgg16`` and
 ``resnet18`` at 32x32, ``vit_tiny`` at depth 12 (dim 192, 3 heads, MLP
 ratio 4, 64 tokens).  Weights are random from a fixed seed.  Phases:
 
-* kernels — ``mounted_gemm`` on the exact and the sliced path at real
-  stage shapes, bit-exact against the XLA integer references of
+* kernels — ``mounted_gemm`` on the exact path (mount and dense
+  layouts) and the sliced path at real stage shapes, bit-exact against the XLA integer references of
   ``kernels/ref.py``; ``fb_epilogue`` in every mode against its XLA
   reference, held to ``FB_TAIL_ULP`` normwise ulps (DESIGN.md §5);
 * serve — request batches of 1, 3 and 8 through ``CompiledModel.run``
@@ -60,7 +60,9 @@ from repro.api import HurryConfig  # noqa: E402
 from repro.api.zoo import GRAPHS, vit_tiny_graph  # noqa: E402
 from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.kernels import ref  # noqa: E402
-from repro.kernels.crossbar_gemm import mount_layout, mounted_gemm  # noqa: E402
+from repro.kernels.crossbar_gemm import (dense_blocks,  # noqa: E402
+                                        dense_layout, mount_layout,
+                                        mounted_gemm)
 from repro.kernels.fb_epilogue import fb_epilogue  # noqa: E402
 from repro.kernels.ops import interpret_default  # noqa: E402
 from repro.models.cnn import fp_matmul, make_crossbar_matmul  # noqa: E402
@@ -203,9 +205,11 @@ def first_divergence(model, x, oracle: dict
     return None
 
 
-def xla_gemm(x, w, *, rows, **_):
-    """The clip-free crossbar GEMM as one XLA integer dot on the mounted
+def xla_gemm(x, w, *, rows, layout="mounted", **_):
+    """The clip-free crossbar GEMM as one XLA integer dot on the laid-out
     operands (zero rows add nothing): ``mounted_gemm``'s reference."""
+    if layout == "dense":
+        return ref.crossbar_gemm_exact_ref(x, w[:x.shape[1]])
     return ref.crossbar_gemm_exact_ref(mount_layout(x, rows, 1), w)
 
 
@@ -262,6 +266,14 @@ def stage_check(model, x, oracle: dict) -> dict:
                 tail_stage=tail_stage, oracle=dep, oracle_stage=dep_stage)
 
 
+def layout_tally(model) -> str:
+    """How many stages took each K layout (``PackedProgram.layouts``)."""
+    layouts = model.packed.layouts()
+    return "stage layouts " + ", ".join(
+        f"{layouts.count(kind)}/{len(layouts)} {kind}"
+        for kind in ("dense", "mounted"))
+
+
 def check(checks: list, name: str, ok: bool, detail: str) -> None:
     print(f"check {'PASS' if ok else 'FAIL'} {name}: {detail}", flush=True)
     checks.append((name, ok))
@@ -287,6 +299,12 @@ def kernel_phase(gemm_cases=GEMM_CASES, epilogue_cases=EPILOGUE_CASES,
         yr = ref.crossbar_gemm_exact_ref(x, w)
         check(checks, f"gemm/{name}/exact", np.array_equal(y, yr),
               f"M={m} K={k} N={n} rows={rows} -> K_mounted={wm.shape[0]}")
+        wd = dense_layout(w, 0)
+        y = mounted_gemm(x, wd, adc_bits=9, rows=rows, layout="dense",
+                         interpret=interpret)
+        check(checks, f"gemm/{name}/dense", np.array_equal(y, yr),
+              f"dense layout: K_dense={wd.shape[0]}, K blocks of "
+              f"{dense_blocks(k)[1]}")
         y = mounted_gemm(x, wm, adc_bits=7, rows=rows, exact=False,
                          interpret=interpret)
         yr = ref.crossbar_gemm_ref(x, w, adc_bits=7, rows=rows)
@@ -324,7 +342,8 @@ def serve_phase(net: str, depth: int, batches=BATCHES,
     t0 = time.perf_counter()
     model = api.compile(graph, CLIP_FREE, params=params)
     print(f"smoke {net}: api.compile {time.perf_counter() - t0:.2f} s "
-          f"(graph lowering + weight packing)", flush=True)
+          f"(graph lowering + weight packing); {layout_tally(model)}",
+          flush=True)
     agree, worst = [], 0
     for b in batches:
         x = request(graph, b, seed)
@@ -414,6 +433,8 @@ def sliced_phase(net: str, depth: int, batch: int = 8,
     graph = graph_of(net, depth)
     params = random_params(graph, seed)
     model = api.compile(graph, SLICED, params=params)
+    print(f"smoke {net}: sliced (8-bit ADC) {layout_tally(model)}",
+          flush=True)
     x = request(graph, batch, seed)
     t0 = time.perf_counter()
     out = np.asarray(jax.block_until_ready(model.run(x, logits=True)))

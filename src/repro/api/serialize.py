@@ -1,6 +1,6 @@
 """Persist compiled models: ``CompiledModel.save`` / ``api.load``.
 
-Format (single ``.npz`` file, version 4):
+Format (single ``.npz`` file, version 5):
 
 * ``__meta__`` — a JSON document holding the graph (name, input spec,
   ``LayerSpec`` list), the ``HurryConfig``, the batch-bucket ladder,
@@ -11,8 +11,8 @@ Format (single ``.npz`` file, version 4):
 * ``p0 .. pN`` — the parameter arrays, ordered by the ``params`` index
   in the meta document (``[layer, key]`` pairs).
 * ``w0/wa0/wb0 .. `` — the **packed weight planes** (since version 2):
-  per GEMM stage the int8 mount-plane matrix (pre-quantized, im2col
-  layout, K in the mount layout of ``kernels.crossbar_gemm``), the f32
+  per GEMM stage the int8 plane matrix (pre-quantized, in the stage's
+  im2col order and K layout, see version 5 below), the f32
   weight ``amax``, and the f32 bias, in ``program.stages()`` order.  A loaded model serves from
   these directly — ``api.load(...).run(...)`` never quantizes a weight
   (the analogue of shipping a programmed chip, not a netlist).
@@ -41,12 +41,22 @@ Version 4 changes only the planes' K layout: each mount's
 whole mounts.  It also stores ``block_m``/``block_n`` as ``None`` when
 the kernels pick their own tiles.
 
+Version 5 stores each stage's planes in the layout
+``program.pack.stage_layout`` gives it, listed per stage in the meta's
+``layouts``: a clip-free weight-mounted stage is **dense** — a conv's K
+in ``(i, j, c)`` order, K zero-padded only at its end to whole kernel
+blocks (``dense_layout``); every other stage keeps version 4's
+``(c, i, j)`` order and mount layout.
+
 Version-1 files (pre-packing) still load: the packed planes are
 re-derived once from the saved params at load time (repack fallback).
-Version-2/3 files load without requantizing: their planes are re-laid
-into the mount layout (exact — both paddings are zero rows), and the
-old 512x512 block-size defaults they stored become ``None``.  Version-2
-files have no sequence fields and no ln stages.
+Version-2/3/4 files load without requantizing: each plane is cut back
+to its real K rows (versions 2-3 padded K at its end, version 4 each
+mount) and laid out as version 5 lays that stage out — for a dense conv
+the rows are permuted from ``(c, i, j)`` to ``(i, j, c)`` — exact, as
+every padding is zero rows.  The old 512x512 block-size defaults that
+versions 2-3 stored become ``None``.  Version-2 files have no sequence
+fields and no ln stages.
 """
 
 from __future__ import annotations
@@ -58,17 +68,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.workload import LayerSpec
-from repro.kernels.crossbar_gemm import mount_layout
+from repro.kernels.crossbar_gemm import dense_layout, mount_layout, mount_rows
 from repro.program.compile import CrossbarProgram, MountRound, ProgramOp
-from repro.program.pack import (PackedProgram, PackedStage, pack_program)
+from repro.program.pack import (PackedProgram, PackedStage, pack_program,
+                                stage_layout)
 from repro.program.serve import BUCKETS
 
 from .config import HurryConfig
 from .graph import NetworkGraph
 
 FORMAT = "repro.api/compiled-model"
-VERSION = 4
-_LOADABLE = (1, 2, 3, 4)
+VERSION = 5
+_LOADABLE = (1, 2, 3, 4, 5)
 _OLD_BLOCK_DEFAULT = 512      # versions <= 3 stored it for "no override"
 
 
@@ -102,14 +113,27 @@ def _program_from_meta(meta: dict) -> CrossbarProgram:
         in_features=meta["in_features"], in_seq=meta.get("in_seq", 0))
 
 
-def _relayout(w8: jnp.ndarray, op: ProgramOp) -> jnp.ndarray:
-    """A version 2/3 plane (K zero-padded at its end to whole
-    ``tile_rows`` mounts) in the mount layout: cut to the real K, then
-    lay out.  Exact, as the old padding was zero rows."""
+def _relayout(w8: jnp.ndarray, op: ProgramOp, cfg,
+              version: int) -> jnp.ndarray:
+    """A version 2-4 plane in the layout version 5 gives its stage: cut
+    to the real K rows (versions 2-3 padded K at its end to whole
+    mounts, version 4 each mount to 128-row tiles), then laid out dense
+    (a conv's rows permuted from ``(c, i, j)`` to ``(i, j, c)``) or
+    mounted.  Exact: every padding is zero rows."""
     if w8.size == 0:                      # dynamic-stage placeholder
         return w8
-    k = max(r.k1 for r in op.mount_rounds)
-    return mount_layout(w8[:k], op.tile_rows, 0)
+    k, rows = max(r.k1 for r in op.mount_rounds), op.tile_rows
+    if version == 4 and k > rows:
+        n = -(-k // rows)
+        w8 = w8.reshape(n, mount_rows(rows), -1)[:, :rows]
+        w8 = w8.reshape(n * rows, -1)
+    w8 = w8[:k]
+    if stage_layout(op, cfg) == "mounted":
+        return mount_layout(w8, rows, 0)
+    if op.is_conv:                        # (c, i, j) -> (i, j, c)
+        kk = op.ksize * op.ksize
+        w8 = w8.reshape(k // kk, kk, -1).transpose(1, 0, 2).reshape(k, -1)
+    return dense_layout(w8, 0)
 
 
 def save_model(model, path: str) -> str:
@@ -141,6 +165,7 @@ def save_model(model, path: str) -> str:
         "params": index,
         "packed_stages": len(packed.stages),
         "ln_stages": ln_stages,
+        "layouts": list(packed.layouts()),
         "buckets": list(model.buckets),
     }
     with open(path, "wb") as f:
@@ -185,10 +210,16 @@ def load_model(path: str):
         if len(stages) != len(gemms):
             raise ValueError(f"{path}: corrupt file — {len(stages)} packed "
                              f"weight planes for {len(gemms)} GEMM stages")
-        if version < 4:
-            stages = tuple(dataclasses.replace(st, w8=_relayout(st.w8, op))
-                           for st, op in zip(stages, gemms))
+        if version < 5:
+            stages = tuple(
+                dataclasses.replace(st, w8=_relayout(st.w8, op, program.cfg,
+                                                     version))
+                for st, op in zip(stages, gemms))
         packed = PackedProgram(stages=stages, program=program)
+        if version == 5 and list(packed.layouts()) != meta["layouts"]:
+            raise ValueError(f"{path}: corrupt file — stage layouts "
+                             f"{meta['layouts']} where this program lays "
+                             f"out {list(packed.layouts())}")
     gm = meta["graph"]
     graph = NetworkGraph(
         name=gm["name"], in_hw=gm["in_hw"], in_ch=gm["in_ch"],

@@ -201,10 +201,20 @@ def quantize_scale(amax: jnp.ndarray, bits: int = 8) -> jnp.ndarray:
     return jnp.maximum(amax, 1e-8) / qmax
 
 
-def quantize_symmetric(x: jnp.ndarray, bits: int = 8) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Symmetric per-tensor quantization -> (int values, scale)."""
+def quantize_symmetric(x: jnp.ndarray, bits: int = 8,
+                       amax: jnp.ndarray | None = None
+                       ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Symmetric per-tensor quantization -> (int values, scale).
+
+    ``amax`` is the ``max(|.|)`` the scale derives from, ``x``'s own by
+    default; a caller quantizing a tensor before it expands it (a conv
+    input before im2col) passes the max over the elements the expansion
+    reads, which is the expanded matrix's own.
+    """
     qmax = (1 << (bits - 1)) - 1
-    scale = quantize_scale(jnp.max(jnp.abs(x)), bits)
+    if amax is None:
+        amax = jnp.max(jnp.abs(x))
+    scale = quantize_scale(amax, bits)
     q = jnp.clip(jnp.round(x / scale), -qmax - 1, qmax).astype(jnp.int32)
     return q, scale
 

@@ -31,8 +31,9 @@ Two statically-dispatched compute paths (DESIGN.md §"Exact fast path"):
   ``clip_possible``).  When clipping *can* fire the fast path is
   refused and the sliced path runs.
 
-Grid: (M/bm, N/bn, n_mounts) — each K block is one mount (one
-"array" read); both paths do a single MXU dispatch per tile.
+Grid: (M/bm, N/bn, n_k) — each K block is one mount (one "array"
+read), or one block of the dense layout below; both paths do a single
+MXU dispatch per tile.
 
 **Mount layout.** A stage's ``tile_rows`` is the array height minus the
 rows its functional blocks reserve, so it is rarely a multiple of the
@@ -48,6 +49,17 @@ full length as the block, which Mosaic accepts at any size.
 ``mounted_gemm`` takes such weights and lays out the streamed
 activation itself, and ``crossbar_gemm`` also lays out the weights for
 callers holding plain ``(K, N)`` operands.
+
+**Dense layout.** Where clipping cannot fire (``clip_possible`` is
+False), the int32 sums are the same for any K order and any K
+blocking, so mount boundaries mean nothing and their padding is pure
+overhead (+78 % K for a 576-row contraction in 485-row mounts).  Such
+operands carry K in the *dense layout* (``dense_layout``): the whole
+contraction as one block when it is at most ``DENSE_BLOCK_K`` rows,
+else K zero-padded at its end to whole blocks of a multiple of 128 rows
+(``dense_blocks``).  ``mounted_gemm(..., layout="dense")`` takes an
+activation in that order (only its end is padded, never re-mounted) and
+runs the exact kernel, one K block per grid step.
 
 Block activation is pad-to-block: operands whose M/N are not multiples
 of the block sizes (clamped to M, N rounded up to the (8, 128) tiling)
@@ -135,12 +147,12 @@ def _kernel_sliced(x_ref, w_ref, o_ref, acc_ref, *, adc_max: int, n_k: int):
 def _kernel_exact(x_ref, w_ref, o_ref, acc_ref, *, n_k: int, f32_dot: bool):
     """Clip-free fast path: plain int8 -> int32 GEMM, no bit slicing.
 
-    When the per-chunk partial sum provably fits f32's integer range
-    (``rows * 128 * 128 <= 2^24``, always true at the paper's ADC
-    resolutions since the exact path requires ``rows <= 2^adc_bits - 1``)
-    the chunk dot runs in f32 — bit-exact, and it hits the fast matmul
-    path on every backend (int32 dot has none on CPU) — with cross-chunk
-    accumulation still in int32.
+    When the per-block partial sum provably fits f32's integer range
+    (``block_k * 128 * 128 <= 2^24``, i.e. K blocks of at most 1024
+    rows, which both layouts keep to on the exact path) the block dot
+    runs in f32 — bit-exact, and it hits the fast matmul path on every
+    backend (int32 dot has none on CPU) — with cross-block accumulation
+    still in int32.
     """
     ki = pl.program_id(2)
 
@@ -196,6 +208,42 @@ def mount_layout(a: jnp.ndarray, rows: int, axis: int) -> jnp.ndarray:
     return a.reshape(a.shape[:axis] + (n * height,) + a.shape[axis + 2:])
 
 
+# the tallest K block whose f32 chunk dot stays exact: 1024 * 128 * 128
+# = 2^24 (``_kernel_exact``)
+DENSE_BLOCK_K = 1024
+
+
+def dense_blocks(k: int) -> tuple[int, int]:
+    """(padded K, K block) of the dense layout of a ``k``-row contraction.
+
+    Up to ``DENSE_BLOCK_K`` rows K is one block, unpadded.  Beyond it, K
+    is cut into blocks of a multiple of 128 rows, at most
+    ``DENSE_BLOCK_K``: among the fewest such blocks up to twice as many,
+    the count that pads K least (4608 rows: six blocks of 768, no
+    padding).
+    """
+    if k <= DENSE_BLOCK_K:
+        return k, k
+    tiles = -(-k // LANE)
+    fewest = -(-tiles // (DENSE_BLOCK_K // LANE))
+    n = min(range(fewest, 2 * fewest + 1),
+            key=lambda n: (-(-tiles // n) * n, n))
+    block = -(-tiles // n) * LANE
+    return n * block, block
+
+
+def dense_layout(a: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Zero-pad ``a``'s contraction ``axis`` to its dense-layout length
+    (``dense_blocks``); rows keep their order."""
+    k = a.shape[axis]
+    kp, _ = dense_blocks(k)
+    if kp == k:
+        return a
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, kp - k)
+    return jnp.pad(a, widths)
+
+
 def crossbar_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
                   rows: int = 512, block_m: int | None = None,
                   block_n: int | None = None, interpret: bool = False,
@@ -211,26 +259,36 @@ def crossbar_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
 
 
 @functools.partial(jax.jit, static_argnames=("adc_bits", "rows", "block_m",
-                                             "block_n", "interpret", "exact"))
+                                             "block_n", "interpret", "exact",
+                                             "layout"))
 def mounted_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
                  rows: int = 512, block_m: int | None = None,
                  block_n: int | None = None, interpret: bool = False,
-                 exact: bool | None = None) -> jnp.ndarray:
-    """(M, K) int8 activation x mounted int8 weights -> (M, N) int32.
+                 exact: bool | None = None,
+                 layout: str = "mounted") -> jnp.ndarray:
+    """(M, K) int8 activation x laid-out int8 weights -> (M, N) int32.
 
-    ``w`` is already in the mount layout of ``rows``-row mounts
-    (``mount_layout``, as ``pack.plane_pack`` stores it); ``x`` is laid
-    out the same way here.  ``rows`` is the real row count of each mount
-    (the ADC chunk).  ``exact=None`` (default) auto-dispatches: the
-    clip-free single-GEMM fast path when ``rows <= 2^adc_bits - 1``
+    ``layout="mounted"`` (default): ``w`` is in the mount layout of
+    ``rows``-row mounts (``mount_layout``, as ``pack.plane_pack`` stores
+    it); ``x`` is laid out the same way here.  ``rows`` is the real row
+    count of each mount (the ADC chunk).  ``exact=None`` auto-dispatches:
+    the clip-free single-GEMM fast path when ``rows <= 2^adc_bits - 1``
     (bit-identical, see ``clip_possible``), else the plane-packed sliced
     path.  ``exact=False`` forces the faithful sliced path;
     ``exact=True`` asserts clip-freeness and raises if ADC saturation
-    could fire.  Block sizes default per path (``tiling.py``).
+    could fire.
 
-    M and N need not divide the (clamped) block sizes: operands are
-    zero-padded up to the block multiple, full tiles run, and the output
-    is sliced back to (M, N) — slice-exact (see module docstring).
+    ``layout="dense"``: ``w`` is in the dense layout (``dense_layout``)
+    and ``x`` holds the same K rows in the same order, unpadded; only
+    its end is zero-padded here.  Dense operands are exact by contract:
+    the caller's mounts of ``rows`` rows are clip-free (raises
+    otherwise, and for ``exact=False``), and K blocks follow
+    ``dense_blocks``, not mounts.
+
+    Block sizes default per path (``tiling.py``).  M and N need not
+    divide the (clamped) block sizes: operands are zero-padded up to the
+    block multiple, full tiles run, and the output is sliced back to
+    (M, N) — slice-exact (see module docstring).
 
     Its ops sit in two named scopes: ``mount`` (the activation's mount
     layout, the block pads and the slice back) and ``gemm`` (the
@@ -238,21 +296,34 @@ def mounted_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
     """
     assert x.dtype == jnp.int8 and w.dtype == jnp.int8
     M, K = x.shape
-    rows = min(rows, K)
-    with jax.named_scope("mount"):
-        x = mount_layout(x, rows, 1)
-    K = x.shape[1]
+    pk = 0
+    if layout == "dense":
+        if exact is False or clip_possible(rows, adc_bits):
+            raise ValueError(
+                f"the dense layout is exact-only: rows={rows} with a "
+                f"{adc_bits}-bit ADC can clip, or exact=False was asked")
+        exact = True
+        kp, block_k = dense_blocks(K)
+        pk = kp - K
+    elif layout == "mounted":
+        rows = min(rows, K)
+        with jax.named_scope("mount"):
+            x = mount_layout(x, rows, 1)
+        K = x.shape[1]
+        block_k = min(K, mount_rows(rows))
+        if exact is None:
+            exact = not clip_possible(rows, adc_bits)
+        elif exact and clip_possible(rows, adc_bits):
+            raise ValueError(
+                f"exact=True but ADC clipping can fire: rows={rows} > "
+                f"2^{adc_bits} - 1 = {(1 << adc_bits) - 1}; use the sliced "
+                "path")
+    else:
+        raise ValueError(f"layout {layout!r} not in ('mounted', 'dense')")
     Kw, N = w.shape
-    if K != Kw:
+    if K + pk != Kw:
         raise ValueError(f"weights have {Kw} rows; the activation laid out "
-                         f"as {rows}-row mounts has {K} (see mount_layout)")
-    block_k = min(K, mount_rows(rows))
-    if exact is None:
-        exact = not clip_possible(rows, adc_bits)
-    elif exact and clip_possible(rows, adc_bits):
-        raise ValueError(
-            f"exact=True but ADC clipping can fire: rows={rows} > "
-            f"2^{adc_bits} - 1 = {(1 << adc_bits) - 1}; use the sliced path")
+                         f"{layout} has {K + pk} (see {layout}_layout)")
     bm, bn = default_blocks("exact" if exact else "sliced")
     # clamp to the operand rounded up to the (8, 128) tiling: the sliced
     # path's plane reshapes need aligned tiles even for tiny M or N
@@ -261,16 +332,16 @@ def mounted_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
     # pad-to-block activation: zero rows/cols are slice-exact (docstring)
     pm, pn = -M % block_m, -N % block_n
     with jax.named_scope("mount"):
-        if pm:
-            x = jnp.pad(x, ((0, pm), (0, 0)))
+        if pm or pk:
+            x = jnp.pad(x, ((0, pm), (0, pk)))
         if pn:
             w = jnp.pad(w, ((0, 0), (0, pn)))
     Mp, Np = M + pm, N + pn
-    n_k = K // block_k
+    n_k = Kw // block_k
     if exact:
-        # f32 chunk dots are exact iff |partial| <= rows * 128^2 <= 2^24
+        # f32 block dots are exact iff |partial| <= block_k * 128^2 <= 2^24
         kernel = functools.partial(_kernel_exact, n_k=n_k,
-                                   f32_dot=rows * 128 * 128 <= 1 << 24)
+                                   f32_dot=block_k * 128 * 128 <= 1 << 24)
     else:
         kernel = functools.partial(_kernel_sliced,
                                    adc_max=(1 << adc_bits) - 1, n_k=n_k)

@@ -8,13 +8,28 @@ scheduled program is *the* thing that computes:
   analogue of programming conductances), so the hot loop only
   quantizes *activations* — the data-dependent quantities;
 * every GEMM is ONE ``mounted_gemm`` Pallas dispatch: the kernel's K
-  grid activates all row mounts of the stage in a single call
-  (``rows=tile_rows``, the kernel lays the streamed activation out as
-  mounts like the packed weights — each K block is one physical array
-  read with per-mount ADC chunk semantics, partial sums chained in
-  int32 inside the kernel's accumulator: SnA across stacked arrays,
-  bit-identical to the former per-mount ``lax.scan`` because int32
-  addition is associative);
+  grid activates all row mounts of the stage in a single call, partial
+  sums chained in int32 inside the kernel's accumulator (SnA across
+  stacked arrays, bit-identical to the former per-mount ``lax.scan``
+  because int32 addition is associative).  How the stage's operand is
+  built depends on its layout (``pack.stage_layout``):
+
+  - **dense** — every weight-mounted stage on the exact path (its
+    ``tile_rows``-row mounts cannot clip; all stages of the clip-free
+    configs).  There the int32 sums are the same for any K order and
+    any K blocking, so mount boundaries may be ignored, and the int8
+    operand is built once, in the layout the kernel reads
+    (``_dense_operand``): the stage input is quantized first — its
+    ``amax`` over exactly the elements im2col reads, so values and
+    scale are bit-identical to quantizing the patch matrix — then the
+    ``k*k`` taps are cut from the int8 tensor and concatenated along
+    the channels (``(i, j, c)`` order, channels minor and lane-dense),
+    and the kernel pads K only to whole blocks of at most 1024 rows;
+  - **mounted** — stages whose mounts can clip: the f32 im2col in
+    ``(c, i, j)`` order, quantized, then laid out by the kernel as
+    mounts like the packed weights (``rows=tile_rows``), each K block
+    one physical array read with per-mount ADC chunk semantics — there
+    K order and mount membership are semantics;
 * every post-op chain (shift-and-add requant -> bias -> residual ->
   ReLU/GELU -> layer norm -> max/avg/seq-mean pool window | softmax)
   runs in ONE pass of the fused ``fb_epilogue`` Pallas kernel over the
@@ -39,7 +54,8 @@ Intermediate buffers are dropped as soon as no later stage reads them
 live frontier of the dataflow graph, not every activation.
 
 Quantization mirrors ``core/crossbar.crossbar_linear`` exactly
-(per-tensor symmetric int8 of the full im2col/token matrix and weight
+(per-tensor symmetric int8 of the full im2col/token matrix — for a
+dense stage, of the input elements that matrix copies — and weight
 matrix; per-(batch, head) tensors for dynamic stages), so under a
 clip-free config the program forward is bit-identical to the
 functional-model forward when both are jitted (identical FMA
@@ -51,10 +67,12 @@ experiment: the program path models a clean chip.
 one phase scope inside it, so a compiled program's ``op_name``
 metadata says which stage and which phase each instruction serves:
 
-* ``im2col`` — im2col, token and flatten reshapes, head splits;
+* ``im2col`` — im2col (a dense stage's tap concat on the int8
+  tensor), token and flatten reshapes, head splits;
 * ``quantize`` — the activation's amax reduction and int8 convert;
-* ``mount`` — ``mounted_gemm``'s mount layout, block pads and slice
-  back, and ``plane_pack`` of a dynamic stage's right-hand operand;
+* ``mount`` — ``mounted_gemm``'s mount layout, its K/M/N block pads and
+  slice back, and ``plane_pack`` of a dynamic stage's right-hand
+  operand;
 * ``gemm`` — the ``mounted_gemm`` kernel;
 * ``epilogue`` — the scale product, the ``fb_epilogue`` kernel and the
   output reshape.
@@ -80,10 +98,11 @@ from repro.core.crossbar import quantize_scale, quantize_symmetric
 from repro.kernels.crossbar_gemm import mounted_gemm
 from repro.kernels.fb_epilogue import fb_epilogue
 from repro.kernels.ops import interpret_default
-from repro.models.cnn import im2col
+from repro.models.cnn import im2col, im2col_read_mask
 
 from .compile import CrossbarProgram, ProgramOp
-from .pack import PackedProgram, PackedStage, pack_program, plane_pack
+from .pack import (PackedProgram, PackedStage, pack_program, plane_pack,
+                   stage_layout)
 from .sequence import merge_heads, split_qkv_heads, tokens
 
 
@@ -159,6 +178,45 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
     return out, acc
 
 
+def _gemm_rows(x: jnp.ndarray, seq: bool) -> jnp.ndarray:
+    """A linear stage's input as GEMM rows: tokens ``(B, T, D)`` ->
+    ``(B*T, D)``, an NHWC map flattened per image, a matrix as is."""
+    if x.ndim == 2:
+        return x
+    return x.reshape(-1, x.shape[-1]) if seq else x.reshape(x.shape[0], -1)
+
+
+def _dense_operand(gemm: ProgramOp, src: jnp.ndarray, bits: int
+                   ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """A dense stage's int8 GEMM operand ``(M, K)`` and its scale, built
+    in one pass in the kernel's K order (module docstring).
+
+    The stage input is quantized first, its ``amax`` taken over exactly
+    the pixels im2col reads (``im2col_read_mask``), so the int8 values and
+    the scale are bit-identical to quantizing the patch matrix: the same
+    scalar and the same elementwise expression on the same values.  The
+    patches are then cut from the int8 tensor channels-minor, in the
+    ``(i, j, c)`` order ``pack_weight`` gives a dense conv's weights.
+    """
+    with jax.named_scope("quantize"):
+        mag = jnp.abs(src)
+        if gemm.is_conv:
+            read = im2col_read_mask(src.shape[1], src.shape[2], gemm.ksize,
+                                    gemm.stride, gemm.padding)
+            if read is not None:         # unread pixels count as 0
+                mag = jnp.where(read, mag, 0.0)
+        xq, xs = quantize_symmetric(src, bits, amax=jnp.max(mag))
+        xq = xq.astype(jnp.int8)
+    with jax.named_scope("im2col"):
+        if gemm.is_conv:
+            xq = im2col(xq, gemm.ksize, gemm.stride, gemm.padding,
+                        channels_minor=True)
+            xq = xq.reshape(-1, xq.shape[-1])
+        else:
+            xq = _gemm_rows(xq, gemm.seq)
+    return xq, xs
+
+
 def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
                   st: PackedStage, bufs: Mapping, cfg, *,
                   block_m: int | None, block_n: int | None, interpret: bool,
@@ -169,25 +227,26 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
     src = bufs[gemm.src]
     b = src.shape[0]
     t = 0
-    with jax.named_scope("im2col"):
-        if gemm.is_conv:
-            cols = im2col(src, gemm.ksize, gemm.stride, gemm.padding)
-            xin = cols.reshape(-1, cols.shape[-1])
-        elif gemm.seq:
+    if gemm.seq:
+        with jax.named_scope("im2col"):
             src = tokens(src)
-            t = src.shape[1]
-            xin = src.reshape(-1, src.shape[-1])
-        elif src.ndim == 4:
-            xin = src.reshape(b, -1)             # NHWC flatten
-        else:
-            xin = src
-
-    with jax.named_scope("quantize"):
-        xq, xs = quantize_symmetric(xin, cfg.input_bits)
-        xq = xq.astype(jnp.int8)
+        t = src.shape[1]
+    layout = stage_layout(gemm, cfg)
+    if layout == "dense":
+        xq, xs = _dense_operand(gemm, src, cfg.input_bits)
+    else:
+        with jax.named_scope("im2col"):
+            if gemm.is_conv:
+                cols = im2col(src, gemm.ksize, gemm.stride, gemm.padding)
+                xin = cols.reshape(-1, cols.shape[-1])
+            else:
+                xin = _gemm_rows(src, gemm.seq)
+        with jax.named_scope("quantize"):
+            xq, xs = quantize_symmetric(xin, cfg.input_bits)
+            xq = xq.astype(jnp.int8)
     y_int = kernels.gemm(xq, st.w8, adc_bits=cfg.adc_bits,
                          rows=gemm.tile_rows, block_m=block_m,
-                         block_n=block_n, interpret=interpret)
+                         block_n=block_n, interpret=interpret, layout=layout)
     act, pool, window, img_hw, norm = "none", "none", 0, 0, "none"
     softmax, res = False, None
     out_hw = gemm.out_hw

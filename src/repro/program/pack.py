@@ -14,13 +14,25 @@ call:
   reduction; the executor re-derives the scalar scale in-graph via
   ``quantize_scale`` so the dequant product keeps the exact HLO shape
   of the functional reference — see that helper's docstring);
-* the conv im2col layout (``w.transpose(2, 0, 1, 3).reshape(kk, -1)``);
-* the mount layout (``kernels.crossbar_gemm.mount_layout``): K cut into
-  ``tile_rows``-row mounts, each zero-padded to the next multiple of 128
-  rows, so every mount round is one ADC chunk of exactly ``tile_rows``
-  real rows in a K block the TPU tiling accepts, and the executor
-  activates ALL mounts of a stage in one ``mounted_gemm`` K-grid
-  dispatch (block activation).
+* the K order and layout, chosen per stage by ``stage_layout``:
+
+  - **dense** — every weight-mounted stage whose ``tile_rows``-row
+    mounts cannot clip (``clip_possible`` is False: the exact path).
+    Its int32 sums are the same for any K order and any K blocking, so
+    mount boundaries carry no semantics: a conv's K is taken in
+    ``(i, j, c)`` order (``w.reshape(k*k*C, N)``, the order of the
+    executor's channels-minor im2col) and zero-padded only to whole
+    kernel blocks (``kernels.crossbar_gemm.dense_layout``);
+  - **mounted** — stages where an ADC clip can fire, and dynamic
+    attention stages.  K order and mount membership decide which
+    products share an ADC chunk, so a conv keeps the ``(c, i, j)``
+    im2col order (``w.transpose(2, 0, 1, 3).reshape(kk, -1)``) and the
+    mount layout (``kernels.crossbar_gemm.mount_layout``): K cut into
+    ``tile_rows``-row mounts, each zero-padded to the next multiple of
+    128 rows, so every mount round is one ADC chunk of exactly
+    ``tile_rows`` real rows in a K block the TPU tiling accepts, and the
+    executor activates ALL mounts of a stage in one ``mounted_gemm``
+    K-grid dispatch (block activation).
 
 The quantize+pad core is the standalone ``plane_pack`` helper — the
 SAME function the executor invokes **in-graph, per batch** on the
@@ -32,7 +44,8 @@ verbatim.
 The result is a ``PackedProgram`` — a jax pytree whose leaves are the
 per-stage ``(w8, w_amax, bias[, ln_g, ln_b])`` arrays and whose static
 treedef carries the (plan-free) program — that ``execute_packed``
-consumes directly.  Layer-norm FBs fused onto a stage carry their
+consumes directly.  ``PackedProgram.layouts()`` says which layout each
+stage took.  Layer-norm FBs fused onto a stage carry their
 gamma/beta here too, so the packed executor never reads the float
 param pytree.  Dynamic-operand stages own no weights: they pack as
 empty placeholders (their mounts materialize per batch in the
@@ -42,7 +55,7 @@ float math again.  Packing eagerly and quantizing under jit produce
 bit-identical planes: ``quantize_symmetric`` is abs/max/divide/round —
 none of it subject to FMA contraction (DESIGN.md §5).
 
-``repro.api`` persists the packed planes in its save format (version 4),
+``repro.api`` persists the packed planes in its save format (version 5),
 so ``api.load(...).run(...)`` never re-derives them (DESIGN.md §7).
 """
 
@@ -55,9 +68,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.crossbar import quantize_symmetric
-from repro.kernels.crossbar_gemm import mount_layout
+from repro.kernels.crossbar_gemm import (clip_possible, dense_layout,
+                                        mount_layout)
 
-from .compile import CrossbarProgram
+from .compile import CrossbarProgram, ProgramOp
 
 
 @jax.tree_util.register_dataclass
@@ -65,9 +79,10 @@ from .compile import CrossbarProgram
 class PackedStage:
     """One GEMM stage's chip-resident weights.
 
-    ``w8`` is the int8 mount-plane matrix ``(K_mounted, N)`` — im2col
-    layout applied, K in the mount layout (``mount_layout``) so the
-    kernel's K grid is exactly the stage's mount rounds; ``w_amax`` is the f32
+    ``w8`` is the int8 plane matrix ``(K_laid_out, N)`` — im2col order
+    and K layout as ``stage_layout`` chose (module docstring), so the
+    kernel's K grid is the stage's mount rounds (mounted) or its dense
+    K blocks (dense); ``w_amax`` is the f32
     per-tensor ``max(|w|)`` from which the executor derives the
     symmetric quantization scale in-graph (``quantize_scale``);
     ``bias`` the f32 per-column bias.  ``ln_g``/``ln_b`` are the fused
@@ -109,15 +124,33 @@ class PackedProgram:
     def cfg(self):
         return self.program.cfg
 
+    def layouts(self) -> tuple[str, ...]:
+        """``"dense"`` or ``"mounted"`` per stage, in stage order
+        (``stage_layout``)."""
+        return tuple(stage_layout(gemm, self.cfg)
+                     for gemm, _ in self.program.stages())
 
-def plane_pack(w: jnp.ndarray, *, tile_rows: int,
-               weight_bits: int = 8) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Mount a (K, N) float matrix: -> (int8 planes (K_mounted, N), f32 amax).
+
+def stage_layout(gemm: ProgramOp, cfg) -> str:
+    """The K layout of a stage's operands: ``"dense"`` for a
+    weight-mounted stage whose ``tile_rows``-row mounts cannot clip at
+    ``cfg.adc_bits`` (the exact path, where K order and blocking change
+    no sum), else ``"mounted"`` (module docstring)."""
+    if gemm.kind == "gemm" and not clip_possible(gemm.tile_rows,
+                                                 cfg.adc_bits):
+        return "dense"
+    return "mounted"
+
+
+def plane_pack(w: jnp.ndarray, *, tile_rows: int, weight_bits: int = 8,
+               layout: str = "mounted") -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Mount a (K, N) float matrix: -> (int8 planes (K_laid_out, N), f32 amax).
 
     Symmetric per-tensor int8 quantization at ``weight_bits``, K laid
     out as full ``tile_rows``-row mounts, each zero-padded to a multiple
     of 128 rows (``mount_layout``; zero rows add nothing to any bitline
-    count).
+    count), or in the dense layout (``dense_layout``: K padded only at
+    its end, to whole kernel blocks).
     Invoked once per weight at pack time — and **in-graph, per batch**
     on the quantized K/V head matrices of dynamic attention stages, the
     run-time analogue of programming conductances (DESIGN.md §9).  The
@@ -125,17 +158,27 @@ def plane_pack(w: jnp.ndarray, *, tile_rows: int,
     derives the scale through ``quantize_scale``'s traced expression.
     """
     wq, _ = quantize_symmetric(w, weight_bits)
-    return (mount_layout(wq.astype(jnp.int8), tile_rows, 0),
-            jnp.max(jnp.abs(w)).astype(jnp.float32))
+    wq = wq.astype(jnp.int8)
+    planes = (dense_layout(wq, 0) if layout == "dense"
+              else mount_layout(wq, tile_rows, 0))
+    return planes, jnp.max(jnp.abs(w)).astype(jnp.float32)
 
 
 def pack_weight(w: jnp.ndarray, *, is_conv: bool, tile_rows: int,
-                weight_bits: int) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Float weight -> (int8 mount planes (K_mounted, N), f32 amax)."""
-    if is_conv:                 # (k, k, in_ch, out_ch) -> (in_ch*k*k, N)
-        kk = w.shape[0] * w.shape[1] * w.shape[2]
-        w = w.transpose(2, 0, 1, 3).reshape(kk, -1)
-    return plane_pack(w, tile_rows=tile_rows, weight_bits=weight_bits)
+                weight_bits: int, layout: str = "mounted"
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Float weight -> (int8 planes (K_laid_out, N), f32 amax).
+
+    A conv weight ``(k, k, in_ch, out_ch)`` is taken in the im2col order
+    of ``layout``: ``(i, j, c)`` dense, ``(c, i, j)`` mounted.
+    """
+    if is_conv:
+        n = w.shape[-1]
+        if layout == "mounted":
+            w = w.transpose(2, 0, 1, 3)
+        w = w.reshape(-1, n)
+    return plane_pack(w, tile_rows=tile_rows, weight_bits=weight_bits,
+                      layout=layout)
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
@@ -160,7 +203,8 @@ def pack_program(program: CrossbarProgram, params: dict) -> PackedProgram:
         p = params[gemm.param]
         w8, amax = pack_weight(p[gemm.w_key], is_conv=gemm.is_conv,
                                tile_rows=gemm.tile_rows,
-                               weight_bits=cfg.weight_bits)
+                               weight_bits=cfg.weight_bits,
+                               layout=stage_layout(gemm, cfg))
         ln = next((o for o in posts if o.kind == "layernorm"), None)
         lp = params[ln.param] if ln is not None else None
         stages.append(PackedStage(

@@ -63,7 +63,7 @@ def test_kernel_phase_small():
                                        norm="layer"), True, True),
            ("softmax", 4, 10, dict(softmax=True), False, False))
     checks = smoke.kernel_phase(gemm, epi)
-    assert len(checks) == 2 + len(epi)
+    assert len(checks) == 3 + len(epi)     # exact, dense, sliced per GEMM
     assert all(ok for _, ok in checks), checks
 
 

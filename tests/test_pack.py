@@ -20,13 +20,18 @@ import pytest
 
 from repro import api
 from repro.api import HurryConfig, NetworkBuilder
-from repro.core.crossbar import CrossbarConfig, quantize_symmetric
+from repro.api.zoo import vit_tiny_graph
+from repro.core.crossbar import (CrossbarConfig, crossbar_matmul,
+                                 quantize_symmetric)
 from repro.kernels import ref
-from repro.kernels.crossbar_gemm import (crossbar_gemm, mount_rows,
+from repro.kernels.crossbar_gemm import (clip_possible, crossbar_gemm,
+                                        dense_blocks, dense_layout,
+                                        mount_layout, mount_rows,
                                         mounted_gemm)
 from repro.kernels.fb_epilogue import fb_epilogue
-from repro.models.cnn import CNN_MODELS, make_crossbar_matmul
+from repro.models.cnn import CNN_MODELS, im2col, make_crossbar_matmul
 from repro.program import compile_network, execute_packed, pack_program
+from repro.program.execute import stage_outputs
 
 CLIP_FREE = CrossbarConfig(rows=511, adc_bits=9)
 
@@ -35,22 +40,42 @@ CLIP_FREE = CrossbarConfig(rows=511, adc_bits=9)
 # packing: planes match traced quantization, layout and padding applied
 # ---------------------------------------------------------------------------
 
-def test_packed_planes_match_traced_quantization():
+# a clip-free config: every static stage dense; a config whose every
+# mount can clip (a 4-bit ADC digitizes counts up to 15, the smallest
+# alexnet mount has 27 rows): every stage mounted
+LAYOUT_CASES = {"dense": CLIP_FREE,
+                "mounted": CrossbarConfig(adc_bits=4)}
+
+
+@pytest.mark.parametrize("layout", LAYOUT_CASES)
+def test_packed_planes_match_traced_quantization(layout):
     params = CNN_MODELS["alexnet"].init(jax.random.PRNGKey(1))
-    program = compile_network("alexnet", cfg=CLIP_FREE)
+    program = compile_network("alexnet", cfg=LAYOUT_CASES[layout])
     packed = pack_program(program, params)
     assert packed.program.plans == ()       # executor never reads plans
+    assert packed.layouts() == (layout,) * len(program.stages())
     multi_mount_unaligned = 0
     for (gemm, _), st in zip(program.stages(), packed.stages):
         w = params[gemm.param]["w"]
         if gemm.is_conv:
             kk = w.shape[0] * w.shape[1] * w.shape[2]
-            w = w.transpose(2, 0, 1, 3).reshape(kk, -1)
+            # dense: (i, j, c) rows, the channels-minor im2col order;
+            # mounted: (c, i, j), the oracle's im2col order
+            w = (w.reshape(kk, -1) if layout == "dense"
+                 else w.transpose(2, 0, 1, 3).reshape(kk, -1))
         wq = np.asarray(jax.jit(lambda v: quantize_symmetric(v, 8)[0])(w))
         assert st.w8.dtype == jnp.int8
         np.testing.assert_array_equal(
             np.asarray(st.w_amax), np.asarray(jnp.max(jnp.abs(w))))
         rows, k = gemm.tile_rows, wq.shape[0]
+        if layout == "dense":
+            # K padded only at its end, to whole kernel blocks
+            kp, block = dense_blocks(k)
+            assert st.w8.shape[0] == kp and kp % block == 0
+            assert kp - k < 128 * (kp // block)
+            np.testing.assert_array_equal(np.asarray(st.w8)[:k], wq)
+            assert not np.asarray(st.w8)[k:].any()
+            continue
         if k <= rows:            # one mount: the whole contraction, unpadded
             np.testing.assert_array_equal(np.asarray(st.w8), wq)
             continue
@@ -63,7 +88,138 @@ def test_packed_planes_match_traced_quantization():
         np.testing.assert_array_equal(mounts[:, :rows], real)
         assert not mounts[:, rows:].any()
         multi_mount_unaligned += rows % 128 != 0
-    assert multi_mount_unaligned     # alexnet has 485/493-row mounts
+    # alexnet has 485/493-row mounts
+    assert multi_mount_unaligned or layout == "dense"
+
+
+def test_layouts_follow_clip_possible_per_stage():
+    """The benchmark's config lays every ResNet-18 stage out dense; the
+    8-bit-ADC config mounts every stage whose mounts can clip and keeps
+    alexnet's 27-row stem dense (27 <= 255: it cannot clip); attention's
+    dynamic stages stay mounted."""
+    resnet = api.compile("resnet18", HurryConfig(array_rows=511))
+    assert resnet.packed.layouts() == ("dense",) * 21
+    alexnet = api.compile("alexnet", HurryConfig(adc_bits=8))
+    want = tuple("mounted" if clip_possible(g.tile_rows, 8) else "dense"
+                 for g, _ in alexnet.program.stages())
+    assert alexnet.packed.layouts() == want
+    assert want == ("dense",) + ("mounted",) * 7
+    vit = api.compile(vit_tiny_graph(depth=1), HurryConfig(array_rows=511))
+    assert vit.packed.layouts() == tuple(
+        "mounted" if g.kind == "dyn_gemm" else "dense"
+        for g, _ in vit.program.stages())
+
+
+def test_channels_minor_im2col_permutes_the_patch_features():
+    """``im2col(channels_minor=True)`` holds the same patch entries as
+    the oracle's ``(c, i, j)`` im2col, in ``(i, j, c)`` order, for the
+    tap-concat and the non-overlapping reshape forms."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 9, 9, 5))
+    for k, stride, pad in ((3, 1, 1), (3, 2, 1), (1, 2, 0), (3, 3, 0)):
+        cij = np.asarray(im2col(x, k, stride, pad))
+        ijc = np.asarray(im2col(x, k, stride, pad, channels_minor=True))
+        n, oh, ow, _ = cij.shape
+        cij = cij.reshape(n, oh, ow, 5, k * k).swapaxes(-1, -2)
+        np.testing.assert_array_equal(ijc, cij.reshape(n, oh, ow, -1))
+
+
+# (name, input hw, input channels, out channels, k, stride, padding)
+DENSE_CONV_CASES = (
+    ("3x3_s1_p1", 8, 16, 24, 3, 1, 1),
+    ("3x3_s2_p1", 8, 16, 24, 3, 2, 1),
+    ("1x1_s2_p0", 8, 16, 24, 1, 2, 0),
+    ("stem_c3", 8, 3, 16, 3, 1, 1),
+    ("patch_16x16", 32, 3, 16, 16, 16, 0),
+    ("3x3_c128_three_k_blocks", 4, 128, 16, 3, 1, 1),
+)
+
+
+def _conv_net(hw, cin, cout, k, stride, pad):
+    nb = NetworkBuilder("one_conv", input_hw=hw, input_ch=cin)
+    nb.conv(cout, k, stride, pad, name="c")
+    nb.relu(name="r")
+    nb.fc(10, name="fc")
+    return nb.build()
+
+
+def _oracle_conv_acc(graph, params, x, cfg):
+    """The oracle's int32 conv GEMM: its ``(c, i, j)`` im2col matrix and
+    weight matrix quantized as ``crossbar_linear`` does, one int dot."""
+    l = graph.layers[0]
+    w = params[l.name]["w"]
+    cols = im2col(x, l.ksize, l.stride, l.padding)
+    cols = cols.reshape(-1, cols.shape[-1])
+    wm = w.transpose(2, 0, 1, 3).reshape(cols.shape[-1], -1)
+    xq, _ = quantize_symmetric(cols, cfg.input_bits)
+    wq, _ = quantize_symmetric(wm, cfg.weight_bits)
+    return crossbar_matmul(xq, wq, cfg)
+
+
+@pytest.mark.parametrize("case", DENSE_CONV_CASES, ids=lambda c: c[0])
+def test_dense_operand_bit_exact_vs_oracle(case):
+    """A dense conv stage — input quantized before im2col, patches cut
+    channels-minor from the int8 tensor, K padded only to whole kernel
+    blocks — gives the oracle's int32 GEMM and stage outputs bit for
+    bit, and the whole net its logits."""
+    _, hw, cin, cout, k, stride, pad = case
+    graph = _conv_net(hw, cin, cout, k, stride, pad)
+    config = HurryConfig(array_rows=511)
+    model = api.compile(graph, config, seed=2)
+    assert model.packed.layouts() == ("dense", "dense")
+    x = jax.random.normal(jax.random.PRNGKey(3), graph.input_shape(2))
+    cfg = config.crossbar()
+    stages = jax.jit(lambda pk, v: {
+        o.name: (o.value, o.acc) for o in stage_outputs(pk, v)})(
+            model.packed, x)
+    oracle = jax.jit(lambda p, v: graph.buffers(
+        p, v, mm=make_crossbar_matmul(cfg)))(model.params, x)
+    acc = jax.jit(_oracle_conv_acc, static_argnums=(0, 3))(
+        graph, model.params, x, cfg)
+    conv_acc = stages["r"][1]                # the conv stage writes "r"
+    assert conv_acc.dtype == jnp.int32 and set(stages) == {"r", "fc"}
+    np.testing.assert_array_equal(np.asarray(conv_acc), np.asarray(acc))
+    for name, (value, _) in stages.items():
+        np.testing.assert_array_equal(np.asarray(value),
+                                      np.asarray(oracle[name]))
+
+
+def test_dense_amax_reads_only_the_pixels_im2col_reads():
+    """A 1x1/2 projection reads every other pixel: the input's largest
+    |value|, put at an odd pixel, is never read, so it must not set the
+    scale — the program still matches the oracle bit for bit, and a
+    scale taken over the whole input would not."""
+    graph = _conv_net(8, 16, 24, 1, 2, 0)
+    config = HurryConfig(array_rows=511)
+    model = api.compile(graph, config, seed=2)
+    x = jax.random.normal(jax.random.PRNGKey(3), graph.input_shape(2))
+    x = x.at[1, 3, 5, 7].set(-50.0)
+    cfg = config.crossbar()
+    acc = jax.jit(lambda pk, v: next(stage_outputs(pk, v)).acc)(
+        model.packed, x)
+    want = jax.jit(_oracle_conv_acc, static_argnums=(0, 3))(
+        graph, model.params, x, cfg)
+    np.testing.assert_array_equal(np.asarray(acc), np.asarray(want))
+    fwd = jax.jit(lambda p, v: graph.forward(
+        p, v, mm=make_crossbar_matmul(cfg), logits=True))
+    np.testing.assert_array_equal(np.asarray(model.run(x, logits=True)),
+                                  np.asarray(fwd(model.params, x)))
+    # the planted value is the input's max and im2col never reads it
+    read = np.asarray(x)[:, ::2, ::2]
+    assert np.abs(read).max() < 50.0 == np.abs(np.asarray(x)).max()
+
+
+def test_resnet18_cifar10_dense_program_logits_bit_exact():
+    """The benchmark's network and config (``HurryConfig(array_rows=
+    511)``, every stage dense) at batch 2: logits bit-exact against the
+    jitted functional oracle."""
+    config = HurryConfig(array_rows=511)
+    model = api.compile("resnet18", config, seed=5)
+    graph = model.graph
+    x = jax.random.normal(jax.random.PRNGKey(4), graph.input_shape(2))
+    fwd = jax.jit(lambda p, v: graph.forward(
+        p, v, mm=make_crossbar_matmul(config.crossbar()), logits=True))
+    np.testing.assert_array_equal(np.asarray(model.run(x, logits=True)),
+                                  np.asarray(fwd(model.params, x)))
 
 
 def test_multi_mount_stage_keeps_sliced_adc_semantics():
@@ -269,23 +425,39 @@ def test_version1_file_loads_via_repack_fallback(tmp_path):
         api.load(bad)
 
 
-def _v3_plane(w8: np.ndarray, op) -> np.ndarray:
-    """A mount-layout plane as versions 2-3 stored it: the real K rows,
-    zero-padded at the end to whole ``tile_rows`` mounts."""
+def _real_rows(w8: np.ndarray, op, layout: str) -> np.ndarray:
+    """A stored plane's real K rows in the oracle's ``(c, i, j)`` order."""
     k, rows = max(r.k1 for r in op.mount_rounds), op.tile_rows
+    if layout == "dense":
+        w8 = w8[:k]
+        if op.is_conv:                    # (i, j, c) -> (c, i, j)
+            kk = op.ksize * op.ksize
+            w8 = w8.reshape(kk, k // kk, -1).swapaxes(0, 1).reshape(k, -1)
+        return w8
     if k > rows:
         n = -(-k // rows)
         w8 = w8.reshape(n, mount_rows(rows), -1)[:, :rows]
-    w8 = w8.reshape(-1, w8.shape[-1])[:k]
-    return np.pad(w8, ((0, -k % rows), (0, 0)))
+    return w8.reshape(-1, w8.shape[-1])[:k]
 
 
-@pytest.mark.parametrize("version", [2, 3])
+def _old_plane(w8: np.ndarray, op, layout: str, version: int) -> np.ndarray:
+    """A plane as a version 2-4 file stored it: ``(c, i, j)`` rows, K
+    zero-padded at its end to whole ``tile_rows`` mounts (versions 2-3)
+    or in the mount layout (version 4)."""
+    w8 = _real_rows(w8, op, layout)
+    if version == 4:
+        return np.asarray(mount_layout(jnp.asarray(w8), op.tile_rows, 0))
+    return np.pad(w8, ((0, -w8.shape[0] % op.tile_rows), (0, 0)))
+
+
+@pytest.mark.parametrize("version", [2, 3, 4])
 def test_pre_mount_layout_file_loads_bit_identical(tmp_path, version):
-    """Files saved before the mount layout (versions 2-3: K padded once,
-    at its end, to whole mounts; the old 512x512 block defaults stored
-    explicitly) load and run bit-identically, on a multi-mount stage
-    whose ``tile_rows`` is off the 128-row tiling."""
+    """Files saved before the dense layout load and run bit-identically,
+    on a multi-mount stage whose ``tile_rows`` is off the 128-row tiling
+    and a conv whose ``(c, i, j)`` rows are permuted at load: versions
+    2-3 (K padded once, at its end, to whole mounts; the old 512x512
+    block defaults stored explicitly) and version 4 (every plane in the
+    mount layout, no ``layouts`` list)."""
     nb = NetworkBuilder("tiny", input_hw=8, input_ch=4)
     nb.conv(16, name="c1")
     nb.relu(name="r1")
@@ -295,6 +467,8 @@ def test_pre_mount_layout_file_loads_bit_identical(tmp_path, version):
     model = api.compile(graph, HurryConfig(array_rows=100), seed=1)
     x = jax.random.normal(jax.random.PRNGKey(0), graph.input_shape(3))
     gemms = [g for g, _ in model.program.stages()]
+    layouts = model.packed.layouts()
+    assert layouts == ("dense", "dense")
     # fc: K=256 in 100-row mounts
     assert any(max(r.k1 for r in g.mount_rounds) > g.tile_rows
                and g.tile_rows % 128 for g in gemms)
@@ -302,10 +476,14 @@ def test_pre_mount_layout_file_loads_bit_identical(tmp_path, version):
     with np.load(path, allow_pickle=False) as z:
         meta = json.loads(str(z["__meta__"][()]))
         arrays = {k: z[k] for k in z.files if k != "__meta__"}
+    assert meta["version"] == 5 and meta["layouts"] == list(layouts)
     meta["version"] = version
-    meta["config"].update(block_m=512, block_n=512)
+    del meta["layouts"]
+    if version < 4:
+        meta["config"].update(block_m=512, block_n=512)
     for i, op in enumerate(gemms):
-        arrays[f"w{i}"] = _v3_plane(arrays[f"w{i}"], op)
+        arrays[f"w{i}"] = _old_plane(arrays[f"w{i}"], op, layouts[i],
+                                     version)
     old = str(tmp_path / f"v{version}.npz")
     with open(old, "wb") as f:
         np.savez(f, __meta__=np.asarray(json.dumps(meta)), **arrays)
@@ -315,6 +493,52 @@ def test_pre_mount_layout_file_loads_bit_identical(tmp_path, version):
         np.testing.assert_array_equal(np.asarray(a.w8), np.asarray(b.w8))
     np.testing.assert_array_equal(np.asarray(model.run(x, logits=True)),
                                   np.asarray(loaded.run(x, logits=True)))
+
+
+def test_version4_mounted_planes_load_unchanged(tmp_path):
+    """Under a config whose mounts can clip, a version-4 file's planes
+    are already version 5's: they load as stored."""
+    nb = NetworkBuilder("tiny", input_hw=8, input_ch=16)
+    nb.conv(16, name="c1")
+    nb.relu(name="r1")
+    nb.fc(10, name="fc")
+    graph = nb.build()
+    model = api.compile(graph, HurryConfig(array_rows=100, adc_bits=5),
+                        seed=1)
+    assert model.packed.layouts() == ("mounted", "mounted")
+    x = jax.random.normal(jax.random.PRNGKey(0), graph.input_shape(2))
+    path = model.save(str(tmp_path / "m.npz"))
+    with np.load(path, allow_pickle=False) as z:
+        meta = json.loads(str(z["__meta__"][()]))
+        arrays = {k: z[k] for k in z.files if k != "__meta__"}
+    meta["version"] = 4
+    del meta["layouts"]
+    v4 = str(tmp_path / "v4.npz")
+    with open(v4, "wb") as f:
+        np.savez(f, __meta__=np.asarray(json.dumps(meta)), **arrays)
+    loaded = api.load(v4)
+    for i, st in enumerate(loaded.packed.stages):
+        np.testing.assert_array_equal(np.asarray(st.w8), arrays[f"w{i}"])
+    np.testing.assert_array_equal(np.asarray(model.run(x, logits=True)),
+                                  np.asarray(loaded.run(x, logits=True)))
+
+
+def test_dense_gemm_bit_exact_over_k_blocks():
+    """``mounted_gemm(layout="dense")`` at K with one, several and padded
+    K blocks equals the plain integer GEMM; the dense layout refuses a
+    mount that can clip."""
+    for k in (150, 1152, 1100):
+        kx, kw = jax.random.split(jax.random.PRNGKey(k))
+        x = jax.random.randint(kx, (24, k), -128, 128).astype(jnp.int8)
+        w = jax.random.randint(kw, (k, 19), -128, 128).astype(jnp.int8)
+        y = mounted_gemm(x, dense_layout(w, 0), rows=485, layout="dense",
+                         block_m=16, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(y), np.asarray(ref.crossbar_gemm_exact_ref(x, w)))
+    assert dense_blocks(1100) == (1152, 384)      # K padded by 52 rows
+    with pytest.raises(ValueError, match="exact-only"):
+        mounted_gemm(x, dense_layout(w, 0), rows=512, adc_bits=9,
+                     layout="dense", interpret=True)
 
 
 def test_packed_program_is_a_jit_arg():

@@ -70,7 +70,7 @@ def test_vit_tiny_bit_exact_and_roundtrip(tmp_path):
 
     path = model.save(str(tmp_path / "vit.npz"))
     meta_version = VERSION
-    assert meta_version == 4
+    assert meta_version == 5
     loaded = api.load(path)
     assert loaded.program.ops == model.program.ops
     assert loaded.program.has_dynamic_stages

@@ -26,7 +26,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro import api
 from repro.api.zoo import vit_tiny_graph
-from repro.kernels.crossbar_gemm import mount_layout, mounted_gemm
+from repro.kernels.crossbar_gemm import (dense_layout, mount_layout,
+                                        mounted_gemm)
 from repro.kernels.fb_epilogue import fb_epilogue
 from repro.program.execute import execute_packed
 
@@ -76,6 +77,19 @@ def test_crossbar_gemm_compiles(one_chip, case, path):
                                        interpret=False),
              _shape(one_chip, (m, k), jnp.int8),
              _shape(one_chip, (k_mounted, n), jnp.int8))
+
+
+@pytest.mark.parametrize("k,n", [(576, 64), (2304, 64), (4608, 64),
+                                 (4608, 512)])
+def test_dense_gemm_compiles(one_chip, k, n):
+    """The dense layout's K blocks (one 576-row block; three and six
+    768-row blocks) at a ResNet-18 stage's M per 8 images."""
+    kp = jax.eval_shape(lambda a: dense_layout(a, 0),
+                        jax.ShapeDtypeStruct((k, n), jnp.int8)).shape[0]
+    _compile(lambda x, w: mounted_gemm(x, w, adc_bits=9, rows=493,
+                                       layout="dense", interpret=False),
+             _shape(one_chip, (8 * 1024, k), jnp.int8),
+             _shape(one_chip, (kp, n), jnp.int8))
 
 
 # batches off the pooled modes' images-per-step count and past the
