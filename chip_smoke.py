@@ -1,13 +1,16 @@
 #!/usr/bin/env python3
 """Chip smoke test: the crossbar program stack's main path on one TPU.
 
-    python chip_smoke.py
+    python chip_smoke.py [--nets deit_ti,resnet18]
 
 One process, one chip.  It drives what a user calls —
 ``api.compile(graph, HurryConfig) -> CompiledModel.run`` — for every
 network of the zoo at its full geometry: ``alexnet``, ``vgg16`` and
 ``resnet18`` at 32x32, ``vit_tiny`` at depth 12 (dim 192, 3 heads, MLP
-ratio 4, 64 tokens).  Weights are random from a fixed seed.  Phases:
+ratio 4, 64 tokens), and ``deit_ti`` (DeiT-Ti at 224x224: pre-norm
+blocks, 197 tokens with the class token, erf GELU).  ``--nets`` keeps
+the networks named (the kernel phase always runs).  Weights are random
+from a fixed seed.  Phases:
 
 * kernels — ``mounted_gemm`` on the exact path (mount and dense
   layouts) and the sliced path at real stage shapes, bit-exact against the XLA integer references of
@@ -41,6 +44,7 @@ the CPU in interpret mode.
 
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import statistics
@@ -57,7 +61,7 @@ import numpy as np  # noqa: E402
 
 from repro import api  # noqa: E402
 from repro.api import HurryConfig  # noqa: E402
-from repro.api.zoo import GRAPHS, vit_tiny_graph  # noqa: E402
+from repro.api.zoo import GRAPHS  # noqa: E402
 from repro.compile_cache import use_compile_cache  # noqa: E402
 from repro.kernels import ref  # noqa: E402
 from repro.kernels.crossbar_gemm import (dense_blocks,  # noqa: E402
@@ -74,7 +78,8 @@ CLIP_FREE = HurryConfig(array_rows=511)      # every mount clip-free (§4)
 # 8-bit ADC: every mount over 255 rows can clip, so it takes the sliced
 # kernel (kernels/crossbar_gemm.py::clip_possible)
 SLICED = HurryConfig(adc_bits=8)
-NETS = (("alexnet", 0), ("vgg16", 0), ("resnet18", 0), ("vit_tiny", 12))
+NETS = (("alexnet", 0), ("vgg16", 0), ("resnet18", 0), ("vit_tiny", 12),
+        ("deit_ti", 12))
 SLICED_NETS = (("alexnet", 0), ("vit_tiny", 12))
 BATCHES = (1, 3, 8)
 STEADY_RUNS = 5
@@ -109,8 +114,13 @@ EPILOGUE_CASES = (
                                       norm="layer"), True, True),
     ("layernorm_n192", 8 * 64, 192, dict(norm="layer"), True, True),
     ("gelu_n768", 8 * 64, 768, dict(act="gelu"), False, False),
+    ("dequant_t197_n768", 8 * 197, 768, dict(), False, False),
+    ("layernorm_eps1e-6_n192", 8 * 197, 192, dict(norm="layer", eps=1e-6),
+     True, True),
     ("softmax_n10", 8, 10, dict(softmax=True), False, False),
     ("scores_t64", 64, 64, dict(softmax=True, post_scale=0.125), False,
+     False),
+    ("scores_t197", 197, 197, dict(softmax=True, post_scale=0.125), False,
      False),
 )
 
@@ -146,7 +156,9 @@ def ulp_error(a, b) -> float:
 
 
 def graph_of(net: str, depth: int):
-    return vit_tiny_graph(depth=depth) if net == "vit_tiny" else GRAPHS[net]()
+    if net in ("vit_tiny", "deit_ti"):
+        return GRAPHS[net](depth=depth)
+    return GRAPHS[net]()
 
 
 def random_params(graph, seed: int) -> dict:
@@ -464,7 +476,14 @@ def save_load_phase(model, batch: int = 3, seed: int = SEED) -> list:
     return checks
 
 
-def main() -> int:
+def main(argv=()) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--nets", default=",".join(n for n, _ in NETS),
+                    help="comma-separated networks of NETS to run")
+    nets = ap.parse_args(argv).nets.split(",")
+    unknown = set(nets) - {n for n, _ in NETS}
+    if unknown:
+        ap.error(f"unknown networks {sorted(unknown)}")
     devices = jax.devices()
     dev = devices[0]
     if dev.platform != "tpu":
@@ -476,12 +495,15 @@ def main() -> int:
           f"compile cache {cache}", flush=True)
     checks = kernel_phase()
     for net, depth in NETS:
+        if net not in nets:
+            continue
         model, c = serve_phase(net, depth)
         checks += c + compiled_phase(model)
-        if net == "alexnet":
+        if net in ("alexnet", "deit_ti"):
             checks += save_load_phase(model)
     for net, depth in SLICED_NETS:
-        checks += sliced_phase(net, depth)
+        if net in nets:
+            checks += sliced_phase(net, depth)
     failed = [name for name, ok in checks if not ok]
     if failed:
         print(f"chip_smoke: {len(failed)} of {len(checks)} checks failed: "
@@ -495,4 +517,4 @@ def main() -> int:
 
 
 if __name__ == "__main__":
-    sys.exit(main())
+    sys.exit(main(sys.argv[1:]))

@@ -13,14 +13,20 @@ offending layer's name, not deep inside the compiler.
 
 **Sequence mode** (DESIGN.md §9): the same builder authors transformer
 graphs over ``(T, D)`` token shapes — ``nb.linear(features)``,
-``nb.layernorm()``, ``nb.gelu()``, ``nb.attention(heads)``,
-``nb.seqpool()``.  A spatial buffer entering a sequence op is
-rasterized into ``T = hw^2`` tokens (the ViT patchify transition); a
-network may also start directly in token space via
-``NetworkBuilder(input_seq_dim=D)``, in which case the sequence length
-is a run-time property of the batch (``T`` is tracked as 0 during
-inference of shapes).  The sequence FB chain order is ``residual ->
-gelu -> layernorm -> seqpool`` (post-norm transformer blocks).
+``nb.layernorm(eps=)``, ``nb.gelu(approx=)``, ``nb.attention(heads)``,
+``nb.seqpool(mode=)``, ``nb.embed()``.  A spatial buffer entering a
+sequence op is rasterized into ``T = hw^2`` tokens (the ViT patchify
+transition); ``nb.embed()`` after the patchify conv does the same with
+a learned class token prepended and a learned position table added
+(``T = hw^2 + 1``).  A network may also start directly in token space
+via ``NetworkBuilder(input_seq_dim=D)``, in which case the sequence
+length is a run-time property of the batch (``T`` is tracked as 0
+during inference of shapes).  The sequence FB chain order is
+``residual -> gelu -> layernorm -> seqpool`` (post-norm transformer
+blocks).  Pre-norm blocks (``x + f(LN(x))``) put
+``nb.layernorm(pre=True)`` before the GEMM op: the next ``linear``,
+``attention`` or ``fc`` normalizes its input, and residuals that read
+that input still read it un-normed.
 
 The resulting ``NetworkGraph`` is the one source of truth for layer
 shapes: the scheduler consumes its ``LayerSpec`` list, ``init_params``
@@ -43,9 +49,10 @@ import jax.numpy as jnp
 
 from repro.core.workload import (GEMM_KINDS, LayerSpec, POST_RANK,
                                  input_spec, layer_groups)
-from repro.kernels.fb_epilogue import gelu, layer_norm_rows, softmax_rows
+from repro.kernels.fb_epilogue import (gelu, gelu_erf, layer_norm_ordered,
+                                       layer_norm_rows, softmax_ordered)
 from repro.models.cnn import conv2d, fp_matmul, maxpool
-from repro.program.sequence import (attn_scale, merge_heads,
+from repro.program.sequence import (attn_scale, embed_tokens, merge_heads,
                                     split_qkv_heads, tokens)
 
 # shapes are ("spatial", hw, ch) until an fc flattens to ("flat", features)
@@ -56,7 +63,8 @@ _AUTO_PREFIX = {"conv": "conv", "fc": "fc", "relu": "relu",
                 "maxpool": "pool", "avgpool": "avgpool",
                 "residual": "res", "softmax": "softmax",
                 "linear": "lin", "layernorm": "ln", "gelu": "gelu",
-                "attention": "attn", "seqpool": "seqpool"}
+                "attention": "attn", "seqpool": "seqpool",
+                "embed": "embed"}
 
 
 def _as_tokens(shape: tuple) -> tuple:
@@ -94,6 +102,9 @@ class NetworkGraph:
         params: dict = {}
         for i, l in enumerate(self.layers):
             k = jax.random.fold_in(key, i)
+            if l.prenorm:
+                params[l.prenorm] = {"g": jnp.ones((l.features_in,)),
+                                     "b": jnp.zeros((l.features_in,))}
             if l.kind == "conv":
                 fan_in = l.ksize * l.ksize * l.in_ch
                 w = jax.random.normal(
@@ -118,6 +129,14 @@ class NetworkGraph:
             elif l.kind == "layernorm":
                 params[l.name] = {"g": jnp.ones((l.features_out,)),
                                   "b": jnp.zeros((l.features_out,))}
+            elif l.kind == "embed":
+                # ViT's init: both drawn at std 0.02
+                k1, k2 = jax.random.split(k)
+                d = l.features_out
+                params[l.name] = {
+                    "cls": 0.02 * jax.random.normal(k1, (d,)),
+                    "pos": 0.02 * jax.random.normal(
+                        k2, (l.in_hw * l.in_hw + 1, d))}
         return params
 
     def forward(self, params: dict, x: jnp.ndarray, *,
@@ -147,36 +166,50 @@ class NetworkGraph:
         implementation stage by stage.  An attention layer also records
         its inner results as ``<layer>.qkv`` (fused projection,
         ``(B, T, 3D)``), ``<layer>.probs`` (per batch*head softmax) and
-        ``<layer>.ctx`` (merged context).  Arguments as ``forward``.
+        ``<layer>.ctx`` (merged context).  A GEMM head's pre-norm is
+        part of its layer (no buffer of its own).  Arguments as
+        ``forward``.
         """
         bufs: dict[str, jnp.ndarray] = {"input": x}
         cur = "input"
+
+        def gemm_input(l):
+            """A linear/attention/fc layer's input: tokens, or a flat
+            row per image for fc; layer-normed under ``prenorm``."""
+            src = bufs[l.input_from or cur]
+            if l.kind != "fc":
+                src = tokens(src)
+            elif src.ndim == 4:
+                src = src.reshape(src.shape[0], -1)
+            if l.prenorm:
+                p = params[l.prenorm]
+                src = layer_norm_ordered(src, p["g"], p["b"], l.eps)
+            return src
+
         for l in self.layers:
             if l.kind == "conv":
                 src = bufs[l.input_from or cur]
                 p = params[l.name]
                 y = conv2d(src, p["w"], p["b"], l.stride, l.padding, mm)
             elif l.kind == "fc":
-                src = bufs[l.input_from or cur]
-                if src.ndim == 4:
-                    src = src.reshape(src.shape[0], -1)
+                src = gemm_input(l)
                 p = params[l.name]
                 y = mm(src, p["w"]) + p["b"]
             elif l.kind == "linear":
-                src = tokens(bufs[l.input_from or cur])
+                src = gemm_input(l)
                 b, t, d = src.shape
                 p = params[l.name]
                 y = (mm(src.reshape(b * t, d), p["w"])
                      + p["b"]).reshape(b, t, -1)
             elif l.kind == "attention":
-                src = tokens(bufs[l.input_from or cur])
+                src = gemm_input(l)
                 b, t, d = src.shape
                 p = params[l.name]
                 qkv = mm(src.reshape(b * t, d), p["wqkv"]) + p["bqkv"]
                 q, kk, v = split_qkv_heads(qkv.reshape(b, t, 3 * d),
                                            l.heads)
                 scores = jax.vmap(lambda a, w: mm(a, w.T))(q, kk)
-                probs = softmax_rows(scores * attn_scale(d // l.heads))
+                probs = softmax_ordered(scores * attn_scale(d // l.heads))
                 ctx = merge_heads(jax.vmap(mm)(probs, v), l.heads)
                 y = (mm(ctx.reshape(b * t, d), p["wo"])
                      + p["bo"]).reshape(b, t, d)
@@ -186,10 +219,14 @@ class NetworkGraph:
             elif l.kind == "relu":
                 y = jax.nn.relu(bufs[cur])
             elif l.kind == "gelu":
-                y = gelu(bufs[cur])
+                y = (gelu_erf if l.approx == "erf" else gelu)(bufs[cur])
             elif l.kind == "layernorm":
                 p = params[l.name]
-                y = layer_norm_rows(tokens(bufs[cur]), p["g"], p["b"])
+                y = layer_norm_rows(tokens(bufs[cur]), p["g"], p["b"],
+                                    l.eps)
+            elif l.kind == "embed":
+                p = params[l.name]
+                y = embed_tokens(tokens(bufs[cur]), p["cls"], p["pos"])
             elif l.kind == "maxpool":
                 y = maxpool(bufs[cur], l.ksize, l.stride)
             elif l.kind == "avgpool":
@@ -198,7 +235,8 @@ class NetworkGraph:
                 y = v.reshape(b, h // l.ksize, l.ksize,
                               w_ // l.ksize, l.ksize, c).mean(axis=(2, 4))
             elif l.kind == "seqpool":
-                y = tokens(bufs[cur]).mean(axis=1)
+                v = tokens(bufs[cur])
+                y = v[:, 0] if l.mode == "cls" else v.mean(axis=1)
             elif l.kind == "residual":
                 a = bufs[cur]
                 r = bufs[l.residual_from]
@@ -261,6 +299,7 @@ class NetworkBuilder:
         self._counts: dict[str, int] = {}
         self._has_gemm = False
         self._head_kind = ""          # kind of the current group's head
+        self._prenorm: tuple[str, float] | None = None   # (name, eps)
 
     # -- internals ---------------------------------------------------------
 
@@ -323,7 +362,21 @@ class NetworkBuilder:
         self._head_kind = kind
         return src
 
+    def _take_prenorm(self, name: str, kind: str) -> dict:
+        """The pending pre-norm as a GEMM head's fields (and clear it)."""
+        if self._prenorm is None:
+            return {}
+        if kind not in ("fc", "linear", "attention"):
+            raise ValueError(
+                f"{name}: a pre-norm ({self._prenorm[0]!r}) must be "
+                f"followed by a linear, attention or fc layer, not {kind}")
+        norm, eps = self._prenorm
+        self._prenorm = None
+        return {"prenorm": norm, "eps": eps}
+
     def _add(self, spec: LayerSpec, shape: tuple) -> str:
+        if self._prenorm is not None:       # a GEMM head took it already
+            self._take_prenorm(spec.name, spec.kind)
         self._layers.append(spec)
         self._shapes[spec.name] = shape
         self._cur = spec.name
@@ -358,7 +411,8 @@ class NetworkBuilder:
             else shape[1]
         return self._add(
             LayerSpec(name, "fc", features_in=fin,
-                      features_out=features_out, input_from=input_from),
+                      features_out=features_out, input_from=input_from,
+                      **self._take_prenorm(name, "fc")),
             (_FLAT, features_out))
 
     def linear(self, features_out: int, *, name: str | None = None,
@@ -369,7 +423,8 @@ class NetworkBuilder:
         _, t, d = self._src_shape(name, src, _SEQ)
         return self._add(
             LayerSpec(name, "linear", features_in=d,
-                      features_out=features_out, input_from=input_from),
+                      features_out=features_out, input_from=input_from,
+                      **self._take_prenorm(name, "linear")),
             (_SEQ, t, features_out))
 
     def attention(self, heads: int, *, name: str | None = None,
@@ -388,7 +443,8 @@ class NetworkBuilder:
                 f"{name}: {heads} heads do not divide model dim {d}")
         return self._add(
             LayerSpec(name, "attention", features_in=d, features_out=d,
-                      heads=heads, input_from=input_from),
+                      heads=heads, input_from=input_from,
+                      **self._take_prenorm(name, "attention")),
             (_SEQ, t, d))
 
     def relu(self, *, name: str | None = None) -> str:
@@ -401,30 +457,71 @@ class NetworkBuilder:
             spec = LayerSpec(name, "relu", features_out=shape[-1])
         return self._add(spec, shape)
 
-    def gelu(self, *, name: str | None = None) -> str:
-        """GELU FB (sequence chains; the LUT analogue of the relu FB)."""
+    def gelu(self, *, approx: str = "tanh", name: str | None = None) -> str:
+        """GELU FB (sequence chains; the LUT analogue of the relu FB):
+        the tanh approximation or the exact ``approx="erf"`` form."""
         name = self._name("gelu", name)
+        if approx not in ("tanh", "erf"):
+            raise ValueError(f"{name}: gelu approx {approx!r} is not "
+                             "'tanh' or 'erf'")
         self._require_seq_head(name, "gelu")
         shape = self._src_shape(name, self._cur, _SEQ)
         return self._add(
-            LayerSpec(name, "gelu", features_out=shape[2]), shape)
+            LayerSpec(name, "gelu", features_out=shape[2], approx=approx),
+            shape)
 
-    def layernorm(self, *, name: str | None = None) -> str:
-        """Layer norm FB over the feature axis of a token buffer."""
+    def layernorm(self, *, pre: bool = False, eps: float = 1e-5,
+                  name: str | None = None) -> str:
+        """Layer norm over the feature axis, with epsilon ``eps``.
+
+        By default an FB post-op of the current group's token buffer
+        (post-norm).  ``pre=True`` normalizes the input of the next
+        ``linear``, ``attention`` or ``fc`` op instead (pre-norm): it
+        adds no layer and no buffer, the GEMM head carries it, and a
+        residual that reads the same input reads it un-normed.
+        """
         name = self._name("layernorm", name)
+        if pre:
+            if self._prenorm is not None:
+                raise ValueError(f"{name}: pre-norm {self._prenorm[0]!r} "
+                                 "has no GEMM op yet")
+            self._shapes[name] = self._shapes[self._cur]   # name taken
+            self._prenorm = (name, eps)
+            return name
         self._require_seq_head(name, "layernorm")
         shape = self._src_shape(name, self._cur, _SEQ)
         return self._add(
-            LayerSpec(name, "layernorm", features_out=shape[2]), shape)
+            LayerSpec(name, "layernorm", features_out=shape[2], eps=eps),
+            shape)
 
-    def seqpool(self, *, name: str | None = None) -> str:
-        """Mean-pool the token axis: (T, D) -> flat (D,) (ViT-style head)."""
+    def seqpool(self, *, mode: str = "mean", name: str | None = None) -> str:
+        """Pool the token axis: (T, D) -> flat (D,) (ViT-style head) —
+        the mean of the tokens, or with ``mode="cls"`` the class token
+        (row 0; ``embed`` put it there)."""
         name = self._name("seqpool", name)
+        if mode not in ("mean", "cls"):
+            raise ValueError(f"{name}: seqpool mode {mode!r} is not "
+                             "'mean' or 'cls'")
         self._require_seq_head(name, "seqpool")
         shape = self._src_shape(name, self._cur, _SEQ)
         return self._add(
-            LayerSpec(name, "seqpool", features_out=shape[2]),
+            LayerSpec(name, "seqpool", features_out=shape[2], mode=mode),
             (_FLAT, shape[2]))
+
+    def embed(self, *, name: str | None = None) -> str:
+        """Token embedding FB of a patchify conv: its (hw, hw, D) map as
+        hw^2 row-major tokens, a learned class token prepended (token 0)
+        and a learned (hw^2 + 1, D) position table added."""
+        name = self._name("embed", name)
+        self._require_gemm(name, "embed")
+        if self._head_kind != "conv":
+            raise ValueError(
+                f"layer {name!r} (embed) fuses onto a patchify conv group "
+                f"head, not a {self._head_kind}")
+        _, hw, ch = self._src_shape(name, self._cur, _SPATIAL)
+        return self._add(
+            LayerSpec(name, "embed", in_hw=hw, out_ch=ch, features_out=ch),
+            (_SEQ, hw * hw + 1, ch))
 
     def _pool(self, kind: str, k: int, stride: int,
               name: str | None) -> str:
@@ -487,6 +584,9 @@ class NetworkBuilder:
     def build(self) -> NetworkGraph:
         if not self._layers:
             raise ValueError(f"{self.name}: empty network")
+        if self._prenorm is not None:
+            raise ValueError(f"{self._prenorm[0]}: pre-norm with no GEMM "
+                             "op after it")
         # grouping + canonical chain order validation (same POST_RANK
         # table as the compiler, so errors surface at build time with
         # layer names and the two checks can never diverge)
@@ -497,7 +597,7 @@ class NetworkBuilder:
                     raise ValueError(
                         f"{l.name}: {l.kind} out of canonical FB chain "
                         "order (residual -> relu|gelu -> pool -> "
-                        "layernorm -> seqpool -> softmax) in "
+                        "layernorm -> embed|seqpool -> softmax) in "
                         f"group {group[0].name!r}")
                 rank = POST_RANK[l.kind]
         hw, ch, seq = self._in

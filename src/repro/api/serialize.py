@@ -1,6 +1,6 @@
 """Persist compiled models: ``CompiledModel.save`` / ``api.load``.
 
-Format (single ``.npz`` file, version 5):
+Format (single ``.npz`` file, version 6):
 
 * ``__meta__`` — a JSON document holding the graph (name, input spec,
   ``LayerSpec`` list), the ``HurryConfig``, the batch-bucket ladder,
@@ -18,6 +18,9 @@ Format (single ``.npz`` file, version 5):
   (the analogue of shipping a programmed chip, not a netlist).
 * ``wg{i}/wh{i}`` — (version 3) the fused layer-norm FB's gamma/beta
   for stages listed in the meta's ``ln_stages``.
+* ``wpg{i}/wpb{i}`` and ``wec{i}/wep{i}`` — (version 6) a stage's
+  pre-norm gamma/beta (meta ``pre_stages``) and its ``embed`` FB's
+  class token and position table (meta ``embed_stages``).
 
 Version 3 extends version 2 for graphs containing **dynamic-operand
 stages** (attention, DESIGN.md §9): sequence fields ride on the graph /
@@ -47,6 +50,13 @@ Version 5 stores each stage's planes in the layout
 in ``(i, j, c)`` order, K zero-padded only at its end to whole kernel
 blocks (``dense_layout``); every other stage keeps version 4's
 ``(c, i, j)`` order and mount layout.
+
+Version 6 adds what pre-norm transformers need (DeiT): the optional
+per-stage arrays above, and the new fields of the graph's layers
+(``prenorm``, ``eps``, ``approx``, ``mode``) and of the program's ops
+(``prenorm``, ``eps``, ``approx``, ``select``).  Files of versions 2-5
+have none of them and load with their defaults, which are the meaning
+those files had (no pre-norm, LN epsilon 1e-5, tanh GELU, mean pool).
 
 Version-1 files (pre-packing) still load: the packed planes are
 re-derived once from the saved params at load time (repack fallback).
@@ -78,8 +88,13 @@ from .config import HurryConfig
 from .graph import NetworkGraph
 
 FORMAT = "repro.api/compiled-model"
-VERSION = 5
-_LOADABLE = (1, 2, 3, 4, 5)
+VERSION = 6
+_LOADABLE = (1, 2, 3, 4, 5, 6)
+# meta key listing the stages that hold them -> (PackedStage field,
+# array prefix) of each optional per-stage array
+_OPTIONAL = {"ln_stages": (("ln_g", "wg"), ("ln_b", "wh")),
+             "pre_stages": (("pre_g", "wpg"), ("pre_b", "wpb")),
+             "embed_stages": (("emb_cls", "wec"), ("emb_pos", "wep"))}
 _OLD_BLOCK_DEFAULT = 512      # versions <= 3 stored it for "no override"
 
 
@@ -146,15 +161,16 @@ def save_model(model, path: str) -> str:
             arrays[f"p{len(index)}"] = np.asarray(model.params[layer][key])
             index.append([layer, key])
     packed = model._packed()
-    ln_stages = []
+    optional = {key: [] for key in _OPTIONAL}
     for i, st in enumerate(packed.stages):
         arrays[f"w{i}"] = np.asarray(st.w8)
         arrays[f"wa{i}"] = np.asarray(st.w_amax)
         arrays[f"wb{i}"] = np.asarray(st.bias)
-        if st.ln_g is not None:
-            ln_stages.append(i)
-            arrays[f"wg{i}"] = np.asarray(st.ln_g)
-            arrays[f"wh{i}"] = np.asarray(st.ln_b)
+        for key, fields in _OPTIONAL.items():
+            if getattr(st, fields[0][0]) is not None:
+                optional[key].append(i)
+                for field, prefix in fields:
+                    arrays[f"{prefix}{i}"] = np.asarray(getattr(st, field))
     meta = {
         "format": FORMAT, "version": VERSION,
         "graph": {"name": g.name, "in_hw": g.in_hw, "in_ch": g.in_ch,
@@ -164,7 +180,7 @@ def save_model(model, path: str) -> str:
         "program": _program_meta(model.program),
         "params": index,
         "packed_stages": len(packed.stages),
-        "ln_stages": ln_stages,
+        **optional,
         "layouts": list(packed.layouts()),
         "buckets": list(model.buckets),
     }
@@ -189,13 +205,15 @@ def load_model(path: str):
         params: dict = {}
         for i, (layer, key) in enumerate(meta["params"]):
             params.setdefault(layer, {})[key] = jnp.asarray(z[f"p{i}"])
-        ln = set(meta.get("ln_stages", ()))
+        def extras(i):
+            return {field: jnp.asarray(z[f"{prefix}{i}"])
+                    for key, fields in _OPTIONAL.items()
+                    if i in meta.get(key, ())
+                    for field, prefix in fields}
         stages = tuple(
             PackedStage(w8=jnp.asarray(z[f"w{i}"]),
                         w_amax=jnp.asarray(z[f"wa{i}"]),
-                        bias=jnp.asarray(z[f"wb{i}"]),
-                        ln_g=jnp.asarray(z[f"wg{i}"]) if i in ln else None,
-                        ln_b=jnp.asarray(z[f"wh{i}"]) if i in ln else None)
+                        bias=jnp.asarray(z[f"wb{i}"]), **extras(i))
             for i in range(meta.get("packed_stages", 0)))
     program = _program_from_meta(meta["program"])
     config = dict(meta["config"])
@@ -216,7 +234,7 @@ def load_model(path: str):
                                                      version))
                 for st, op in zip(stages, gemms))
         packed = PackedProgram(stages=stages, program=program)
-        if version == 5 and list(packed.layouts()) != meta["layouts"]:
+        if version >= 5 and list(packed.layouts()) != meta["layouts"]:
             raise ValueError(f"{path}: corrupt file — stage layouts "
                              f"{meta['layouts']} where this program lays "
                              f"out {list(packed.layouts())}")

@@ -14,6 +14,10 @@ block built from the sequence ops the crossbar program stack lowers
 (attention expands into dynamic-operand GEMM stages).  The default is a
 CI-scale reduction (2 blocks of the ViT-Tiny geometry: dim 192, 3
 heads, MLP ratio 4); pass ``depth=12`` for the full-size model.
+
+``deit_ti`` is DeiT-Ti at 224x224 as published: pre-norm blocks, a
+class token and a position table (``embed``), exact GELU, LN epsilon
+1e-6, and a head on the class token.
 """
 
 from __future__ import annotations
@@ -121,9 +125,50 @@ def vit_tiny_graph(depth: int = 2, dim: int = 192, heads: int = 3,
 vit_tiny = vit_tiny_graph
 
 
+def deit_graph(depth: int = 12, dim: int = 192, heads: int = 3,
+               mlp_ratio: int = 4, patch: int = 16, input_hw: int = 224,
+               input_ch: int = 3, classes: int = 1000, eps: float = 1e-6,
+               name: str = "deit_ti") -> NetworkGraph:
+    """DeiT-Ti as published (Touvron et al., arXiv:2012.12877, Table 1;
+    the block equations of ViT, arXiv:2010.11929, Eqs. 1-4)::
+
+        z0  = [x_cls; patches(x) E] + E_pos     # hw^2 + 1 tokens
+        z'l = z(l-1) + MSA(LN1(z(l-1)))
+        zl  = z'l + MLP(LN2(z'l))               # GELU (erf)
+        p   = softmax(head(LN(zL[0])))          # the class token
+
+    Pre-norm blocks: every layer norm normalizes a GEMM op's input
+    (``layernorm(pre=True)``), so the residual stream stays un-normed;
+    LN epsilon 1e-6 and exact GELU as in timm's
+    ``deit_tiny_patch16_224``.  The defaults are DeiT-Ti at 224x224:
+    197 tokens of width 192, 3 heads of 64, MLP 768, 1000 classes.
+    """
+    if input_hw % patch:
+        raise ValueError(f"{name}: patch {patch} does not tile "
+                         f"{input_hw}x{input_hw}")
+    nb = NetworkBuilder(name, input_hw=input_hw, input_ch=input_ch)
+    nb.conv(dim, k=patch, stride=patch, padding=0, name="patch")
+    entry = nb.embed(name="embed")
+    for i in range(depth):
+        nb.layernorm(pre=True, eps=eps, name=f"b{i}_ln1")
+        nb.attention(heads, name=f"b{i}_attn")
+        r1 = nb.residual(entry, name=f"b{i}_res1")
+        nb.layernorm(pre=True, eps=eps, name=f"b{i}_ln2")
+        nb.linear(dim * mlp_ratio, name=f"b{i}_fc1")
+        nb.gelu(approx="erf", name=f"b{i}_gelu")
+        nb.linear(dim, name=f"b{i}_fc2")
+        entry = nb.residual(r1, name=f"b{i}_res2")
+    nb.seqpool(mode="cls", name="pool")
+    nb.layernorm(pre=True, eps=eps, name="norm")
+    nb.fc(classes, name="head")
+    nb.softmax(name="softmax")
+    return nb.build()
+
+
 GRAPHS = {
     "alexnet": alexnet_graph,
     "vgg16": vgg16_graph,
     "resnet18": resnet18_graph,
     "vit_tiny": vit_tiny_graph,
+    "deit_ti": deit_graph,
 }

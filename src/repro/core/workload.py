@@ -19,8 +19,17 @@ Two layer vocabularies share this record:
   into the GEMM M axis), ``attention`` is one multi-head self-attention
   layer (``heads`` heads over ``features_in`` channels — the compiler
   expands it into qkv/scores/context/projection stages), ``layernorm``
-  / ``gelu`` are FB post-ops, and ``seqpool`` mean-pools the token axis
-  into a flat feature vector (the classifier-head transition).
+  / ``gelu`` are FB post-ops, and ``seqpool`` pools the token axis into
+  a flat feature vector (the classifier-head transition): the mean of
+  the tokens (``mode="mean"``) or the class token, row 0
+  (``mode="cls"``).  ``embed`` turns a patchify conv's spatial output
+  into tokens with a learned class token prepended and a learned
+  position table added (``in_hw**2 + 1`` tokens).
+
+A GEMM head with ``prenorm`` set normalizes its *input* (a pre-norm
+transformer block, ``x + f(LN(x))``): ``prenorm`` names the layer
+norm's parameters, and residuals that read the input still read it
+un-normed.
 """
 
 from __future__ import annotations
@@ -32,7 +41,8 @@ from typing import Iterator
 # kinds that head a GEMM group (own weights / mounts on the array)
 GEMM_KINDS = ("conv", "fc", "linear", "attention")
 # kinds that only appear in sequence (transformer) graphs
-SEQ_KINDS = ("linear", "attention", "layernorm", "gelu", "seqpool")
+SEQ_KINDS = ("linear", "attention", "layernorm", "gelu", "seqpool",
+             "embed")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,6 +62,11 @@ class LayerSpec:
     input_from: str = ""       # layer whose output this one consumes
                                # ("" = the immediately preceding layer)
     heads: int = 0             # attention only
+    prenorm: str = ""          # GEMM heads: params key of a layer norm
+                               # of the input (pre-norm block)
+    eps: float = 1e-5          # layer-norm epsilon (layernorm, prenorm)
+    approx: str = "tanh"       # gelu form: "tanh" | "erf"
+    mode: str = "mean"         # seqpool: "mean" | "cls" (row 0)
 
     # -- workload numbers used by mapping/cycle models ----------------------
     @property
@@ -141,17 +156,19 @@ WORKLOADS = _WorkloadShim({
 
 
 # canonical FB chain order inside one fused group (gemm implicit first):
-# residual -> relu|gelu -> pool -> layernorm -> seqpool -> softmax.
+# residual -> relu|gelu -> pool -> layernorm -> embed|seqpool -> softmax.
 # The CNN subset (paper Fig 4a merges res under conv, §II-C2 merges ReLU
 # into max pool, softmax consumes the fc head) keeps its historical
 # order; the sequence kinds slot in where post-norm transformer blocks
 # produce them (residual -> layernorm, linear -> gelu, final block ->
-# seqpool).  Activations share a rank (they never chain), and spatial
-# pools can never precede a layernorm because pools are spatial-only
-# while layernorm is sequence-only.  Shared by the program compiler and
-# the api builder's build-time check.
+# seqpool; a patchify conv -> embed).  Activations share a rank (they
+# never chain), as do embed and seqpool (one makes tokens, the other
+# consumes them), and spatial pools can never precede a layernorm
+# because pools are spatial-only while layernorm is sequence-only.
+# Shared by the program compiler and the api builder's build-time check.
 POST_RANK = {"residual": 0, "relu": 1, "gelu": 1, "maxpool": 2,
-             "avgpool": 2, "layernorm": 3, "seqpool": 4, "softmax": 5}
+             "avgpool": 2, "layernorm": 3, "embed": 4, "seqpool": 4,
+             "softmax": 5}
 
 
 def input_spec(layers: list[LayerSpec]) -> tuple[int, int, int, int]:

@@ -10,12 +10,16 @@ round-trips through a separate jnp op.  This extends
 crossbar's int32 -> f32 dequant chain and to window reductions.
 
 The sequence workload class (DESIGN.md §9) adds three FB ops on top of
-the CNN chain: **GELU** (a LUT activation like the softmax exp), **layer
-norm** (mean/variance row statistics in the SnA datapath, then a scale
-and shift — the transformer analogue of the shift-and-add requant), and
-**seq-mean pooling** (the classifier-head token reduction, a 1-D window
-average over one sequence's rows).  A static ``post_scale`` factor
-multiplies the dequantized tile before the activation — attention
+the CNN chain: **GELU** (a LUT activation like the softmax exp; the
+tanh form), **layer norm** (mean/variance row statistics in the SnA
+datapath, then a scale and shift — the transformer analogue of the
+shift-and-add requant; its epsilon ``eps`` is static), and **seq-mean
+pooling** (the classifier-head token reduction, a 1-D window average
+over one sequence's rows).  The exact GELU (``gelu_erf``) is no mode of
+the kernel: Mosaic has no lowering for ``erf``, so the executor applies
+it with XLA to the kernel's output (DESIGN.md §9).  A static
+``post_scale`` factor multiplies the dequantized tile before the
+activation — attention
 programs fold `1/sqrt(head_dim)` into the scores stage there, keeping
 the float op order identical to the functional oracle's
 ``softmax(scores * sm_scale)``.
@@ -51,16 +55,20 @@ The per-column operands (bias, layer-norm gamma/beta) enter the kernel
 as ``(1, N)`` rows with ``(1, block_n)`` blocks: a 1-D block has no
 layout the TPU tiling and XLA agree on.
 
-Block activation is pad-to-block: when (M, N) do not divide the
-(clamped) block sizes, operands are zero-padded up to the block
+Block activation is pad-to-block over rows: when M does not divide
+the (clamped) row block, operands are zero-padded up to the block
 multiple, full-size tiles run, and the result is sliced back — every
-row/column is processed independently by the FB chain, so the padding
-is slice-exact and callers never tune divisor blocks.  The structural
+row is processed independently by the FB chain, so the padding is
+slice-exact and callers never tune divisor blocks.  Columns are never
+padded: an N that ``block_n`` does not divide takes one full-width
+block (``block_n = N``, which the TPU tiling always accepts).  A
+padded and sliced-back column axis would reach the next stage as a
+slice, and XLA's reduction over a sliced row (a pre-norm's mean)
+rounds apart from its reduction over a plain one.  The structural
 constraints remain: pooling fixes M to ``B * img_hw^2`` (or ``B * T``
 for seqmean — rows are padded by whole images or sequences there), and
-softmax / layer norm need the full feature axis in-tile (``block_n =
-N``, never padded).  On TPU proper, multiples of (8, 128) pick the fast
-path.
+softmax / layer norm need the full feature axis in-tile.  On TPU
+proper, multiples of (8, 128) pick the fast path.
 """
 
 from __future__ import annotations
@@ -85,6 +93,13 @@ def gelu(x: jnp.ndarray) -> jnp.ndarray:
     return 0.5 * x * (1.0 + jnp.tanh(_GELU_C * (x + 0.044715 * x * x * x)))
 
 
+def gelu_erf(x: jnp.ndarray) -> jnp.ndarray:
+    """Exact GELU, ``x * Phi(x)`` (ViT, DeiT).  Not a kernel mode:
+    Mosaic cannot lower ``erf``, so the executor evaluates this with XLA
+    on the kernel's output, the expression the oracle traces."""
+    return 0.5 * x * (1.0 + jax.lax.erf(x * 0.7071067811865476))
+
+
 def layer_norm_rows(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
                     eps: float = LN_EPS) -> jnp.ndarray:
     """Per-row layer norm over the last axis, then scale and shift.
@@ -97,6 +112,38 @@ def layer_norm_rows(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
     d = x - m
     v = jnp.mean(d * d, axis=-1, keepdims=True)
     return d / jnp.sqrt(v + eps) * gamma + beta
+
+
+def ordered_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the last axis (kept) in one fixed pairwise order, as
+    elementwise adds of its halves.  XLA rounds an elementwise add the
+    same in any fusion and layout, while the order of its ``reduce``
+    follows the layout it picks for the operand: a row sum of an XLA
+    tail (below) must round as the reference's does (DESIGN.md §9)."""
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        y = x[..., :h] + x[..., h:2 * h]
+        x = jnp.concatenate([y, x[..., 2 * h:]], axis=-1)
+    return x
+
+
+def layer_norm_ordered(x: jnp.ndarray, gamma: jnp.ndarray,
+                       beta: jnp.ndarray, eps: float = LN_EPS
+                       ) -> jnp.ndarray:
+    """``layer_norm_rows`` with ``ordered_sum`` row sums: the pre-norm,
+    which XLA evaluates in the operand build of the stage it feeds."""
+    n = x.shape[-1]
+    m = ordered_sum(x) / n
+    d = x - m
+    v = ordered_sum(d * d) / n
+    return d / jnp.sqrt(v + eps) * gamma + beta
+
+
+def softmax_ordered(x: jnp.ndarray) -> jnp.ndarray:
+    """``softmax_rows`` with an ``ordered_sum`` denominator: attention's
+    softmax, which XLA evaluates on the epilogue kernel's output."""
+    e = jnp.exp(x - jnp.max(x, axis=-1, keepdims=True))
+    return e / ordered_sum(e)
 
 
 def softmax_rows(x: jnp.ndarray) -> jnp.ndarray:
@@ -113,7 +160,7 @@ def softmax_rows(x: jnp.ndarray) -> jnp.ndarray:
 
 def _kernel(y_ref, scale_ref, b_ref, res_ref, g_ref, bt_ref, o_ref, *,
             act: str, pool: str, window: int, img_hw: int, softmax: bool,
-            norm: str, post_scale: float, has_residual: bool):
+            norm: str, eps: float, post_scale: float, has_residual: bool):
     y = (y_ref[...].astype(jnp.float32) * scale_ref[0, 0]
          + b_ref[...].astype(jnp.float32))
     if has_residual:
@@ -126,7 +173,7 @@ def _kernel(y_ref, scale_ref, b_ref, res_ref, g_ref, bt_ref, o_ref, *,
         y = gelu(y)
     if norm == "layer":
         y = layer_norm_rows(y, g_ref[...].astype(jnp.float32),
-                            bt_ref[...].astype(jnp.float32))
+                            bt_ref[...].astype(jnp.float32), eps)
     bn = y.shape[-1]
     if pool == "seqmean":                # window = tokens per sequence
         y = jnp.mean(y.reshape(-1, window, bn), axis=1)
@@ -142,15 +189,16 @@ def _kernel(y_ref, scale_ref, b_ref, res_ref, g_ref, bt_ref, o_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("act", "pool", "window",
                                              "img_hw", "softmax", "norm",
-                                             "post_scale", "block_m",
+                                             "eps", "post_scale", "block_m",
                                              "block_n", "interpret"))
 def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
                 residual: jnp.ndarray | None = None, *, act: str = "none",
                 pool: str = "none", window: int = 0, img_hw: int = 0,
                 softmax: bool = False, norm: str = "none",
                 gamma: jnp.ndarray | None = None,
-                beta: jnp.ndarray | None = None, post_scale: float = 0.0,
-                block_m: int | None = None, block_n: int | None = None,
+                beta: jnp.ndarray | None = None, eps: float = LN_EPS,
+                post_scale: float = 0.0, block_m: int | None = None,
+                block_n: int | None = None,
                 interpret: bool = False) -> jnp.ndarray:
     """y (M, N) int32 crossbar output -> fused FB chain -> f32.
 
@@ -161,9 +209,10 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     image (M = B * img_hw^2, output (B * (img_hw//window)^2, N));
     ``seqmean`` mean-reduces each sequence's ``window`` token rows
     (M = B * window, output (B, N)).  ``norm="layer"`` applies
-    ``layer_norm_rows`` with ``gamma``/``beta`` (N,) after the
-    activation.  ``post_scale`` (static) multiplies the dequantized tile
-    before the activation — attention scores fold `1/sqrt(hd)` here.
+    ``layer_norm_rows`` with ``gamma``/``beta`` (N,) and epsilon ``eps``
+    (static) after the activation.  ``post_scale`` (static) multiplies
+    the dequantized tile before the activation — attention scores fold
+    `1/sqrt(hd)` here.
     ``softmax=True`` (exclusive with pool) normalizes over the full
     feature axis -> (M, N).  Block sizes default to ``tiling.py``'s
     epilogue entry.
@@ -187,9 +236,9 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     dm, dn = default_blocks("epilogue")
     block_m, block_n = block_m or dm, block_n or dn
     # pad-to-block activation (module docstring): pad rows (by whole
-    # images or sequences when pooling), pad cols unless softmax or
-    # layer norm span the full feature axis; run full tiles, slice back.
-    if softmax or has_norm:
+    # images or sequences when pooling), run full tiles, slice back;
+    # one full-width column block where block_n does not divide N
+    if softmax or has_norm or N % min(block_n, N):
         block_n = N              # the row reduction needs every column
     block_n = min(block_n, N)
     if pool == "seqmean":
@@ -207,34 +256,32 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         pm = -n_groups % k * group_rows
     else:
         pm = -M % min(block_m, M)
-    pn = -N % block_n
-    if pm or pn:
-        y = jnp.pad(y, ((0, pm), (0, pn)))
-        bias = jnp.pad(bias, ((0, 0), (0, pn)))
+    if pm:
+        y = jnp.pad(y, ((0, pm), (0, 0)))
         if has_residual:
-            res = jnp.pad(res, ((0, pm), (0, pn)))
-    Mp, Np = M + pm, N + pn
+            res = jnp.pad(res, ((0, pm), (0, 0)))
+    Mp = M + pm
 
     if pool != "none":
         n_steps = Mp // (k * group_rows)
-        grid = (n_steps, Np // block_n)
+        grid = (n_steps, N // block_n)
         row_spec = pl.BlockSpec((k * group_rows, block_n),
                                 lambda i, j: (i, j))
         out_spec = pl.BlockSpec((k * out_rows, block_n), lambda i, j: (i, j))
-        out_shape = jax.ShapeDtypeStruct((n_steps * k * out_rows, Np),
+        out_shape = jax.ShapeDtypeStruct((n_steps * k * out_rows, N),
                                          jnp.float32)
     else:
         block_m = min(block_m, Mp)
-        grid = (Mp // block_m, Np // block_n)
+        grid = (Mp // block_m, N // block_n)
         row_spec = pl.BlockSpec((block_m, block_n), lambda i, j: (i, j))
         out_spec = row_spec
-        out_shape = jax.ShapeDtypeStruct((Mp, Np), jnp.float32)
+        out_shape = jax.ShapeDtypeStruct((Mp, N), jnp.float32)
 
     one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     col_spec = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
     kernel = functools.partial(_kernel, act=act, pool=pool, window=window,
                                img_hw=img_hw, softmax=softmax, norm=norm,
-                               post_scale=post_scale,
+                               eps=eps, post_scale=post_scale,
                                has_residual=has_residual)
     out = pl.pallas_call(
         kernel,
@@ -254,8 +301,6 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         # HLO, which trace readers match on
         name="fb_epilogue",
     )(y, scale, bias, res, g, bt)
-    if pn:
-        out = out[:, :N]
     if pm:                       # drop the padded rows (images, sequences)
         out = out[:M if pool == "none" else n_groups * out_rows]
     return out

@@ -109,7 +109,7 @@ def fb_epilogue_ref(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
                     act: str = "none", pool: str = "none", window: int = 0,
                     img_hw: int = 0, softmax: bool = False,
                     norm: str = "none", gamma: jnp.ndarray | None = None,
-                    beta: jnp.ndarray | None = None,
+                    beta: jnp.ndarray | None = None, eps: float = 1e-5,
                     post_scale: float = 0.0) -> jnp.ndarray:
     """The unfused jnp composition the fb_epilogue kernel must equal:
     dequant -> +bias -> +residual -> [* post_scale] -> ReLU|GELU ->
@@ -136,7 +136,7 @@ def fb_epilogue_ref(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     if norm == "layer":
         mu = out.mean(axis=-1, keepdims=True)
         var = ((out - mu) ** 2).mean(axis=-1, keepdims=True)
-        out = ((out - mu) / jnp.sqrt(var + 1e-5)
+        out = ((out - mu) / jnp.sqrt(var + eps)
                * gamma.astype(jnp.float32) + beta.astype(jnp.float32))
     elif norm != "none":
         raise ValueError(norm)

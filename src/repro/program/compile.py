@@ -42,9 +42,20 @@ Algorithm 1/2 vocabulary, and a dynamic stage's element count is
 unknown at compile time), so sequence groups skip ``plan_array``.
 
 The FB op vocabulary is ``gemm | dyn_gemm | relu | gelu | maxpool |
-avgpool | layernorm | seqpool | residual | softmax``; post-ops must
-follow the canonical FB chain order ``residual -> relu|gelu -> pool ->
-layernorm -> seqpool -> softmax`` (``core.workload.POST_RANK``).
+avgpool | layernorm | embed | seqpool | residual | softmax``; post-ops
+must follow the canonical FB chain order ``residual -> relu|gelu ->
+pool -> layernorm -> embed|seqpool -> softmax``
+(``core.workload.POST_RANK``).
+
+**Pre-norm blocks and the class token.**  A GEMM head's ``prenorm``
+lowers onto the stage that reads the normed input — for attention the
+fused qkv projection — as that op's ``prenorm``/``eps``: the executor
+normalizes the stage input while it builds the int8 operand, so LN(x)
+is never a buffer and the residual source stays x.  ``embed`` is a
+post-op of the patchify conv's stage.  A class-token pool
+(``seqpool(mode="cls")``) emits no op: the GEMM head that reads it
+takes its source's row 0 (``select="cls"``), so the stage before it
+writes the whole token buffer.
 """
 
 from __future__ import annotations
@@ -98,7 +109,7 @@ class ProgramOp:
     """One FB op of the static program (see module docstring)."""
 
     kind: str                  # gemm|dyn_gemm|relu|gelu|maxpool|avgpool|
-                               # layernorm|seqpool|residual|softmax
+                               # layernorm|embed|seqpool|residual|softmax
     name: str                  # producing workload layer
     src: str                   # input buffer (a ProgramOp name or "input")
     dst: str                   # output buffer (== name)
@@ -121,6 +132,11 @@ class ProgramOp:
     dyn_src: str = ""          # buffer mounted as the dynamic operand
     heads: int = 0
     post_scale: float = 0.0    # static factor folded into the epilogue
+    # sequence FBs
+    prenorm: str = ""          # gemm: params key of its input's layer norm
+    eps: float = 1e-5          # layer-norm epsilon (layernorm, prenorm)
+    approx: str = "tanh"       # gelu form: "tanh" | "erf"
+    select: str = ""           # gemm: "cls" reads row 0 of each sequence
     # pool
     window: int = 0            # pool window edge (== stride; VALID)
     in_hw: int = 0             # spatial extent entering the pool
@@ -195,7 +211,9 @@ class CrossbarProgram:
                  f"{self.n_mount_rounds} mount rounds"
                  + (" + dynamic mounts" if self.has_dynamic_stages else "")]
         for gemm, posts in self.stages():
-            chain = "+".join([gemm.kind] + [p.kind for p in posts])
+            pre = (([gemm.select] if gemm.select else [])
+                   + (["prenorm"] if gemm.prenorm else []))
+            chain = "+".join(pre + [gemm.kind] + [p.kind for p in posts])
             mounts = (f"mounts {len(gemm.mount_rounds)}"
                       if gemm.kind == "gemm" else f"dyn[{gemm.dyn}]")
             lines.append(
@@ -226,16 +244,40 @@ def _mount_rounds(K: int, N: int, tile_rows: int,
 
 
 def _is_seq_group(group) -> bool:
+    # embed is a patchify conv's post-op: that group lowers as a CNN one
     return (group[0].kind in ("linear", "attention")
-            or any(l.kind in SEQ_KINDS for l in group))
+            or any(l.kind in SEQ_KINDS and l.kind != "embed"
+                   for l in group))
+
+
+def _source(head, prev: str, finals: set[str],
+            cls_pools: dict[str, str]) -> tuple[str, str]:
+    """A GEMM head's input buffer and ``select``: a class-token pool's
+    name resolves to the buffer it pools, read with ``select="cls"``."""
+    src = head.input_from or prev
+    if src not in finals:
+        raise ValueError(f"{head.name} consumes unknown buffer {src!r}")
+    if src in cls_pools:
+        return cls_pools[src], "cls"
+    return src, ""
+
+
+def _norm_fields(head) -> dict:
+    """A GEMM head's pre-norm as its input stage's op fields."""
+    return {"prenorm": head.prenorm, "eps": head.eps} if head.prenorm else {}
 
 
 def _seq_posts(group, head_dst: str, finals: set[str],
-               ops: list[ProgramOp]) -> str:
-    """Emit the sequence group's post-op chain; returns the final buffer."""
+               ops: list[ProgramOp], cls_pools: dict[str, str]) -> str:
+    """Emit the sequence group's post-op chain; returns the final buffer
+    (a class-token pool's name, recorded in ``cls_pools``, when the
+    chain ends in one)."""
     rank = -1
     cur = head_dst
     for l in group[1:]:
+        if cur in cls_pools:
+            raise ValueError(f"{l.name}: a class-token pool ends its group "
+                             "(only a GEMM head reads it)")
         if l.kind not in POST_RANK:
             raise ValueError(f"unsupported FB op {l.kind} ({l.name})")
         if POST_RANK[l.kind] <= rank:
@@ -251,7 +293,16 @@ def _seq_posts(group, head_dst: str, finals: set[str],
                                  f"{l.residual_from!r} not materialized")
             extra = {"res_src": l.residual_from}
         if l.kind == "layernorm":
-            extra = {"param": l.name}
+            extra = {"param": l.name, "eps": l.eps}
+        if l.kind == "gelu":
+            extra = {"approx": l.approx}
+        if l.kind == "gelu" and l.approx == "erf" and l is not group[-1]:
+            raise ValueError(f"{l.name}: an erf GELU ends its FB chain (it "
+                             "runs after the epilogue kernel, in XLA)")
+        if l.kind == "seqpool" and l.mode == "cls":
+            cls_pools[l.name] = cur
+            cur = l.name
+            continue
         ops.append(ProgramOp(
             kind=l.kind, name=l.name, src=cur, dst=l.name,
             out_ch=l.features_out, seq=True, **extra))
@@ -260,18 +311,16 @@ def _seq_posts(group, head_dst: str, finals: set[str],
 
 
 def _lower_seq_group(group, chip: ChipConfig, finals: set[str], prev: str,
-                     ops: list[ProgramOp]) -> str:
+                     ops: list[ProgramOp], cls_pools: dict[str, str]) -> str:
     """Lower one sequence group; returns its final buffer name."""
     head = group[0]
     planes = chip.weight_planes
     reserve = sum(_SEQ_FB_ROWS[l.kind] for l in group[1:]
                   if l.kind in _SEQ_FB_ROWS)
-    src = head.input_from or prev
-    if src not in finals:
-        raise ValueError(f"{head.name} consumes unknown buffer {src!r}")
+    src, select = _source(head, prev, finals, cls_pools)
 
     def seq_gemm(name, src, dst, *, K, N, w_key="w", b_key="b",
-                 param=None, rows_reserve=reserve):
+                 param=None, rows_reserve=reserve, **extra):
         tile_rows = max(1, min(K, chip.array_rows - rows_reserve))
         tile_cols = max(1, min(N, chip.array_cols // planes))
         return ProgramOp(
@@ -279,12 +328,13 @@ def _lower_seq_group(group, chip: ChipConfig, finals: set[str], prev: str,
             param=head.name if param is None else param, w_key=w_key,
             b_key=b_key, seq=True, out_ch=N, tile_rows=tile_rows,
             tile_cols=tile_cols,
-            mount_rounds=_mount_rounds(K, N, tile_rows, tile_cols))
+            mount_rounds=_mount_rounds(K, N, tile_rows, tile_cols), **extra)
 
     if head.kind == "linear":
         ops.append(seq_gemm(head.name, src, head.name,
-                            K=head.features_in, N=head.features_out))
-        return _seq_posts(group, head.name, finals, ops)
+                            K=head.features_in, N=head.features_out,
+                            select=select, **_norm_fields(head)))
+        return _seq_posts(group, head.name, finals, ops, cls_pools)
 
     if head.kind != "attention":
         # raw LayerSpec lists can still reach here (the builder rejects
@@ -297,9 +347,11 @@ def _lower_seq_group(group, chip: ChipConfig, finals: set[str], prev: str,
     hd = d // h
     qkv, scores = f"{head.name}@qkv", f"{head.name}@scores"
     probs, ctx = f"{head.name}@probs", f"{head.name}@ctx"
-    # 1. fused qkv projection: one compile-time weight mount, N = 3D
+    # 1. fused qkv projection: one compile-time weight mount, N = 3D;
+    #    a pre-norm normalizes its input
     ops.append(seq_gemm(qkv, src, qkv, K=d, N=3 * d,
-                        w_key="wqkv", b_key="bqkv", rows_reserve=0))
+                        w_key="wqkv", b_key="bqkv", rows_reserve=0,
+                        select=select, **_norm_fields(head)))
     # 2. Q·Kᵀ scores: dynamic K-operand mount, softmax FB fused with the
     #    1/sqrt(hd) logit scale; contraction length is the head dim
     ops.append(ProgramOp(
@@ -324,7 +376,7 @@ def _lower_seq_group(group, chip: ChipConfig, finals: set[str], prev: str,
     #    post-ops (residual/layernorm/...) fuse onto this stage
     ops.append(seq_gemm(head.name, ctx, head.name, K=d, N=d,
                         w_key="wo", b_key="bo"))
-    return _seq_posts(group, head.name, finals, ops)
+    return _seq_posts(group, head.name, finals, ops, cls_pools)
 
 
 def compile_network(net, *, config=None,
@@ -362,11 +414,12 @@ def compile_network(net, *, config=None,
     ops: list[ProgramOp] = []
     plans: list[ArrayPlan] = []
     finals: set[str] = {"input"}
+    cls_pools: dict[str, str] = {}   # class-token pool -> buffer it pools
     prev = "input"
     for group in layer_groups(layers):
         head = group[0]
         if _is_seq_group(group):
-            cur = _lower_seq_group(group, chip, finals, prev, ops)
+            cur = _lower_seq_group(group, chip, finals, prev, ops, cls_pools)
             prev = cur
             finals.add(cur)
             continue
@@ -383,9 +436,7 @@ def compile_network(net, *, config=None,
         tile_rows = reqs[0].req_rows
         tile_cols = max(1, reqs[0].req_cols // planes)
 
-        src = head.input_from or prev
-        if src not in finals:
-            raise ValueError(f"{head.name} consumes unknown buffer {src!r}")
+        src, select = _source(head, prev, finals, cls_pools)
         ops.append(ProgramOp(
             kind="gemm", name=head.name, src=src, dst=head.name,
             param=head.name, is_conv=head.kind == "conv",
@@ -393,6 +444,7 @@ def compile_network(net, *, config=None,
             out_hw=head.out_hw, out_ch=N, tile_rows=tile_rows,
             tile_cols=tile_cols,
             mount_rounds=_mount_rounds(K, N, tile_rows, tile_cols),
+            select=select, **_norm_fields(head),
             **_fb_fields(plan, ("conv", "fc"))))
 
         rank = -1
@@ -418,6 +470,8 @@ def compile_network(net, *, config=None,
                     raise ValueError(f"{l.name} residual source "
                                      f"{l.residual_from!r} not materialized")
                 extra = {"res_src": l.residual_from}
+            if l.kind == "embed":        # patch tokens: class token, pos
+                extra = {"param": l.name, "seq": True}
             ops.append(ProgramOp(
                 kind=l.kind, name=l.name, src=cur, dst=l.name,
                 out_ch=l.out_ch or l.features_out, **extra,
@@ -426,6 +480,9 @@ def compile_network(net, *, config=None,
         prev = cur
         finals.add(cur)
 
+    if prev in cls_pools:
+        raise ValueError(f"{prev}: a class-token pool ends the network; "
+                         "only a GEMM head reads it")
     logits = next(op.dst for op in reversed(ops) if op.kind == "gemm")
     if hasattr(net, "input_shape"):       # a NetworkGraph carries its spec
         ihw, ich, ifeat = net.in_hw, net.in_ch, net.in_features

@@ -35,7 +35,24 @@ scheduled program is *the* thing that computes:
   runs in ONE pass of the fused ``fb_epilogue`` Pallas kernel over the
   GEMM output tile, so the crossbar output never round-trips through a
   separate jnp op — the numeric analogue of HURRY hiding FB post-ops
-  inside the array.
+  inside the array;
+* sequence stages add steps around that (``compile.py``): a stage
+  that reads a class-token pool takes row 0 of each sequence of its
+  source (``select="cls"``); a pre-norm stage layer-normalizes its
+  input while it builds the operand, before ``amax`` and the int8
+  convert, so LN(x) is never a stage buffer; and a patchify stage's
+  ``embed`` prepends the class token to its output tokens and adds the
+  position table (``sequence.embed_tokens``);
+* two float FB ops run as XLA ops on the epilogue kernel's output, in
+  its ``epilogue`` phase: the exact GELU (Mosaic cannot lower ``erf``;
+  it ends its stage's chain) and attention's softmax.  The next stage
+  re-quantizes both, so a result a few ulps apart from an XLA
+  evaluation of the same expression (Mosaic sums a row in another
+  order) flips int8 roundings there, which a deep network amplifies:
+  on a TPU v5e the 12-block DeiT-Ti read ``prob_err`` 0.087 against
+  the oracle with its softmax in Mosaic (PERF.md §6).  In XLA, the
+  program's float tails round as any XLA evaluation of the reference
+  does.
 
 **Dynamic-operand stages** (``kind="dyn_gemm"``, DESIGN.md §9) extend
 the same machinery to attention's activation-side GEMMs: per (batch,
@@ -63,19 +80,24 @@ contraction; DESIGN.md §5).  Read noise is a functional-model-only
 experiment: the program path models a clean chip.
 
 **Named scopes.**  Every op a stage issues carries one stage scope,
-``s<NN>.<buffer>`` (the stage's index and the buffer it writes), and
-one phase scope inside it, so a compiled program's ``op_name``
-metadata says which stage and which phase each instruction serves:
+``s<NN>.<buffer>`` (the stage's index and the buffer it writes, an
+attention buffer ``<layer>@qkv`` spelt ``<layer>.qkv``: JAX ends a
+scope's name at ``@``), and one phase scope inside it, so a compiled
+program's ``op_name`` metadata says which stage and which phase each
+instruction serves:
 
 * ``im2col`` — im2col (a dense stage's tap concat on the int8
   tensor), token and flatten reshapes, head splits;
-* ``quantize`` — the activation's amax reduction and int8 convert;
+* ``quantize`` — the activation's amax reduction and int8 convert,
+  and inside it ``prenorm``, a pre-norm stage's layer norm of its
+  input;
 * ``mount`` — ``mounted_gemm``'s mount layout, its K/M/N block pads and
   slice back, and ``plane_pack`` of a dynamic stage's right-hand
   operand;
 * ``gemm`` — the ``mounted_gemm`` kernel;
 * ``epilogue`` — the scale product, the ``fb_epilogue`` kernel and the
-  output reshape.
+  output reshape, and inside it ``embed``, a patchify stage's class
+  token and position table.
 
 Scopes are metadata only: they change no op and cost nothing at run
 time.
@@ -96,14 +118,15 @@ import jax.numpy as jnp
 
 from repro.core.crossbar import quantize_scale, quantize_symmetric
 from repro.kernels.crossbar_gemm import mounted_gemm
-from repro.kernels.fb_epilogue import fb_epilogue
+from repro.kernels.fb_epilogue import (LN_EPS, fb_epilogue, gelu_erf,
+                                       layer_norm_ordered, softmax_ordered)
 from repro.kernels.ops import interpret_default
 from repro.models.cnn import im2col, im2col_read_mask
 
 from .compile import CrossbarProgram, ProgramOp
 from .pack import (PackedProgram, PackedStage, pack_program, plane_pack,
                    stage_layout)
-from .sequence import merge_heads, split_qkv_heads, tokens
+from .sequence import embed_tokens, merge_heads, split_qkv_heads, tokens
 
 
 class Kernels(NamedTuple):
@@ -152,28 +175,32 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
             raise ValueError(gemm.dyn)
     softmax = any(p.kind == "softmax" for p in posts)
     rows = min(gemm.tile_rows, a.shape[-1])      # dynamic mount height
+    # each phase vmaps over (batch, head) inside its own scope: a scope
+    # opened inside a vmapped function is named "vmap(<phase>)"
+    with jax.named_scope("quantize"):
+        aq, ascale = jax.vmap(
+            lambda a2: quantize_symmetric(a2, cfg.input_bits))(a)
+        aq = aq.astype(jnp.int8)
+    with jax.named_scope("mount"):
+        w8, wamax = jax.vmap(lambda w2: plane_pack(
+            w2, tile_rows=rows, weight_bits=cfg.weight_bits))(w)
+    acc = jax.vmap(lambda a2, w2: kernels.gemm(
+        a2, w2, adc_bits=cfg.adc_bits, rows=rows, block_m=block_m,
+        block_n=block_n, interpret=interpret))(aq, w8)
+    with jax.named_scope("epilogue"):
+        zeros = jnp.zeros((w.shape[2],), jnp.float32)
 
-    def one(a2, w2):
-        with jax.named_scope("quantize"):
-            aq, ascale = quantize_symmetric(a2, cfg.input_bits)
-            aq = aq.astype(jnp.int8)
-        with jax.named_scope("mount"):
-            w8, wamax = plane_pack(w2, tile_rows=rows,
-                                   weight_bits=cfg.weight_bits)
-        y = kernels.gemm(aq, w8, adc_bits=cfg.adc_bits, rows=rows,
-                         block_m=block_m, block_n=block_n,
-                         interpret=interpret)
-        with jax.named_scope("epilogue"):
-            ws = quantize_scale(wamax, cfg.weight_bits)
-            scale = (ascale * ws).astype(jnp.float32).reshape(1, 1)
+        def epilogue(y, s, wm):
+            ws = quantize_scale(wm, cfg.weight_bits)
+            scale = (s * ws).astype(jnp.float32).reshape(1, 1)
             return kernels.epilogue(
-                y, scale, jnp.zeros((w2.shape[1],), jnp.float32), None,
-                softmax=softmax, post_scale=gemm.post_scale,
-                block_m=block_m, block_n=block_n, interpret=interpret), y
+                y, scale, zeros, None, post_scale=gemm.post_scale,
+                block_m=block_m, block_n=block_n, interpret=interpret)
 
-    out, acc = jax.vmap(one)(a, w)
-    if gemm.dyn == "pv":                         # heads rejoin the model dim
-        with jax.named_scope("epilogue"):
+        out = jax.vmap(epilogue)(acc, ascale, wamax)
+        if softmax:                    # XLA's softmax (module docstring)
+            out = softmax_ordered(out)
+        if gemm.dyn == "pv":                     # heads rejoin the model dim
             out = merge_heads(out, gemm.heads)
     return out, acc
 
@@ -227,10 +254,18 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
     src = bufs[gemm.src]
     b = src.shape[0]
     t = 0
-    if gemm.seq:
+    if gemm.seq or gemm.select:
         with jax.named_scope("im2col"):
             src = tokens(src)
-        t = src.shape[1]
+            if gemm.select == "cls":         # the class token, row 0
+                src = src[:, 0]
+        t = src.shape[1] if gemm.seq else 0
+    if gemm.prenorm:
+        if not gemm.seq:
+            with jax.named_scope("im2col"):
+                src = _gemm_rows(src, False)
+        with jax.named_scope("quantize"), jax.named_scope("prenorm"):
+            src = layer_norm_ordered(src, st.pre_g, st.pre_b, gemm.eps)
     layout = stage_layout(gemm, cfg)
     if layout == "dense":
         xq, xs = _dense_operand(gemm, src, cfg.input_bits)
@@ -248,16 +283,20 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
                          rows=gemm.tile_rows, block_m=block_m,
                          block_n=block_n, interpret=interpret, layout=layout)
     act, pool, window, img_hw, norm = "none", "none", 0, 0, "none"
-    softmax, res = False, None
+    softmax, res, eps, embed, erf_gelu = False, None, LN_EPS, False, False
     out_hw = gemm.out_hw
     dst = posts[-1].dst if posts else gemm.dst
     for op in posts:
         if op.kind == "relu":
             act = "relu"
+        elif op.kind == "gelu" and op.approx == "erf":
+            erf_gelu = True            # XLA, after the kernel
         elif op.kind == "gelu":
             act = "gelu"
         elif op.kind == "layernorm":
-            norm = "layer"
+            norm, eps = "layer", op.eps
+        elif op.kind == "embed":
+            embed = True
         elif op.kind == "residual":
             res = bufs[op.res_src]
         elif op.kind in ("maxpool", "avgpool"):
@@ -283,12 +322,17 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
         out = kernels.epilogue(y_int, scale, st.bias, res, act=act,
                                pool=pool, window=window, img_hw=img_hw,
                                softmax=softmax, norm=norm, gamma=st.ln_g,
-                               beta=st.ln_b, block_m=block_m,
+                               beta=st.ln_b, eps=eps, block_m=block_m,
                                block_n=block_n, interpret=interpret)
         if gemm.is_conv:
             out = out.reshape(b, out_hw, out_hw, -1)
         elif gemm.seq and pool != "seqmean":
             out = out.reshape(b, t, -1)
+        if erf_gelu:
+            out = gelu_erf(out)
+        if embed:
+            with jax.named_scope("embed"):
+                out = embed_tokens(tokens(out), st.emb_cls, st.emb_pos)
     return dst, out, y_int
 
 
@@ -332,7 +376,7 @@ def stage_outputs(packed: PackedProgram, x: jnp.ndarray, *,
     last = _last_reads(stages)
     for si, ((gemm, posts), st) in enumerate(zip(stages, packed.stages)):
         dst = posts[-1].dst if posts else gemm.dst
-        with jax.named_scope(f"s{si:02d}.{dst}"):
+        with jax.named_scope(f"s{si:02d}.{dst.replace('@', '.')}"):
             if gemm.kind == "dyn_gemm":
                 out, acc = _dyn_stage(gemm, posts, src, cfg,
                                       block_m=block_m, block_n=block_n,

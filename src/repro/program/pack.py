@@ -42,14 +42,16 @@ mounting are one code path, so the exactness argument transfers
 verbatim.
 
 The result is a ``PackedProgram`` — a jax pytree whose leaves are the
-per-stage ``(w8, w_amax, bias[, ln_g, ln_b])`` arrays and whose static
-treedef carries the (plan-free) program — that ``execute_packed``
-consumes directly.  ``PackedProgram.layouts()`` says which layout each
-stage took.  Layer-norm FBs fused onto a stage carry their
-gamma/beta here too, so the packed executor never reads the float
-param pytree.  Dynamic-operand stages own no weights: they pack as
-empty placeholders (their mounts materialize per batch in the
-executor).  The hot loop then only quantizes *activations* (the
+per-stage ``(w8, w_amax, bias[, ln_g, ln_b, pre_g, pre_b, emb_cls,
+emb_pos])`` arrays and whose static treedef carries the (plan-free)
+program — that ``execute_packed`` consumes directly.
+``PackedProgram.layouts()`` says which layout each stage took.
+Layer-norm FBs fused onto a stage carry their gamma/beta here too, as
+do a stage's pre-norm (the layer norm of its input) and a patchify
+stage's class token and position table, so the packed executor never
+reads the float param pytree.  Dynamic-operand stages own no
+weights: they pack as empty placeholders (their mounts materialize per
+batch in the executor).  The hot loop then only quantizes *activations* (the
 data-dependent quantities) and dispatches kernels; no weight touches
 float math again.  Packing eagerly and quantizing under jit produce
 bit-identical planes: ``quantize_symmetric`` is abs/max/divide/round —
@@ -86,9 +88,12 @@ class PackedStage:
     per-tensor ``max(|w|)`` from which the executor derives the
     symmetric quantization scale in-graph (``quantize_scale``);
     ``bias`` the f32 per-column bias.  ``ln_g``/``ln_b`` are the fused
-    layer-norm FB's gamma/beta when the stage's post chain has one
-    (``None`` otherwise).  Dynamic-operand stages are empty placeholders
-    (0-sized ``w8``): their operands mount per batch in the executor.
+    layer-norm FB's gamma/beta when the stage's post chain has one,
+    ``pre_g``/``pre_b`` those of its input's layer norm (``prenorm``),
+    and ``emb_cls``/``emb_pos`` the class token and position table of
+    an ``embed`` FB (each ``None`` where the stage has no such op).
+    Dynamic-operand stages are empty placeholders (0-sized ``w8``):
+    their operands mount per batch in the executor.
     """
 
     w8: jnp.ndarray
@@ -96,6 +101,10 @@ class PackedStage:
     bias: jnp.ndarray
     ln_g: jnp.ndarray | None = None
     ln_b: jnp.ndarray | None = None
+    pre_g: jnp.ndarray | None = None
+    pre_b: jnp.ndarray | None = None
+    emb_cls: jnp.ndarray | None = None
+    emb_pos: jnp.ndarray | None = None
 
 
 def dyn_placeholder() -> PackedStage:
@@ -181,6 +190,13 @@ def pack_weight(w: jnp.ndarray, *, is_conv: bool, tile_rows: int,
                       layout=layout)
 
 
+def _op_params(params: dict, key: str) -> dict:
+    """An FB's float32 parameters (``params[key]``; none for ``""``)."""
+    if not key:
+        return {}
+    return {k: v.astype(jnp.float32) for k, v in params[key].items()}
+
+
 @functools.partial(jax.jit, static_argnums=(0,))
 def pack_program(program: CrossbarProgram, params: dict) -> PackedProgram:
     """Mount ``params`` into ``program``: the compile-time analogue of
@@ -205,12 +221,16 @@ def pack_program(program: CrossbarProgram, params: dict) -> PackedProgram:
                                tile_rows=gemm.tile_rows,
                                weight_bits=cfg.weight_bits,
                                layout=stage_layout(gemm, cfg))
-        ln = next((o for o in posts if o.kind == "layernorm"), None)
-        lp = params[ln.param] if ln is not None else None
+        ln = _op_params(params, next(
+            (o.param for o in posts if o.kind == "layernorm"), ""))
+        pre = _op_params(params, gemm.prenorm)
+        emb = _op_params(params, next(
+            (o.param for o in posts if o.kind == "embed"), ""))
         stages.append(PackedStage(
             w8=w8, w_amax=amax,
             bias=p[gemm.b_key].astype(jnp.float32),
-            ln_g=None if lp is None else lp["g"].astype(jnp.float32),
-            ln_b=None if lp is None else lp["b"].astype(jnp.float32)))
+            ln_g=ln.get("g"), ln_b=ln.get("b"),
+            pre_g=pre.get("g"), pre_b=pre.get("b"),
+            emb_cls=emb.get("cls"), emb_pos=emb.get("pos")))
     return PackedProgram(stages=tuple(stages),
                          program=dataclasses.replace(program, plans=()))

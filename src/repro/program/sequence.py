@@ -8,8 +8,9 @@ the bit-exactness contract of DESIGN.md §5/§9 needs the two sides to
 trace identical expressions, and layout ops are the easiest place for a
 silent transpose-order divergence to hide.
 
-Everything here is reshape/transpose (no arithmetic) plus one python
-float constant, so sharing is free of FMA-contraction concerns.
+Everything here is reshape/transpose plus one python float constant
+and the position table's single add, so sharing is free of
+FMA-contraction concerns.
 """
 
 from __future__ import annotations
@@ -61,3 +62,13 @@ def merge_heads(ctx: jnp.ndarray, heads: int) -> jnp.ndarray:
     B = bh // heads
     return (ctx.reshape(B, heads, T, hd).transpose(0, 2, 1, 3)
             .reshape(B, T, heads * hd))
+
+
+def embed_tokens(x: jnp.ndarray, cls: jnp.ndarray, pos: jnp.ndarray
+                 ) -> jnp.ndarray:
+    """(B, T, D) patch tokens -> (B, T+1, D): the class token ``cls``
+    (D,) prepended as token 0, then the position table ``pos``
+    (T+1, D) added (ViT's ``[x_cls; x E] + E_pos``)."""
+    b, _, d = x.shape
+    head = jnp.broadcast_to(cls.astype(x.dtype).reshape(1, 1, d), (b, 1, d))
+    return jnp.concatenate([head, x], axis=1) + pos

@@ -165,3 +165,15 @@ def test_sliced_and_save_load_phases(vit_model):
     model, _ = vit_model
     assert all(ok for _, ok in smoke.sliced_phase("vit_tiny", 1, batch=2))
     assert all(ok for _, ok in smoke.save_load_phase(model, batch=2))
+
+
+def test_nets_option_selects_and_checks_names(capsys):
+    """``--nets`` names networks of ``NETS``; an unknown one is refused
+    before anything runs.  ``deit_ti`` is among them, at its depth."""
+    assert ("deit_ti", 12) in smoke.NETS
+    with pytest.raises(SystemExit):
+        smoke.main(["--nets", "resnet18,nope"])
+    assert "nope" in capsys.readouterr().err
+    g = smoke.graph_of("deit_ti", 1)
+    assert sum(l.kind == "attention" for l in g.layers) == 1
+    assert g.input_shape(1) == (1, 224, 224, 3)

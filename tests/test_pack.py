@@ -476,7 +476,7 @@ def test_pre_mount_layout_file_loads_bit_identical(tmp_path, version):
     with np.load(path, allow_pickle=False) as z:
         meta = json.loads(str(z["__meta__"][()]))
         arrays = {k: z[k] for k in z.files if k != "__meta__"}
-    assert meta["version"] == 5 and meta["layouts"] == list(layouts)
+    assert meta["version"] == 6 and meta["layouts"] == list(layouts)
     meta["version"] = version
     del meta["layouts"]
     if version < 4:

@@ -70,7 +70,7 @@ def test_vit_tiny_bit_exact_and_roundtrip(tmp_path):
 
     path = model.save(str(tmp_path / "vit.npz"))
     meta_version = VERSION
-    assert meta_version == 5
+    assert meta_version == 6
     loaded = api.load(path)
     assert loaded.program.ops == model.program.ops
     assert loaded.program.has_dynamic_stages
@@ -183,6 +183,8 @@ def test_softmax_epilogue_stable_on_large_logits():
     dict(act="gelu", norm="layer"),
     dict(norm="layer", pool="seqmean", window=16),
     dict(pool="seqmean", window=8),
+    dict(norm="layer", eps=1e-6),
+    dict(act="gelu", norm="layer", eps=1e-6),
 ])
 @pytest.mark.parametrize("with_res", [False, True])
 def test_fb_epilogue_sequence_modes_match_oracle(kw, with_res):

@@ -4,9 +4,10 @@ The TPU compiler is installed even where no chip is attached, and it
 refuses what interpret mode accepts: K blocks off the 128-lane tiling,
 output blocks of fewer than 8 rows, 1-D operand blocks, tiles that
 overflow VMEM.  These tests compile the main path's kernels at real
-stage shapes, and two whole programs, for one chip of a described
-``v5e:2x2`` topology, and check that the Pallas kernels lowered to
-Mosaic (``tpu_custom_call``) rather than to the interpreter.
+stage shapes, and three whole programs (DeiT-Ti at its published size
+among them), for one chip of a described ``v5e:2x2`` topology, and
+check that the Pallas kernels lowered to Mosaic (``tpu_custom_call``)
+rather than to the interpreter.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load the TPU library, so every pytest
@@ -25,7 +26,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import api
-from repro.api.zoo import vit_tiny_graph
+from repro.api.zoo import deit_graph, vit_tiny_graph
 from repro.kernels.crossbar_gemm import (dense_layout, mount_layout,
                                         mounted_gemm)
 from repro.kernels.fb_epilogue import fb_epilogue
@@ -118,19 +119,26 @@ def test_fb_epilogue_compiles(one_chip, case):
         _shape(one_chip, (n,), f32), res, vec, vec)
 
 
+# net -> (graph, batch) of its whole-program compile
+WHOLE = {"resnet18": (lambda: "resnet18", 8),
+         "vit_tiny": (lambda: vit_tiny_graph(depth=2), 8),
+         "deit_ti": (deit_graph, 64)}      # the benchmark cell's batch
+
+
 @pytest.fixture(scope="module")
 def whole_program(one_chip):
-    """net -> (model, HLO text of its whole program at batch 8), each
-    compiled once for the file."""
+    """net -> (model, HLO text of its whole program at its ``WHOLE``
+    batch), each compiled once for the file."""
     done = {}
 
     def get(net):
         if net not in done:
-            graph = vit_tiny_graph(depth=2) if net == "vit_tiny" else net
-            model = api.compile(graph, smoke.CLIP_FREE)
+            graph, batch = WHOLE[net]
+            model = api.compile(graph(), smoke.CLIP_FREE)
             packed = jax.tree.map(
                 lambda a: _shape(one_chip, a.shape, a.dtype), model.packed)
-            x = _shape(one_chip, model.program.input_shape(8), jnp.float32)
+            x = _shape(one_chip, model.program.input_shape(batch),
+                       jnp.float32)
             done[net] = model, _compile(
                 lambda pk, v: execute_packed(pk, v, interpret=False),
                 packed, x)
@@ -138,7 +146,7 @@ def whole_program(one_chip):
     return get
 
 
-@pytest.mark.parametrize("net", ["resnet18", "vit_tiny"])
+@pytest.mark.parametrize("net", ["resnet18", "vit_tiny", "deit_ti"])
 def test_whole_program_compiles(whole_program, net):
     model, text = whole_program(net)
     # one crossbar GEMM and one fused epilogue per static stage at least
@@ -156,3 +164,27 @@ def test_kernel_names_survive_in_the_compiled_program(whole_program):
         calls = re.findall(rf"^\s*(?:ROOT )?%{kernel}(?:\.\d+)? = .*"
                            r"custom-call\(", text, re.M)
         assert len(calls) == stages, kernel
+
+
+def test_deit_program_lowers_its_new_modes(whole_program):
+    """DeiT-Ti at 224x224, batch 64: 74 stages, 24 of them dynamic, one
+    ``mounted_gemm`` and one ``fb_epilogue`` custom call each (Mosaic
+    kernels); the exact GELU of the 12 MLP stages an XLA ``erf`` in
+    their ``epilogue`` phase (Mosaic has no ``erf``), and the ``prenorm``
+    and ``embed`` scopes named in the compiled text."""
+    model, text = whole_program("deit_ti")
+    stages = model.program.stages()
+    assert len(stages) == 74
+    assert sum(g.kind == "dyn_gemm" for g, _ in stages) == 24
+    for kernel in ("mounted_gemm", "fb_epilogue"):
+        calls = re.findall(rf"^\s*(?:ROOT )?%{kernel}(?:\.\d+)? = .*"
+                           r"custom-call\(", text, re.M)
+        assert len(calls) == len(stages), kernel
+    erf_stages = [posts[-1].dst for _, posts in stages
+                  if any(p.kind == "gelu" and p.approx == "erf"
+                         for p in posts)]
+    assert len(erf_stages) == 12
+    for name in erf_stages:
+        assert re.search(rf' erf\(.*op_name="[^"]*/s\d+\.{name}/epilogue/',
+                         text), name
+    assert "/prenorm/" in text and "/embed/" in text
