@@ -88,7 +88,8 @@ STEADY_RUNS = 5
 FB_TAIL_ULP = 16
 
 # real stage shapes (name, M, K, N, rows per mount); M is cut to 256 —
-# the mount layout under test is K, N and rows
+# the mount layout under test is K, N and rows — except in the edge-block
+# cases, whose M is the point
 GEMM_CASES = (
     ("alexnet_conv1", 256, 27, 64, 27),
     ("alexnet_conv2", 256, 576, 192, 485),
@@ -96,6 +97,10 @@ GEMM_CASES = (
     ("vit_fc1", 256, 192, 768, 192),
     ("vit_fc2", 256, 768, 192, 451),
     ("alexnet_fc8", 8, 1024, 10, 429),
+    # DeiT-Ti at batch 64 (12,608 token rows): M and N divide no block,
+    # so both end in edge blocks
+    ("deit_qkv_b64", 64 * 197, 192, 576, 485),
+    ("deit_fc2_b64", 64 * 197, 768, 192, 485),
 )
 # fb_epilogue modes (name, M, N, kwargs, residual?, layer norm?)
 EPILOGUE_CASES = (
@@ -122,6 +127,10 @@ EPILOGUE_CASES = (
      False),
     ("scores_t197", 197, 197, dict(softmax=True, post_scale=0.125), False,
      False),
+    # DeiT-Ti at batch 64: 12,608 rows end in an edge row block
+    ("deit_fc2_res_b64", 64 * 197, 192, dict(), True, False),
+    ("deit_layernorm_b64", 64 * 197, 192, dict(norm="layer", eps=1e-6),
+     True, True),
 )
 
 

@@ -61,13 +61,22 @@ else K zero-padded at its end to whole blocks of a multiple of 128 rows
 activation in that order (only its end is padded, never re-mounted) and
 runs the exact kernel, one K block per grid step.
 
-Block activation is pad-to-block: operands whose M/N are not multiples
-of the block sizes (clamped to M, N rounded up to the (8, 128) tiling)
-are zero-padded up to the next multiple,
-full-size tiles run, and the result is sliced back to (M, N).  Padded
-output rows/columns are independent of the kept region, so the padding
-is slice-exact on both compute paths — callers with odd spatial dims
-never see a divisibility assert.
+Edge blocks: the grid is ``cdiv(M, block_m) x cdiv(N, block_n)`` over
+the operands as they are, and the output is exactly (M, N).  Where M or
+N does not divide its block, the last block runs past the array: on the
+TPU its out-of-range part reads unspecified values and is dropped when
+written (interpret mode pads it likewise).  Every kept int32 element
+depends only on its own row of ``x``, its own column of ``w`` and the
+whole K axis, so it is computed exactly as in a divisible grid on both
+compute paths; K itself is never ragged (both layouts pad it with
+zeros).  The exact path takes a block of the whole dimension where it
+fits one block, which has no edge at all; the sliced path's plane
+reshapes need tiles on the (8, 128) tiling, so its block is the
+dimension rounded up to it.  One pad is kept, the lane pad: an N under
+one 128-lane tile (a 64-channel conv, a 10-class head, attention's
+P·V) has its weight columns padded to 128 and the result sliced back.
+Such an output fills whole lane tiles in HBM either way, so the slice
+is a bitcast of the same tiled bytes, and the kernel writes whole tiles.
 
 ``block_m``/``block_n`` default per compute path (``tiling.py``).
 """
@@ -286,13 +295,13 @@ def mounted_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
     ``dense_blocks``, not mounts.
 
     Block sizes default per path (``tiling.py``).  M and N need not
-    divide the (clamped) block sizes: operands are zero-padded up to the
-    block multiple, full tiles run, and the output is sliced back to
-    (M, N) — slice-exact (see module docstring).
+    divide them: edge blocks run past the array, and the output is
+    exactly (M, N); only an N under 128 lanes is padded to them and
+    sliced back (see module docstring).
 
     Its ops sit in two named scopes: ``mount`` (the activation's mount
-    layout, the block pads and the slice back) and ``gemm`` (the
-    kernel).
+    layout or its dense K pad, the lane pad and its slice back) and
+    ``gemm`` (the kernel).
     """
     assert x.dtype == jnp.int8 and w.dtype == jnp.int8
     M, K = x.shape
@@ -324,19 +333,18 @@ def mounted_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
     if K + pk != Kw:
         raise ValueError(f"weights have {Kw} rows; the activation laid out "
                          f"{layout} has {K + pk} (see {layout}_layout)")
-    bm, bn = default_blocks("exact" if exact else "sliced")
-    # clamp to the operand rounded up to the (8, 128) tiling: the sliced
-    # path's plane reshapes need aligned tiles even for tiny M or N
-    block_m = min(block_m or bm, -(-M // 8) * 8)
-    block_n = min(block_n or bn, -(-N // LANE) * LANE)
-    # pad-to-block activation: zero rows/cols are slice-exact (docstring)
-    pm, pn = -M % block_m, -N % block_n
+    Np = max(N, LANE)            # the lane pad (module docstring)
     with jax.named_scope("mount"):
-        if pm or pk:
-            x = jnp.pad(x, ((0, pm), (0, pk)))
-        if pn:
-            w = jnp.pad(w, ((0, 0), (0, pn)))
-    Mp, Np = M + pm, N + pn
+        if pk:
+            x = jnp.pad(x, ((0, 0), (0, pk)))
+        if Np > N:
+            w = jnp.pad(w, ((0, 0), (0, Np - N)))
+    bm, bn = default_blocks("exact" if exact else "sliced")
+    # a dimension that fits one block is that block; the sliced path's
+    # plane reshapes need it rounded up to the (8, 128) tiling
+    tm, tn = (1, 1) if exact else (8, LANE)
+    block_m = min(block_m or bm, -(-M // tm) * tm)
+    block_n = min(block_n or bn, -(-Np // tn) * tn)
     n_k = Kw // block_k
     if exact:
         # f32 block dots are exact iff |partial| <= block_k * 128^2 <= 2^24
@@ -348,14 +356,14 @@ def mounted_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
     with jax.named_scope("gemm"):
         y = pl.pallas_call(
             kernel,
-            grid=(Mp // block_m, Np // block_n, n_k),
+            grid=(pl.cdiv(M, block_m), pl.cdiv(Np, block_n), n_k),
             in_specs=[
                 pl.BlockSpec((block_m, block_k), lambda i, j, k: (i, k)),
                 pl.BlockSpec((block_k, block_n), lambda i, j, k: (k, j)),
             ],
             out_specs=pl.BlockSpec((block_m, block_n),
                                    lambda i, j, k: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.int32),
+            out_shape=jax.ShapeDtypeStruct((M, Np), jnp.int32),
             scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.int32)],
             interpret=interpret,
             # names the custom call (``%mounted_gemm.N``) in the
@@ -363,4 +371,4 @@ def mounted_gemm(x: jnp.ndarray, w: jnp.ndarray, *, adc_bits: int = 9,
             name="mounted_gemm",
         )(x, w)
     with jax.named_scope("mount"):
-        return y[:M, :N] if pm or pn else y
+        return y[:, :N] if Np > N else y

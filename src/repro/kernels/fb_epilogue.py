@@ -55,20 +55,18 @@ The per-column operands (bias, layer-norm gamma/beta) enter the kernel
 as ``(1, N)`` rows with ``(1, block_n)`` blocks: a 1-D block has no
 layout the TPU tiling and XLA agree on.
 
-Block activation is pad-to-block over rows: when M does not divide
-the (clamped) row block, operands are zero-padded up to the block
-multiple, full-size tiles run, and the result is sliced back — every
-row is processed independently by the FB chain, so the padding is
-slice-exact and callers never tune divisor blocks.  Columns are never
-padded: an N that ``block_n`` does not divide takes one full-width
-block (``block_n = N``, which the TPU tiling always accepts).  A
-padded and sliced-back column axis would reach the next stage as a
-slice, and XLA's reduction over a sliced row (a pre-norm's mean)
-rounds apart from its reduction over a plain one.  The structural
-constraints remain: pooling fixes M to ``B * img_hw^2`` (or ``B * T``
-for seqmean — rows are padded by whole images or sequences there), and
-softmax / layer norm need the full feature axis in-tile.  On TPU
-proper, multiples of (8, 128) pick the fast path.
+Edge blocks over rows: the grid takes ``cdiv(M, block_m)`` row blocks
+of the operands as they are, and the output is exactly (M, N).  Where M
+does not divide the row block, the last block runs past the array: its
+out-of-range rows read unspecified values and are dropped when written.
+The FB chain treats every row on its own, so each kept row is computed
+exactly as in a divisible grid; an M that fits one block is one block
+of the whole dimension.  Columns have no edge: an N that ``block_n``
+does not divide takes one full-width block (``block_n = N``, which the
+TPU tiling always accepts), as softmax and layer norm always do (they
+need the full feature axis in-tile).  The pooled and seq-mean modes
+fix M to ``B * img_hw^2`` (``B * T``) and pad the batch by whole images
+(sequences) to their images per step, then slice the padding off.
 """
 
 from __future__ import annotations
@@ -235,47 +233,40 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
 
     dm, dn = default_blocks("epilogue")
     block_m, block_n = block_m or dm, block_n or dn
-    # pad-to-block activation (module docstring): pad rows (by whole
-    # images or sequences when pooling), run full tiles, slice back;
     # one full-width column block where block_n does not divide N
     if softmax or has_norm or N % min(block_n, N):
         block_n = N              # the row reduction needs every column
     block_n = min(block_n, N)
-    if pool == "seqmean":
+    pm = 0
+    if pool == "none":           # edge row blocks (module docstring)
+        block_m = min(block_m, M)
+        grid = (pl.cdiv(M, block_m), N // block_n)
+        row_spec = out_spec = pl.BlockSpec((block_m, block_n),
+                                           lambda i, j: (i, j))
+        out_shape = jax.ShapeDtypeStruct((M, N), jnp.float32)
+    else:                        # whole images / sequences per step
         assert not softmax, "pool and softmax FBs never chain directly"
-        assert window >= 1 and M % window == 0, (M, window)
-        group_rows, out_rows = window, 1
-    elif pool != "none":
-        assert not softmax, "pool and softmax FBs never chain directly"
-        assert window > 1 and img_hw % window == 0, (img_hw, window)
-        group_rows, out_rows = img_hw * img_hw, (img_hw // window) ** 2
-        assert M % group_rows == 0, (M, img_hw)
-    if pool != "none":           # pad by whole images / sequences
+        if pool == "seqmean":
+            assert window >= 1 and M % window == 0, (M, window)
+            group_rows, out_rows = window, 1
+        else:
+            assert window > 1 and img_hw % window == 0, (img_hw, window)
+            group_rows, out_rows = img_hw * img_hw, (img_hw // window) ** 2
+            assert M % group_rows == 0, (M, img_hw)
         n_groups = M // group_rows
         k = _groups_per_step(n_groups, out_rows)
         pm = -n_groups % k * group_rows
-    else:
-        pm = -M % min(block_m, M)
-    if pm:
-        y = jnp.pad(y, ((0, pm), (0, 0)))
-        if has_residual:
-            res = jnp.pad(res, ((0, pm), (0, 0)))
-    Mp = M + pm
-
-    if pool != "none":
-        n_steps = Mp // (k * group_rows)
+        if pm:                   # pad by whole zero images / sequences
+            y = jnp.pad(y, ((0, pm), (0, 0)))
+            if has_residual:
+                res = jnp.pad(res, ((0, pm), (0, 0)))
+        n_steps = (M + pm) // (k * group_rows)
         grid = (n_steps, N // block_n)
         row_spec = pl.BlockSpec((k * group_rows, block_n),
                                 lambda i, j: (i, j))
         out_spec = pl.BlockSpec((k * out_rows, block_n), lambda i, j: (i, j))
         out_shape = jax.ShapeDtypeStruct((n_steps * k * out_rows, N),
                                          jnp.float32)
-    else:
-        block_m = min(block_m, Mp)
-        grid = (Mp // block_m, N // block_n)
-        row_spec = pl.BlockSpec((block_m, block_n), lambda i, j: (i, j))
-        out_spec = row_spec
-        out_shape = jax.ShapeDtypeStruct((Mp, N), jnp.float32)
 
     one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     col_spec = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
@@ -301,9 +292,7 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         # HLO, which trace readers match on
         name="fb_epilogue",
     )(y, scale, bias, res, g, bt)
-    if pm:                       # drop the padded rows (images, sequences)
-        out = out[:M if pool == "none" else n_groups * out_rows]
-    return out
+    return out[:n_groups * out_rows] if pm else out
 
 
 def _groups_per_step(n_groups: int, out_rows: int) -> int:

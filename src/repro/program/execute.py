@@ -91,9 +91,9 @@ instruction serves:
 * ``quantize`` — the activation's amax reduction and int8 convert,
   and inside it ``prenorm``, a pre-norm stage's layer norm of its
   input;
-* ``mount`` — ``mounted_gemm``'s mount layout, its K/M/N block pads and
-  slice back, and ``plane_pack`` of a dynamic stage's right-hand
-  operand;
+* ``mount`` — ``mounted_gemm``'s mount layout or dense K pad, its
+  lane pad of an N under 128 and the slice back, and ``plane_pack`` of
+  a dynamic stage's right-hand operand;
 * ``gemm`` — the ``mounted_gemm`` kernel;
 * ``epilogue`` — the scale product, the ``fb_epilogue`` kernel and the
   output reshape, and inside it ``embed``, a patchify stage's class

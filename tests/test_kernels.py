@@ -38,6 +38,71 @@ def test_crossbar_gemm_exact_when_adc_sufficient():
         np.asarray(x.astype(jnp.int32) @ w.astype(jnp.int32)))
 
 
+# M and N that divide no block (DeiT-Ti's 197 tokens at batch 1 and 2, an
+# fc head): the kernel runs edge blocks over the operands as they are
+EDGE_GEMMS = [(197, 64, 197), (394, 192, 576), (394, 768, 192),
+              (3, 512, 10)]
+
+
+@pytest.mark.parametrize("path", ["exact", "dense", "sliced"])
+@pytest.mark.parametrize("m,k,n", EDGE_GEMMS)
+def test_mounted_gemm_edge_blocks_match_ref(m, k, n, path):
+    """Every row and column, those of the edge blocks too, equals the
+    reference bit for bit on both compute paths and both layouts; the
+    exact path also with 128-square blocks, so that M and N both end in
+    an edge block."""
+    from repro.kernels.crossbar_gemm import (dense_layout, mount_layout,
+                                            mounted_gemm)
+
+    k1, k2 = jax.random.split(jax.random.PRNGKey(m * n + k))
+    x = jax.random.randint(k1, (m, k), -128, 128).astype(jnp.int8)
+    w = jax.random.randint(k2, (k, n), -128, 128).astype(jnp.int8)
+    rows = 150                   # several mounts wherever K > 150
+    if path == "sliced":         # a 7-bit ADC clips 150-row mounts
+        runs = [mounted_gemm(x, mount_layout(w, rows, 0), adc_bits=7,
+                             rows=rows, exact=False, interpret=True)]
+        want = ref.crossbar_gemm_ref(x, w, adc_bits=7, rows=rows)
+    else:
+        if path == "dense":
+            wl, kw = dense_layout(w, 0), dict(layout="dense")
+        else:
+            wl, kw = mount_layout(w, rows, 0), dict(exact=True)
+        runs = [mounted_gemm(x, wl, rows=rows, block_m=bm, block_n=bm,
+                             interpret=True, **kw) for bm in (None, 128)]
+        want = ref.crossbar_gemm_exact_ref(x, w)
+    for y in runs:
+        assert y.shape == (m, n)
+        np.testing.assert_array_equal(np.asarray(y), np.asarray(want))
+
+
+@pytest.mark.parametrize("n,kw,with_res", [
+    (576, dict(), True),
+    (768, dict(act="gelu"), True),
+    (192, dict(norm="layer"), True),
+    (197, dict(softmax=True, post_scale=0.125), False),
+], ids=["dequant_res", "gelu_res", "layernorm_res", "softmax"])
+def test_fb_epilogue_edge_rows_match_ref(n, kw, with_res):
+    """M = 394 rows in 256-row blocks: the edge block's 138 kept rows,
+    like the full block's, are bit-identical to the jnp expression."""
+    from repro.kernels.fb_epilogue import fb_epilogue
+
+    m = 394
+    ks = jax.random.split(jax.random.PRNGKey(n), 5)
+    y = jax.random.randint(ks[0], (m, n), -20000, 20000, jnp.int32)
+    scale = jnp.full((1, 1), 3e-4, jnp.float32)
+    bias = jax.random.normal(ks[1], (n,), jnp.float32)
+    res = jax.random.normal(ks[2], (m, n), jnp.float32) if with_res else None
+    ln = {}
+    if kw.get("norm") == "layer":
+        ln = dict(gamma=1 + 0.1 * jax.random.normal(ks[3], (n,)),
+                  beta=0.1 * jax.random.normal(ks[4], (n,)))
+    out = fb_epilogue(y, scale, bias, res, interpret=True, **kw, **ln)
+    want = jax.jit(lambda *a: ref.fb_epilogue_ref(*a, **kw, **ln))(
+        y, scale, bias, res)
+    assert out.shape == (m, n)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
 # ---------------------------------------------------------------------------
 # flash_attention — Eq. 1 semantics across shapes/dtypes/masks
 # ---------------------------------------------------------------------------

@@ -5,8 +5,8 @@ pre-quantized int8 mount planes (bit-identical to traced quantization,
 conv layout applied, K padded to full mounts); save -> load -> run is
 bit-exact WITHOUT re-deriving weight planes (no ``quantize_symmetric``
 of weights on the load-then-run path — version-1 files repack once at
-load); pad-to-block activation is slice-exact at the kernel level and
-through a whole non-divisor network; and the executor's buffer-lifetime
+load); edge-block activation (M and N that divide no block) is exact at
+the kernel level and through a whole non-divisor network; and the executor's buffer-lifetime
 bookkeeping never changes results.
 """
 
@@ -266,12 +266,12 @@ def test_buffer_lifetime_dropping_never_changes_results():
 
 
 # ---------------------------------------------------------------------------
-# pad-to-block activation: slice-exact at the kernel level
+# edge-block activation: exact at the kernel level
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("adc_bits", [9, 5])   # exact path / sliced path
 def test_crossbar_gemm_pad_to_block_slice_exact(adc_bits):
-    """Non-divisor M/N/K: zero-padded full tiles == the unpadded oracle."""
+    """Non-divisor M/N/K: edge blocks == the oracle, shape (M, N)."""
     k = jax.random.PRNGKey(0)
     M, K, N, rows = 37, 150, 19, 64
     x = jax.random.randint(k, (M, K), -128, 128, jnp.int32).astype(jnp.int8)
@@ -285,7 +285,8 @@ def test_crossbar_gemm_pad_to_block_slice_exact(adc_bits):
 
 
 def test_fb_epilogue_pad_to_block_slice_exact():
-    """Odd M (plain chain) and odd N (pool chain) pad + slice exactly."""
+    """Odd M (plain chain: an edge row block) and odd N (pool chain:
+    one full-width column block) are exact."""
     key = jax.random.PRNGKey(0)
     scale = jnp.array([[0.017]], jnp.float32)
     # odd M, odd N, residual + relu
@@ -341,7 +342,7 @@ def test_fb_epilogue_pads_pooled_batches(n, kw):
 def test_non_divisor_network_end_to_end_bit_exact():
     """A net whose M/N divide nothing still matches the functional
     forward bitwise under tiny block sizes — executor-level proof that
-    pad-to-block activation is slice-exact."""
+    edge-block activation is exact."""
     nb = NetworkBuilder("odd13", input_hw=6, input_ch=3)
     nb.conv(13, name="c1")                  # N=13, M=36 vs 8x8 blocks
     nb.relu(name="r1")

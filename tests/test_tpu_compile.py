@@ -122,7 +122,9 @@ def test_fb_epilogue_compiles(one_chip, case):
 # net -> (graph, batch) of its whole-program compile
 WHOLE = {"resnet18": (lambda: "resnet18", 8),
          "vit_tiny": (lambda: vit_tiny_graph(depth=2), 8),
-         "deit_ti": (deit_graph, 64)}      # the benchmark cell's batch
+         "deit_ti": (deit_graph, 64),      # the benchmark cell's batch
+         # DeiT-shaped and small: 197 tokens, width 192, 3 heads, 2 blocks
+         "deit_ti_depth2": (lambda: deit_graph(depth=2), 2)}
 
 
 @pytest.fixture(scope="module")
@@ -188,3 +190,21 @@ def test_deit_program_lowers_its_new_modes(whole_program):
         assert re.search(rf' erf\(.*op_name="[^"]*/s\d+\.{name}/epilogue/',
                          text), name
     assert "/prenorm/" in text and "/embed/" in text
+
+
+def test_deit_linear_stages_run_edge_blocks_unpadded(whole_program):
+    """DeiT's M = 394 token rows and N = 576, 768 and 192 divide no
+    block: both kernels take a linear stage's operands as they are (edge
+    blocks), so no pad or slice sits in either kernel's wrapper there,
+    for the weights neither.  What is left in a wrapper is attention's
+    P·V lane pad (N = 64, under one 128-lane tile), one per block."""
+    model, text = whole_program("deit_ti_depth2")
+    assert len(model.program.stages()) == 14
+    ops = re.findall(r' = (\S+) (pad|slice)\(.*op_name="[^"]*/'
+                     r'(s\d+\.[\w.]+)/(\w*\(?)jit\((mounted_gemm|fb_epilogue)'
+                     r'\)\)?/', text)
+    assert sorted((stage, vmap + kernel, op) for _, op, stage, vmap, kernel
+                  in ops) == [("s03.b0_attn.ctx", "vmap(mounted_gemm", "pad"),
+                              ("s09.b1_attn.ctx", "vmap(mounted_gemm", "pad")]
+    assert all(shape.startswith("s8[") and ",128]" in shape
+               for shape, *_ in ops)
