@@ -81,25 +81,26 @@ def fig8_utilization():
 def accuracy_drop():
     """§IV-B2: marginal accuracy drop from 1-bit cells + read noise.
 
-    Runs the functional CNNs through the bit-sliced crossbar (int8, with
-    read noise) vs fp32 and reports logit agreement on random probes.
+    Runs the zoo CNNs' functional oracle through the bit-sliced crossbar
+    (int8, with read noise) vs fp32 and reports logit agreement on
+    random probes.
     """
     import jax
     import jax.numpy as jnp
-    from repro.core.crossbar import CrossbarConfig
-    from repro.models.cnn import CNN_MODELS, make_crossbar_matmul
+    from repro.core.crossbar import CrossbarConfig, make_crossbar_matmul
 
     rows = []
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 32, 32, 3))
     for net in NETS:
-        m = CNN_MODELS[net]
-        params = m.init(jax.random.PRNGKey(1))
+        graph = GRAPHS[net]()
+        params = graph.init_params(jax.random.PRNGKey(1))
         t0 = time.perf_counter()
-        y_fp = m.forward(params, x)
-        y_clean = m.forward(params, x, mm=make_crossbar_matmul())
+        y_fp = graph.forward(params, x, logits=True)
+        y_clean = graph.forward(params, x, mm=make_crossbar_matmul(),
+                                logits=True)
         mm = make_crossbar_matmul(CrossbarConfig(noise_sigma_thermal=0.3),
                                   noise_key=jax.random.PRNGKey(9))
-        y_noisy = m.forward(params, x, mm=mm)
+        y_noisy = graph.forward(params, x, mm=mm, logits=True)
         us = (time.perf_counter() - t0) * 1e6
         a_clean = float((jnp.argmax(y_fp, 1) == jnp.argmax(y_clean, 1)).mean())
         a_noisy = float((jnp.argmax(y_fp, 1) == jnp.argmax(y_noisy, 1)).mean())

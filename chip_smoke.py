@@ -63,13 +63,13 @@ from repro import api  # noqa: E402
 from repro.api import HurryConfig  # noqa: E402
 from repro.api.zoo import GRAPHS  # noqa: E402
 from repro.compile_cache import use_compile_cache  # noqa: E402
+from repro.core.crossbar import fp_matmul, make_crossbar_matmul  # noqa: E402
 from repro.kernels import ref  # noqa: E402
 from repro.kernels.crossbar_gemm import (dense_blocks,  # noqa: E402
                                         dense_layout, mount_layout,
                                         mounted_gemm)
 from repro.kernels.fb_epilogue import fb_epilogue  # noqa: E402
 from repro.kernels.ops import interpret_default  # noqa: E402
-from repro.models.cnn import fp_matmul, make_crossbar_matmul  # noqa: E402
 from repro.program.execute import (Kernels, execute_packed,  # noqa: E402
                                    stage_outputs)
 

@@ -12,8 +12,8 @@ import argparse
 import jax
 import jax.numpy as jnp
 
-from repro.core.crossbar import CrossbarConfig
-from repro.models.cnn import CNN_MODELS, make_crossbar_matmul
+from repro.api import GRAPHS
+from repro.core.crossbar import CrossbarConfig, make_crossbar_matmul
 
 
 def main():
@@ -25,17 +25,17 @@ def main():
                     help="thermal read-noise sigma (analog counts)")
     args = ap.parse_args()
 
-    m = CNN_MODELS[args.net]
-    params = m.init(jax.random.PRNGKey(1))
-    x = jax.random.normal(jax.random.PRNGKey(0), (args.batch, 32, 32, 3))
+    graph = GRAPHS[args.net]()
+    params = graph.init_params(jax.random.PRNGKey(1))
+    x = jax.random.normal(jax.random.PRNGKey(0), graph.input_shape(args.batch))
 
-    y_fp = m.forward(params, x)
+    y_fp = graph.forward(params, x, logits=True)
     for label, cfg in [
             ("int8 crossbar (clean)", CrossbarConfig()),
             (f"int8 crossbar (noise={args.noise})",
              CrossbarConfig(noise_sigma_thermal=args.noise))]:
         mm = make_crossbar_matmul(cfg, noise_key=jax.random.PRNGKey(9))
-        y_xb = m.forward(params, x, mm=mm)
+        y_xb = graph.forward(params, x, mm=mm, logits=True)
         agree = float((jnp.argmax(y_fp, 1) == jnp.argmax(y_xb, 1)).mean())
         rel = float(jnp.linalg.norm(y_xb - y_fp) / jnp.linalg.norm(y_fp))
         print(f"{args.net:9s} {label:28s} argmax-agree {agree:6.1%}  "

@@ -28,7 +28,7 @@ import numpy as np
 from repro import api
 from repro.api import HurryConfig, NetworkBuilder
 from repro.compile_cache import use_compile_cache
-from repro.models.cnn import make_crossbar_matmul
+from repro.core.crossbar import make_crossbar_matmul
 
 
 def custom_graph():

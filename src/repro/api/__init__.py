@@ -10,9 +10,8 @@ validation), configure the chip/crossbar/executor with one
     model.save(path); api.load(path)     # serve without recompiling
 
 The three paper CNNs and the ``vit_tiny`` and ``deit_ti`` transformers
-live in
-``repro.api.zoo`` as builder programs (``core.workload.WORKLOADS`` is a
-deprecated compat shim over the CNNs).  Sequence graphs (DESIGN.md §9)
+live in ``repro.api.zoo`` as builder programs; ``api.compile(name)``
+looks a name up there.  Sequence graphs (DESIGN.md §9)
 compile to the same program stack: attention lowers into
 dynamic-operand GEMM stages that mount runtime activations on the
 crossbar per batch.

@@ -13,10 +13,10 @@ Every downstream structure is *derived* here and nowhere else:
   ``baseline()``  -> ``core.baselines.BaselineConfig`` (ISAAC/MISCA
                      comparison chips sharing this geometry)
 
-``program/compile.py`` and ``program/serve.py`` accept a ``HurryConfig``
-directly; ``core/simulator.py`` and ``core/baselines.py`` accept one via
-duck typing (anything with a ``.chip()`` / ``.baseline()`` derivation),
-so ``core`` never imports ``api``.  Legacy callers that pass only a
+``program/compile.py`` accepts a ``HurryConfig`` directly;
+``core/simulator.py`` and ``core/baselines.py`` accept one via duck
+typing (anything with a ``.chip()`` / ``.baseline()`` derivation), so
+``core`` never imports ``api``.  Legacy callers that pass only a
 ``ChipConfig`` are routed through ``HurryConfig.from_chip`` so the
 ChipConfig -> CrossbarConfig derivation also lives here, not in each
 consumer.

@@ -31,7 +31,7 @@ that input still read it un-normed.
 The resulting ``NetworkGraph`` is the one source of truth for layer
 shapes: the scheduler consumes its ``LayerSpec`` list, ``init_params``
 derives the parameter pytree from it, and ``forward`` is a generic
-functional interpreter (same primitives as ``models/cnn.py``, GEMMs
+functional interpreter (the im2col primitives of ``core/conv.py``, GEMMs
 routed through any ``mm`` — fp32 or the crossbar functional model) used
 as the numeric reference for compiled programs.  Attention routes all
 four of its GEMMs (fused qkv projection, per-head Q·Kᵀ, per-head P·V,
@@ -47,11 +47,12 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.core.conv import conv2d, maxpool
+from repro.core.crossbar import fp_matmul
 from repro.core.workload import (GEMM_KINDS, LayerSpec, POST_RANK,
                                  input_spec, layer_groups)
 from repro.kernels.fb_epilogue import (gelu, gelu_erf, layer_norm_ordered,
                                        layer_norm_rows, softmax_ordered)
-from repro.models.cnn import conv2d, fp_matmul, maxpool
 from repro.program.sequence import (attn_scale, embed_tokens, merge_heads,
                                     split_qkv_heads, tokens)
 
@@ -96,8 +97,8 @@ class NetworkGraph:
     def init_params(self, key: jax.Array) -> dict:
         """He-init parameter pytree whose shapes come from the graph.
 
-        One source of truth: ``models/cnn.py`` and ``api.compile`` both
-        init through here, so layer shapes exist in exactly one place.
+        One source of truth: ``api.compile`` inits through here, so
+        layer shapes exist in exactly one place.
         """
         params: dict = {}
         for i, l in enumerate(self.layers):
@@ -144,8 +145,8 @@ class NetworkGraph:
                 ) -> jnp.ndarray:
         """Generic functional forward over the graph (the numeric oracle).
 
-        Interprets the layer list with the same primitives the
-        handwritten CNN forwards use, routing every GEMM through ``mm``
+        Interprets the layer list with the im2col primitives of
+        ``core/conv.py``, routing every GEMM through ``mm``
         (``make_crossbar_matmul(cfg)`` for the crossbar model) —
         including the two *dynamic-operand* GEMMs inside attention,
         which vmap ``mm`` over the (batch, head) axis exactly as the

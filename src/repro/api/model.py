@@ -1,7 +1,6 @@
 """The front-door session object: ``api.compile(...) -> CompiledModel``.
 
-One object unifies the former ``compile_network`` / ``execute_program``
-/ ``ProgramServer`` split:
+One object is the compiled network and its serving entry:
 
     model = api.compile(graph, HurryConfig(array_rows=511))
     probs = model.run(x)                    # jitted; cached per batch bucket
@@ -14,7 +13,7 @@ analogue of programming conductances), so ``run`` only ever quantizes
 the input and dispatches kernels; no weight touches float math after
 compile.  ``run`` keeps one jitted executor per output flavor and pads
 incoming batches up to a small bucket ladder (edge replication —
-slice-exact, see ``program/serve.py``), so varying-traffic batch sizes
+slice-exact, see ``pad_batch``), so varying-traffic batch sizes
 share one XLA executable per bucket instead of compiling per exact
 shape.  ``simulate`` runs the analytical chip model on the *same*
 graph the numeric program was compiled from — one network definition,
@@ -24,6 +23,7 @@ both evaluations.
 from __future__ import annotations
 
 import dataclasses
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +34,6 @@ from repro.core.simulator import simulate_hurry
 from repro.program.compile import CrossbarProgram, compile_network
 from repro.program.execute import execute_packed
 from repro.program.pack import PackedProgram, pack_program
-from repro.program.serve import BUCKETS, bucket_batch, pad_batch
 
 from .config import HurryConfig
 from .graph import NetworkBuilder, NetworkGraph
@@ -42,6 +41,34 @@ from .serialize import load_model, save_model
 from .zoo import GRAPHS
 
 SIM_ARCHS = ("hurry", "isaac-128", "isaac-256", "isaac-512", "misca")
+
+# default batch-bucket ladder: powers of two cover varying traffic with
+# at most 2x padding and ~10 executables total
+BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
+
+
+def bucket_batch(b: int, buckets: Sequence[int]) -> int:
+    """Smallest bucket >= b, or b itself beyond the ladder (exact shape).
+
+    Order-insensitive, so a user-supplied unsorted ladder never pads
+    more than the tightest eligible bucket.
+    """
+    return min((s for s in buckets if s >= b), default=b)
+
+
+def pad_batch(x: jnp.ndarray, bucket: int) -> jnp.ndarray:
+    """Pad the batch axis up to ``bucket`` by edge replication.
+
+    Replicating the last request (rather than zero-filling) keeps every
+    per-tensor quantization statistic exact: ``max(|x|)`` over
+    duplicated rows equals the unpadded max at every stage, so the kept
+    rows of a bucketed run are bit-identical to the unbucketed run.
+    """
+    b = x.shape[0]
+    if bucket == b:
+        return x
+    return jnp.pad(x, ((0, bucket - b),) + ((0, 0),) * (x.ndim - 1),
+                   mode="edge")
 
 
 @dataclasses.dataclass
@@ -52,7 +79,7 @@ class CompiledModel:
     config: HurryConfig
     program: CrossbarProgram
     params: dict
-    packed: PackedProgram | None = None
+    packed: PackedProgram
     buckets: tuple[int, ...] = BUCKETS
     _fns: dict = dataclasses.field(default_factory=dict, repr=False,
                                    compare=False)
@@ -63,11 +90,6 @@ class CompiledModel:
     _requests: int = dataclasses.field(default=0, repr=False, compare=False)
 
     # -- numeric execution -------------------------------------------------
-
-    def _packed(self) -> PackedProgram:
-        if self.packed is None:   # models built before packing existed
-            self.packed = pack_program(self.program, self.params)
-        return self.packed
 
     def _fn(self, logits: bool):
         """The jitted executor of one output flavor, built once."""
@@ -113,7 +135,7 @@ class CompiledModel:
             new = (logits, bucket) not in self._called
             with TraceAnnotation("repro.run.call", request=req,
                                  bucket=bucket, new=int(new)):
-                y = fn(self._packed(), x)
+                y = fn(self.packed, x)
             self._called.add((logits, bucket))
             with TraceAnnotation("repro.run.slice", request=req):
                 return y[:b]
@@ -127,7 +149,7 @@ class CompiledModel:
         JAX's compile cache, where one is on."""
         shape = (bucket_batch(x.shape[0], self.buckets),) + x.shape[1:]
         v = jax.ShapeDtypeStruct(shape, x.dtype)
-        return self._fn(logits).lower(self._packed(), v).compile().as_text()
+        return self._fn(logits).lower(self.packed, v).compile().as_text()
 
     def warmup(self, batch: int = 1, *, logits: bool = False,
                seq_len: int = 16) -> None:

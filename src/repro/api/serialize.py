@@ -82,7 +82,6 @@ from repro.kernels.crossbar_gemm import dense_layout, mount_layout, mount_rows
 from repro.program.compile import CrossbarProgram, MountRound, ProgramOp
 from repro.program.pack import (PackedProgram, PackedStage, pack_program,
                                 stage_layout)
-from repro.program.serve import BUCKETS
 
 from .config import HurryConfig
 from .graph import NetworkGraph
@@ -160,7 +159,7 @@ def save_model(model, path: str) -> str:
         for key in sorted(model.params[layer]):
             arrays[f"p{len(index)}"] = np.asarray(model.params[layer][key])
             index.append([layer, key])
-    packed = model._packed()
+    packed = model.packed
     optional = {key: [] for key in _OPTIONAL}
     for i, st in enumerate(packed.stages):
         arrays[f"w{i}"] = np.asarray(st.w8)
@@ -193,7 +192,7 @@ def load_model(path: str):
     """Load a ``CompiledModel`` saved by ``save_model`` — no compile step,
     and (version >= 2) no weight quantization: the packed planes are read
     back verbatim."""
-    from .model import CompiledModel
+    from .model import BUCKETS, CompiledModel
     with np.load(path, allow_pickle=False) as z:
         meta = json.loads(str(z["__meta__"][()]))
         if meta.get("format") != FORMAT:

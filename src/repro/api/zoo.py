@@ -1,11 +1,9 @@
 """The paper's benchmark CNNs — plus sequence models — as builder programs.
 
-The CNNs produce layer-by-layer *identical* ``LayerSpec`` lists to the
-historical handwritten lists in ``core/workload.py`` (same names, same
-shapes, same residual/branch wiring), so the simulator, scheduler, and
-compiled-program paths see exactly the graphs the paper §IV evaluates.
-``core.workload.WORKLOADS`` is a deprecated compat shim over this
-module.
+The CNNs are the CIFAR-10 variants the paper §IV evaluates; the
+simulator, the scheduler and the compiled-program path all read their
+``LayerSpec`` lists from these graphs, and ``NetworkGraph.forward`` is
+their functional oracle.
 
 ``vit_tiny`` opens the transformer workload class (DESIGN.md §9): a
 patchify conv, ``depth`` post-norm encoder blocks (attention + MLP,

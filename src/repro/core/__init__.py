@@ -17,7 +17,7 @@ from .scheduling import (ArrayPlan, fb_relative_positioning,
 from .bas import ArrayConfig, ArraySchedule, schedule_array, check_legal
 from .simulator import ChipConfig, SimReport, simulate_hurry
 from .baselines import BaselineConfig, simulate_isaac, simulate_misca
-from .workload import WORKLOADS, LayerSpec, layer_groups
+from .workload import LayerSpec, layer_groups
 
 __all__ = [
     "CrossbarConfig", "crossbar_matmul", "crossbar_linear", "quantize_symmetric",
@@ -27,5 +27,5 @@ __all__ = [
     "ArrayConfig", "ArraySchedule", "schedule_array", "check_legal",
     "ChipConfig", "SimReport", "simulate_hurry",
     "BaselineConfig", "simulate_isaac", "simulate_misca",
-    "WORKLOADS", "LayerSpec", "layer_groups",
+    "LayerSpec", "layer_groups",
 ]

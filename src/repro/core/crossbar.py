@@ -41,7 +41,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Optional
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -227,3 +227,25 @@ def crossbar_linear(x_fp: jnp.ndarray, w_fp: jnp.ndarray,
     wq, wscale = quantize_symmetric(w_fp, cfg.weight_bits)
     y = crossbar_matmul(xq, wq, cfg, noise_key)
     return y.astype(jnp.float32) * (xscale * wscale)
+
+
+MatmulFn = Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray]
+
+
+def fp_matmul(x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    return x @ w
+
+
+def make_crossbar_matmul(cfg: Optional[CrossbarConfig] = None,
+                         noise_key: Optional[jax.Array] = None) -> MatmulFn:
+    """Route model GEMMs through the crossbar functional model.
+
+    ``crossbar_matmul`` statically dispatches per config (DESIGN.md §4):
+    clip-free + no-noise runs as one exact int GEMM; noisy or saturating
+    configs take the faithful plane-packed sliced path.
+    """
+    cfg = cfg or CrossbarConfig()
+
+    def mm(x, w):
+        return crossbar_linear(x, w, cfg, noise_key)
+    return mm

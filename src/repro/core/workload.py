@@ -4,8 +4,7 @@
 consumer reads (simulator, baselines, program compiler).  Networks are
 *authored* through ``repro.api.NetworkBuilder`` (shape inference +
 build-time validation); the three paper CNNs live in ``repro.api.zoo``
-as builder programs, and the ``WORKLOADS`` registry below is a
-deprecated compat shim over them.  Shapes follow the common CIFAR-10
+as builder programs.  Shapes follow the common CIFAR-10
 variants of AlexNet / VGG-16 / ResNet-18 used by PUMAsim-style
 evaluations; BatchNorm is folded into the preceding conv for inference.
 
@@ -35,7 +34,6 @@ un-normed.
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Iterator
 
 # kinds that head a GEMM group (own weights / mounts on the array)
@@ -110,49 +108,6 @@ class LayerSpec:
             return (self.out_ch * self.out_hw * self.out_hw
                     or self.features_out)
         return self.features_out
-
-
-# -- the paper CNNs (compat shims over the repro.api builder programs) -----
-# The graphs themselves are authored in ``repro.api.zoo`` through
-# ``NetworkBuilder`` (imported lazily: api builds on top of core).
-
-def alexnet_cifar() -> list[LayerSpec]:
-    from repro.api.zoo import alexnet_graph
-    return list(alexnet_graph().layers)
-
-
-def vgg16_cifar() -> list[LayerSpec]:
-    from repro.api.zoo import vgg16_graph
-    return list(vgg16_graph().layers)
-
-
-def resnet18_cifar() -> list[LayerSpec]:
-    from repro.api.zoo import resnet18_graph
-    return list(resnet18_graph().layers)
-
-
-class _WorkloadShim(dict):
-    """Deprecated registry: warns and forwards to ``repro.api.zoo``.
-
-    Kept so historical call sites (``WORKLOADS["alexnet"]()``) keep
-    returning the layer-identical specs, but every lookup points users
-    at the authoring surface that replaced it.
-    """
-
-    def __getitem__(self, net):
-        warnings.warn(
-            "core.workload.WORKLOADS is deprecated; author networks with "
-            "repro.api.NetworkBuilder and use the repro.api.zoo registry "
-            "(api.zoo.GRAPHS / api.compile(name)) instead",
-            DeprecationWarning, stacklevel=2)
-        return super().__getitem__(net)
-
-
-WORKLOADS = _WorkloadShim({
-    "alexnet": alexnet_cifar,
-    "vgg16": vgg16_cifar,
-    "resnet18": resnet18_cifar,
-})
 
 
 # canonical FB chain order inside one fused group (gemm implicit first):

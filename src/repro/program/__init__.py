@@ -8,23 +8,19 @@ int8 planes, conv layout, K padded to full mounts — the numeric
 analogue of programming conductances); ``execute.py`` runs the packed
 program batched under ``jax.jit``, activating all mounts of a stage in
 one ``crossbar_gemm`` K-grid dispatch and every post-op chain in one
-fused ``fb_epilogue`` pass; ``serve.py`` is the compile+pack-once /
-execute-per-batch serving entry with batch-shape bucketing.
-``repro.api`` builds the user-facing surface (builder graphs, unified
-``HurryConfig``, persistable ``CompiledModel`` sessions) on top of
-this subsystem.
+fused ``fb_epilogue`` pass.  ``repro.api`` builds the user-facing
+surface on top of this subsystem (builder graphs, unified
+``HurryConfig``, persistable ``CompiledModel`` sessions whose ``run``
+is the serving entry); nothing here imports it.
 """
 
 from .compile import (CrossbarProgram, MountRound, ProgramOp,
                       compile_network)
-from .execute import execute_packed, execute_program
+from .execute import execute_packed
 from .pack import PackedProgram, PackedStage, pack_program
-from .serve import BUCKETS, ProgramServer, bucket_batch, make_server, \
-    pad_batch
 
 __all__ = [
     "CrossbarProgram", "MountRound", "ProgramOp", "compile_network",
     "PackedProgram", "PackedStage", "pack_program",
-    "execute_packed", "execute_program",
-    "ProgramServer", "make_server", "BUCKETS", "bucket_batch", "pad_batch",
+    "execute_packed",
 ]

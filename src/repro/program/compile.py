@@ -383,7 +383,7 @@ def compile_network(net, *, config=None,
                     chip: ChipConfig | None = None,
                     cfg: CrossbarConfig | None = None,
                     name: str = "") -> CrossbarProgram:
-    """Lower a network (name, LayerSpec list, or NetworkGraph) to a program.
+    """Lower a network (LayerSpec list, or NetworkGraph) to a program.
 
     ``config`` is a ``repro.api.HurryConfig`` — the unified front-door
     config from which both the chip geometry and the crossbar numerics
@@ -397,13 +397,7 @@ def compile_network(net, *, config=None,
         cfg = cfg or config.crossbar()
     chip = chip or ChipConfig()
     cfg = cfg or chip.crossbar()
-    if isinstance(net, str):
-        # lazy: the registry lives in repro.api.zoo, which sits above
-        # this module (core.workload.WORKLOADS is a deprecated shim)
-        from repro.api.zoo import GRAPHS
-        name = name or net
-        layers = list(GRAPHS[net]().layers)
-    elif hasattr(net, "layers"):          # a repro.api NetworkGraph
+    if hasattr(net, "layers"):            # a repro.api NetworkGraph
         layers = list(net.layers)
         name = name or net.name
     else:

@@ -102,11 +102,9 @@ instruction serves:
 Scopes are metadata only: they change no op and cost nothing at run
 time.
 
-``execute_packed`` is trace-pure; wrap it in ``jax.jit`` with the
-program closed over (see ``serve.ProgramServer``) to compile once and
-execute per request batch.  ``execute_program`` is the
-params-consuming compatibility entry: it packs under the trace, i.e.
-re-derives the weight planes every call — the pre-PR-4 cost profile.
+``execute_packed`` is the one entry: trace-pure, it takes the packed
+program as a pytree argument, so ``jax.jit`` compiles it once per batch
+shape (``api.CompiledModel.run`` does, per bucket).
 """
 
 from __future__ import annotations
@@ -116,16 +114,15 @@ from typing import Callable, Iterator, Mapping, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.conv import im2col, im2col_read_mask
 from repro.core.crossbar import quantize_scale, quantize_symmetric
 from repro.kernels.crossbar_gemm import mounted_gemm
 from repro.kernels.fb_epilogue import (LN_EPS, fb_epilogue, gelu_erf,
                                        layer_norm_ordered, softmax_ordered)
 from repro.kernels.ops import interpret_default
-from repro.models.cnn import im2col, im2col_read_mask
 
-from .compile import CrossbarProgram, ProgramOp
-from .pack import (PackedProgram, PackedStage, pack_program, plane_pack,
-                   stage_layout)
+from .compile import ProgramOp
+from .pack import PackedProgram, PackedStage, plane_pack, stage_layout
 from .sequence import embed_tokens, merge_heads, split_qkv_heads, tokens
 
 
@@ -424,19 +421,3 @@ def execute_packed(packed: PackedProgram, x: jnp.ndarray,
             out = stage.value
     return out
 
-
-def execute_program(program: CrossbarProgram, params: dict, x: jnp.ndarray,
-                    *, block_m: int | None = None,
-                    block_n: int | None = None,
-                    interpret: bool | None = None,
-                    return_logits: bool = False) -> jnp.ndarray:
-    """Params-consuming compatibility entry (pre-packing cost profile).
-
-    Packs under the trace — weight planes are re-derived on every call,
-    which is what serving paid before compile-time mounting; servers
-    should pack once and call ``execute_packed`` (``ProgramServer`` and
-    ``api.CompiledModel`` do).  Numerics are identical either way.
-    """
-    return execute_packed(pack_program(program, params), x,
-                          block_m=block_m, block_n=block_n,
-                          interpret=interpret, return_logits=return_logits)

@@ -5,8 +5,7 @@ crossbar conductances **once**; only inputs stream through at inference.
 ``pack_program`` is the numeric analogue of that conductance
 programming: given a compiled ``CrossbarProgram`` and its float
 parameter pytree, it pre-computes — once, at pack time — everything
-about the weights that ``execute_program`` used to re-derive on every
-call:
+about the weights, so no forward re-derives it:
 
 * per-stage symmetric int8 quantization of the full weight matrix
   (``quantize_symmetric`` at ``cfg.weight_bits``) -> the int8 **mount
@@ -201,14 +200,13 @@ def _op_params(params: dict, key: str) -> dict:
 def pack_program(program: CrossbarProgram, params: dict) -> PackedProgram:
     """Mount ``params`` into ``program``: the compile-time analogue of
     programming the chip's conductances.  Meant to run ONCE outside the
-    per-call hot path (``ProgramServer`` packs at construction,
-    ``api.compile`` at compile time).
+    per-call hot path (``api.compile`` packs at compile time).
 
     Jitted (program static) so the weight quantization compiles exactly
-    like the jitted functional reference and the in-trace packing of
-    ``execute_program``: eager op-by-op dispatch rounds ``x / scale``
-    one ulp differently on a measure-zero set of boundary values, which
-    would flip the occasional int8 plane entry (DESIGN.md §5/§7).
+    like the jitted functional reference: eager op-by-op dispatch
+    rounds ``x / scale`` one ulp differently on a measure-zero set of
+    boundary values, which would flip the occasional int8 plane entry
+    (DESIGN.md §5/§7).
     """
     cfg = program.cfg
     stages = []

@@ -4,11 +4,14 @@ Covers ISSUE 3's acceptance criteria: a user-defined ``NetworkBuilder``
 graph (never touching ``core/workload.py``) compiles, runs bit-exactly
 against the functional crossbar forward under a clip-free config, and
 round-trips through ``save``/``load`` bit-exactly (both sides jitted,
-DESIGN.md §5); the paper CNNs keep working through the ``WORKLOADS``
-compat shim; warmup shapes derive from the compiled program's input
-spec; and malformed graphs fail at build time with the offending layer's
-name.
+DESIGN.md §5); the paper CNNs compile by their zoo name; warmup shapes
+derive from the compiled program's input spec; malformed graphs fail at
+build time with the offending layer's name; and the layers below
+``repro.api`` never import it.
 """
+
+import ast
+import pathlib
 
 import jax
 import jax.numpy as jnp
@@ -17,11 +20,10 @@ import pytest
 
 from repro import api
 from repro.api import GRAPHS, HurryConfig, NetworkBuilder, NetworkGraph
-from repro.core.crossbar import CrossbarConfig
+from repro.core.crossbar import CrossbarConfig, make_crossbar_matmul
 from repro.core.simulator import ChipConfig, simulate_hurry
-from repro.core.workload import WORKLOADS, LayerSpec, layer_groups
-from repro.models.cnn import make_crossbar_matmul
-from repro.program import compile_network, make_server
+from repro.core.workload import LayerSpec, layer_groups
+from repro.program import compile_network
 
 CLIP_FREE = HurryConfig(array_rows=511)      # DESIGN.md §4 predicate holds
 
@@ -119,14 +121,14 @@ def test_hurry_config_derivations_agree():
 
 
 def test_compile_and_serve_consume_hurry_config():
-    program = compile_network("alexnet", config=CLIP_FREE)
+    program = compile_network(GRAPHS["alexnet"](), config=CLIP_FREE)
     assert program.cfg == CLIP_FREE.crossbar()
-    server = make_server("alexnet", config=CLIP_FREE)
-    assert server.program.cfg == CLIP_FREE.crossbar()
+    model = api.compile("alexnet", CLIP_FREE)
+    assert model.program.cfg == CLIP_FREE.crossbar()
 
 
 def test_simulator_and_baselines_consume_hurry_config():
-    layers = WORKLOADS["alexnet"]()
+    layers = list(GRAPHS["alexnet"]().layers)
     via_api = simulate_hurry(layers, chip=HurryConfig())
     via_chip = simulate_hurry(layers, chip=ChipConfig())
     assert via_api.throughput_cycles == via_chip.throughput_cycles
@@ -134,7 +136,7 @@ def test_simulator_and_baselines_consume_hurry_config():
 
 
 # ---------------------------------------------------------------------------
-# acceptance: custom net bit-exact, save/load roundtrip, compat shim
+# acceptance: custom net bit-exact, save/load roundtrip, zoo by name
 # ---------------------------------------------------------------------------
 
 def test_custom_net_bit_exact_vs_functional_forward():
@@ -170,19 +172,6 @@ def test_save_load_roundtrip_bit_exact(tmp_path):
                                   np.asarray(loaded.run(x)))
 
 
-def test_workloads_shim_matches_zoo_graphs():
-    """The compat shim serves exactly the zoo builder programs."""
-    for net, fn in WORKLOADS.items():
-        assert fn() == list(GRAPHS[net]().layers)
-    # pinned structural facts of the paper graphs
-    alex = {l.name: l for l in WORKLOADS["alexnet"]()}
-    assert alex["conv1"].in_ch == 3 and alex["conv1"].out_hw == 32
-    assert alex["fc6"].features_in == 256 * 4 * 4
-    res = {l.name: l for l in WORKLOADS["resnet18"]()}
-    assert res["s1b0_res"].residual_from == "s1b0_proj"
-    assert res["s1b0_conv1"].input_from == "s0b1_relu2"
-
-
 def test_paper_cnn_through_api_by_name():
     model = api.compile("alexnet", CLIP_FREE)
     assert model.graph.name == "alexnet"
@@ -196,10 +185,16 @@ def test_graph_init_params_shapes_are_graph_derived():
     params = graph.init_params(jax.random.PRNGKey(0))
     assert params["conv1"]["w"].shape == (3, 3, 3, 64)
     assert params["fc6"]["w"].shape == (256 * 4 * 4, 1024)
-    from repro.models.cnn import CNN_MODELS
-    model_params = CNN_MODELS["alexnet"].init(jax.random.PRNGKey(0))
-    assert jax.tree_util.tree_structure(params) == \
-        jax.tree_util.tree_structure(model_params)
+    # one entry per GEMM layer, shaped by its spec
+    gemms = [l for l in graph.layers if l.kind in ("conv", "fc")]
+    assert sorted(params) == sorted(l.name for l in gemms)
+    for l in gemms:
+        w, b = params[l.name]["w"], params[l.name]["b"]
+        if l.kind == "conv":
+            assert w.shape == (l.ksize, l.ksize, l.in_ch, l.out_ch)
+        else:
+            assert w.shape == (l.features_in, l.features_out)
+        assert b.shape == (w.shape[-1],)
 
 
 # ---------------------------------------------------------------------------
@@ -209,9 +204,9 @@ def test_graph_init_params_shapes_are_graph_derived():
 def test_warmup_shape_derived_from_program():
     graph, model, _ = _model_and_input()
     assert model.program.input_shape(5) == (5, 8, 8, 4)
-    server = make_server(graph, model.params, config=CLIP_FREE)
-    server.warmup(2)               # non-CIFAR shape: used to hardcode 32x32x3
-    y = server(jnp.zeros(graph.input_shape(2), jnp.float32))
+    model.warmup(2)                # non-CIFAR shape: used to hardcode 32x32x3
+    assert (False, 2) in model._called
+    y = model.run(jnp.zeros(graph.input_shape(2), jnp.float32))
     assert y.shape == (2, 10)
 
 
@@ -326,3 +321,34 @@ def test_compiled_text_is_the_scoped_program():
     text = model.compiled_text(x)
     assert text.startswith("HloModule")
     assert "/s00." in text and "/quantize/" in text
+
+
+# ---------------------------------------------------------------------------
+# layering: core -> kernels -> program never import the layers above
+# ---------------------------------------------------------------------------
+
+def test_lower_layers_import_no_upper_layer():
+    """No module of ``repro.core``, ``repro.kernels`` or ``repro.program``
+    imports ``repro.api`` or ``repro.models``, at top level or lazily."""
+    src = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
+    upper = ("repro.api", "repro.models")
+    found = []
+    for pkg in ("core", "kernels", "program"):
+        for path in sorted((src / pkg).rglob("*.py")):
+            for node in ast.walk(ast.parse(path.read_text())):
+                if isinstance(node, ast.Import):
+                    names = [a.name for a in node.names]
+                elif isinstance(node, ast.ImportFrom):
+                    base = node.module or ""
+                    if node.level:          # relative: resolve to repro.*
+                        parts = ["repro", *path.relative_to(src).parts[:-1]]
+                        parts = parts[:len(parts) - node.level + 1]
+                        base = ".".join(parts + ([base] if base else []))
+                    names = [base] + [f"{base}.{a.name}" for a in node.names]
+                else:
+                    continue
+                if any(n == u or n.startswith(u + ".")
+                       for n in names for u in upper):
+                    found.append(f"{path.relative_to(src)}:{node.lineno}")
+    assert not found, found
+

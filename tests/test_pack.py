@@ -19,17 +19,17 @@ import numpy as np
 import pytest
 
 from repro import api
-from repro.api import HurryConfig, NetworkBuilder
+from repro.api import GRAPHS, HurryConfig, NetworkBuilder
 from repro.api.zoo import vit_tiny_graph
+from repro.core.conv import im2col
 from repro.core.crossbar import (CrossbarConfig, crossbar_matmul,
-                                 quantize_symmetric)
+                                 make_crossbar_matmul, quantize_symmetric)
 from repro.kernels import ref
 from repro.kernels.crossbar_gemm import (clip_possible, crossbar_gemm,
                                         dense_blocks, dense_layout,
                                         mount_layout, mount_rows,
                                         mounted_gemm)
 from repro.kernels.fb_epilogue import fb_epilogue
-from repro.models.cnn import CNN_MODELS, im2col, make_crossbar_matmul
 from repro.program import compile_network, execute_packed, pack_program
 from repro.program.execute import stage_outputs
 
@@ -49,8 +49,8 @@ LAYOUT_CASES = {"dense": CLIP_FREE,
 
 @pytest.mark.parametrize("layout", LAYOUT_CASES)
 def test_packed_planes_match_traced_quantization(layout):
-    params = CNN_MODELS["alexnet"].init(jax.random.PRNGKey(1))
-    program = compile_network("alexnet", cfg=LAYOUT_CASES[layout])
+    params = GRAPHS["alexnet"]().init_params(jax.random.PRNGKey(1))
+    program = compile_network(GRAPHS["alexnet"](), cfg=LAYOUT_CASES[layout])
     packed = pack_program(program, params)
     assert packed.program.plans == ()       # executor never reads plans
     assert packed.layouts() == (layout,) * len(program.stages())
@@ -229,8 +229,8 @@ def test_multi_mount_stage_keeps_sliced_adc_semantics():
     streams it: the sliced kernel still clips per mount over exactly
     ``tile_rows`` real rows — bit-exact against ``ref.crossbar_gemm_ref``
     chunked at ``tile_rows``, and genuinely clipping."""
-    params = CNN_MODELS["alexnet"].init(jax.random.PRNGKey(1))
-    program = compile_network("alexnet", cfg=CrossbarConfig(adc_bits=7))
+    params = GRAPHS["alexnet"]().init_params(jax.random.PRNGKey(1))
+    program = compile_network(GRAPHS["alexnet"](), cfg=CrossbarConfig(adc_bits=7))
     packed = pack_program(program, params)
     (gemm, _), st = program.stages()[1], packed.stages[1]
     rows = gemm.tile_rows
@@ -251,8 +251,8 @@ def test_buffer_lifetime_dropping_never_changes_results():
     """Dropping dead buffers is bookkeeping only: a run that keeps every
     intermediate alive produces the identical output."""
     import repro.program.execute as ex
-    params = CNN_MODELS["resnet18"].init(jax.random.PRNGKey(1))
-    program = compile_network("resnet18", cfg=CLIP_FREE)
+    params = GRAPHS["resnet18"]().init_params(jax.random.PRNGKey(1))
+    program = compile_network(GRAPHS["resnet18"](), cfg=CLIP_FREE)
     packed = pack_program(program, params)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 32, 32, 3))
     y_drop = execute_packed(packed, x, return_logits=True)
@@ -394,7 +394,7 @@ def test_load_then_run_never_requantizes_weights(tmp_path, monkeypatch):
     loaded = api.load(path)
     y_loaded = loaded.run(x, logits=True)
     np.testing.assert_array_equal(np.asarray(y_mem), np.asarray(y_loaded))
-    for a, b in zip(model._packed().stages, loaded.packed.stages):
+    for a, b in zip(model.packed.stages, loaded.packed.stages):
         np.testing.assert_array_equal(np.asarray(a.w8), np.asarray(b.w8))
 
 
@@ -416,7 +416,7 @@ def test_version1_file_loads_via_repack_fallback(tmp_path):
     loaded = api.load(v1)
     np.testing.assert_array_equal(np.asarray(model.run(x, logits=True)),
                                   np.asarray(loaded.run(x, logits=True)))
-    for a, b in zip(model._packed().stages, loaded.packed.stages):
+    for a, b in zip(model.packed.stages, loaded.packed.stages):
         np.testing.assert_array_equal(np.asarray(a.w8), np.asarray(b.w8))
     with pytest.raises(ValueError, match="version"):
         meta["version"] = 99
@@ -490,7 +490,7 @@ def test_pre_mount_layout_file_loads_bit_identical(tmp_path, version):
         np.savez(f, __meta__=np.asarray(json.dumps(meta)), **arrays)
     loaded = api.load(old)
     assert loaded.config == model.config        # block sizes back to None
-    for a, b in zip(model._packed().stages, loaded.packed.stages):
+    for a, b in zip(model.packed.stages, loaded.packed.stages):
         np.testing.assert_array_equal(np.asarray(a.w8), np.asarray(b.w8))
     np.testing.assert_array_equal(np.asarray(model.run(x, logits=True)),
                                   np.asarray(loaded.run(x, logits=True)))
@@ -545,8 +545,8 @@ def test_dense_gemm_bit_exact_over_k_blocks():
 def test_packed_program_is_a_jit_arg():
     """PackedProgram crosses the jit boundary as a pytree (arrays as
     leaves, the plan-free program as static treedef metadata)."""
-    params = CNN_MODELS["alexnet"].init(jax.random.PRNGKey(1))
-    program = compile_network("alexnet", cfg=CLIP_FREE)
+    params = GRAPHS["alexnet"]().init_params(jax.random.PRNGKey(1))
+    program = compile_network(GRAPHS["alexnet"](), cfg=CLIP_FREE)
     packed = pack_program(program, params)
     leaves = jax.tree_util.tree_leaves(packed)
     assert all(isinstance(l, jax.Array) for l in leaves)

@@ -23,9 +23,9 @@ import pytest
 from repro import api
 from repro.api import HurryConfig, NetworkBuilder
 from repro.api.zoo import deit_graph, vit_tiny_graph
+from repro.core.crossbar import make_crossbar_matmul
 from repro.kernels import ref
 from repro.kernels.fb_epilogue import fb_epilogue, gelu_erf
-from repro.models.cnn import make_crossbar_matmul
 
 from bench import program_trace as pt
 

@@ -21,24 +21,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.crossbar import CrossbarConfig
-from repro.core.workload import LayerSpec, WORKLOADS
+from repro import api
+from repro.api import GRAPHS, HurryConfig
+from repro.core.crossbar import CrossbarConfig, make_crossbar_matmul
+from repro.core.workload import LayerSpec
 from repro.kernels import ref
 from repro.kernels.fb_epilogue import fb_epilogue
 from repro.kernels.crossbar_gemm import crossbar_gemm
-from repro.models.cnn import CNN_MODELS, make_crossbar_matmul, \
-    make_program_forward
-from repro.program import compile_network, execute_program, make_server
+from repro.program import compile_network, execute_packed, pack_program
 
 NETS = ("alexnet", "vgg16", "resnet18")
 # rows=511 is clip-free (DESIGN.md §4) -> the functional model takes its
 # exact path and every program mount (tile_rows <= 511) digitizes exactly
 CLIP_FREE = CrossbarConfig(rows=511, adc_bits=9)
+# the same numerics as a front-door config: 511-row arrays, 9-bit ADC
+CLIP_FREE_API = HurryConfig(array_rows=511)
 
 
 def _data(net, batch=2, seed=0):
-    m = CNN_MODELS[net]
-    params = m.init(jax.random.PRNGKey(1))
+    graph = GRAPHS[net]()
+    params = graph.init_params(jax.random.PRNGKey(1))
     # random biases: the fused epilogue's bias add must be exercised
     # (model init zeros them)
     params = {k: {"w": v["w"],
@@ -47,29 +49,35 @@ def _data(net, batch=2, seed=0):
                       v["b"].shape)}
               for k, v in params.items()}
     x = jax.random.normal(jax.random.PRNGKey(seed), (batch, 32, 32, 3))
-    return m, params, x
+    return graph, params, x
 
 
-def _ref_logits(m, params, x, cfg):
-    fwd = jax.jit(lambda p, v: m.forward(p, v, mm=make_crossbar_matmul(cfg)))
+def _ref_logits(graph, params, x, cfg):
+    fwd = jax.jit(lambda p, v: graph.forward(
+        p, v, mm=make_crossbar_matmul(cfg), logits=True))
     return fwd(params, x)
+
+
+def _pack(net, params, cfg):
+    return pack_program(compile_network(GRAPHS[net](), cfg=cfg), params)
 
 
 @pytest.mark.parametrize("net", NETS)
 def test_program_bit_exact_clip_free(net):
-    """Packed server AND legacy executor == functional forward, bitwise,
-    clip-free (both sides jitted — FMA contraction, DESIGN.md §5)."""
-    m, params, x = _data(net)
-    ref_logits = _ref_logits(m, params, x, CLIP_FREE)
-    # the packed path: weights mounted once at construction
-    server = make_server(net, params, cfg=CLIP_FREE, return_logits=True)
-    np.testing.assert_array_equal(np.asarray(server(x)),
+    """The serving front AND the bare executor == functional forward,
+    bitwise, clip-free (both sides jitted — FMA contraction, DESIGN.md
+    §5)."""
+    graph, params, x = _data(net)
+    ref_logits = _ref_logits(graph, params, x, CLIP_FREE)
+    # the serving front: weights mounted once at compile
+    model = api.compile(graph, CLIP_FREE_API, params=params)
+    np.testing.assert_array_equal(np.asarray(model.run(x, logits=True)),
                                   np.asarray(ref_logits))
-    # the params-consuming compat entry (packs under the trace)
-    program = compile_network(net, cfg=CLIP_FREE)
-    logits = jax.jit(lambda p, v: execute_program(
-        program, p, v, return_logits=True))(params, x)
-    probs = jax.jit(lambda p, v: execute_program(program, p, v))(params, x)
+    # the executor entry on a program packed outside the trace
+    packed = _pack(net, params, CLIP_FREE)
+    logits = jax.jit(lambda pk, v: execute_packed(
+        pk, v, return_logits=True))(packed, x)
+    probs = jax.jit(execute_packed)(packed, x)
     np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
     np.testing.assert_allclose(
         np.asarray(probs),
@@ -81,11 +89,10 @@ def test_program_tolerance_when_clipping_fires():
     granularity while the model chunks at array rows, so clipped outputs
     differ — but must stay close (DESIGN.md §4 'tolerance otherwise')."""
     cfg = CrossbarConfig(adc_bits=7)     # rows=512 > 127: clipping fires
-    m, params, x = _data("alexnet")
-    program = compile_network("alexnet", cfg=cfg)
-    out = jax.jit(lambda p, v: execute_program(
-        program, p, v, return_logits=True))(params, x)
-    ref_logits = _ref_logits(m, params, x, cfg)
+    graph, params, x = _data("alexnet")
+    out = jax.jit(lambda pk, v: execute_packed(
+        pk, v, return_logits=True))(_pack("alexnet", params, cfg), x)
+    ref_logits = _ref_logits(graph, params, x, cfg)
     r, o = np.asarray(ref_logits), np.asarray(out)
     assert not np.array_equal(r, o)      # saturation genuinely engaged
     assert np.linalg.norm(o - r) / np.linalg.norm(r) < 0.2
@@ -151,8 +158,7 @@ def test_fused_epilogue_used_for_all_postops(monkeypatch):
     monkeypatch.setattr(ex, "fb_epilogue", spy)
     for net in ("alexnet", "resnet18"):
         _, params, x = _data(net, batch=1)
-        program = compile_network(net, cfg=CLIP_FREE)
-        execute_program(program, params, x)
+        execute_packed(_pack(net, params, CLIP_FREE), x)
     acts = {s[0] for s in seen}
     pools = {s[1] for s in seen}
     assert "relu" in acts
@@ -160,7 +166,7 @@ def test_fused_epilogue_used_for_all_postops(monkeypatch):
     assert any(s[2] for s in seen)        # softmax FB fused
     assert any(s[3] for s in seen)        # residual FB fused
     # every stage of both programs went through the fused kernel
-    n_stages = sum(len(compile_network(n, cfg=CLIP_FREE).stages())
+    n_stages = sum(len(compile_network(GRAPHS[n](), cfg=CLIP_FREE).stages())
                    for n in ("alexnet", "resnet18"))
     assert len(seen) == n_stages
 
@@ -170,7 +176,7 @@ def test_fused_epilogue_used_for_all_postops(monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_program_structure_and_mounts():
-    program = compile_network("alexnet", cfg=CLIP_FREE)
+    program = compile_network(GRAPHS["alexnet"](), cfg=CLIP_FREE)
     kinds = {op.kind for op in program.ops}
     assert kinds == {"gemm", "relu", "maxpool", "softmax"}
     for op in program.ops:
@@ -201,7 +207,7 @@ def test_compile_rejects_non_canonical_chain():
 
 
 def test_resnet_residual_wiring_names_real_buffers():
-    layers = WORKLOADS["resnet18"]()
+    layers = GRAPHS["resnet18"]().layers
     by_name = {l.name: l for l in layers}
     # projection blocks route the shortcut through the proj conv
     assert by_name["s1b0_res"].residual_from == "s1b0_proj"
@@ -211,18 +217,31 @@ def test_resnet_residual_wiring_names_real_buffers():
 
 
 # ---------------------------------------------------------------------------
-# serving entry + models rewiring
+# serving entry
 # ---------------------------------------------------------------------------
 
 def test_make_server_compiles_once_and_is_deterministic():
-    _, params, x = _data("alexnet", batch=2)
-    server = make_server("alexnet", params, cfg=CLIP_FREE,
-                         return_logits=True)
-    assert server.program.n_mount_rounds > 0
-    y1 = jax.block_until_ready(server(x))
-    y2 = jax.block_until_ready(server(x))
+    """``CompiledModel.run`` traces its executor once for a bucket and
+    serves repeat requests identically, equal to the bare executor."""
+    import repro.api.model as apimodel
+    graph, params, x = _data("alexnet", batch=2)
+    model = api.compile(graph, CLIP_FREE_API, params=params)
+    assert model.program.n_mount_rounds > 0
+    traces = []
+    orig = apimodel.execute_packed
+
+    def spy(pk, v, **kw):
+        traces.append(v.shape[0])
+        return orig(pk, v, **kw)
+
+    apimodel.execute_packed = spy
+    try:
+        y1 = jax.block_until_ready(model.run(x, logits=True))
+        y2 = jax.block_until_ready(model.run(x, logits=True))
+    finally:
+        apimodel.execute_packed = orig
+    assert traces == [2]                  # compiled once, then served
     np.testing.assert_array_equal(np.asarray(y1), np.asarray(y2))
-    # and the serving output equals the models-layer program forward
-    fwd = jax.jit(make_program_forward("alexnet", cfg=CLIP_FREE))
+    fwd = jax.jit(lambda pk, v: execute_packed(pk, v, return_logits=True))
     np.testing.assert_array_equal(np.asarray(y1),
-                                  np.asarray(fwd(params, x)))
+                                  np.asarray(fwd(model.packed, x)))

@@ -7,8 +7,7 @@ a save→load roundtrip of the same model agrees bit-exactly (npz format
 with dynamic stages), and the satellites: the fused epilogue's
 softmax survives ±1e4-magnitude logits (max-subtraction), crossbar
 attention tracks the ``flash_attention`` reference across a seq-len
-sweep within clip-free int8 tolerance, and ``core.workload.WORKLOADS``
-warns as a deprecated shim naming ``api.zoo``.
+sweep within clip-free int8 tolerance.
 
 Also covers: the dynamic-operand GEMM program structure (qk/pv stages,
 empty packed placeholders, runtime-sized mounts), a linear/gelu/
@@ -28,10 +27,10 @@ from repro import api
 from repro.api import HurryConfig, NetworkBuilder
 from repro.api.serialize import VERSION
 from repro.api.zoo import vit_tiny
+from repro.core.crossbar import make_crossbar_matmul
 from repro.kernels import ref
 from repro.kernels.fb_epilogue import fb_epilogue
 from repro.kernels.flash_attention import flash_attention
-from repro.models.cnn import make_crossbar_matmul
 from repro.program.sequence import split_qkv_heads
 
 CLIP_FREE = HurryConfig(array_rows=511)      # DESIGN.md §4 predicate holds
@@ -337,14 +336,3 @@ def test_builder_spatial_residual_rasterizes_into_tokens():
     nb2.attention(4, name="attn", input_from="patch")
     with pytest.raises(ValueError, match="shape"):
         nb2.residual(proj, name="res")
-
-
-# ---------------------------------------------------------------------------
-# satellite: the WORKLOADS registry is a warning compat shim
-# ---------------------------------------------------------------------------
-
-def test_workloads_shim_emits_deprecation_warning():
-    from repro.core.workload import WORKLOADS
-    with pytest.warns(DeprecationWarning, match="api.zoo"):
-        layers = WORKLOADS["alexnet"]()
-    assert layers                       # still serves the zoo graphs

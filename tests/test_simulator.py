@@ -2,7 +2,7 @@
 
 import pytest
 
-from repro.core import WORKLOADS
+from repro.api import GRAPHS
 from repro.core.simulator import simulate_hurry
 from repro.core.baselines import simulate_isaac, simulate_misca
 
@@ -13,7 +13,7 @@ NETS = ("alexnet", "vgg16", "resnet18")
 def reports():
     out = {}
     for net in NETS:
-        layers = WORKLOADS[net]()
+        layers = list(GRAPHS[net]().layers)
         out[net] = {
             "hurry": simulate_hurry(layers),
             "isaac128": simulate_isaac(layers, 128),
