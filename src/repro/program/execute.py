@@ -38,7 +38,8 @@ scheduled program is *the* thing that computes:
   inside the array;
 * sequence stages add steps around that (``compile.py``): a stage
   that reads a class-token pool takes row 0 of each sequence of its
-  source (``select="cls"``); a pre-norm stage layer-normalizes its
+  source (``select="cls"``: rows ``0, T, 2T, ...``); a pre-norm stage
+  layer-normalizes its
   input while it builds the operand, before ``amax`` and the int8
   convert, so LN(x) is never a stage buffer; and a patchify stage's
   ``embed`` prepends the class token to its output tokens and adds the
@@ -66,6 +67,32 @@ the (batch*heads) axis — mirroring the functional oracle's vmapped
 ``mm`` exactly, so per-slice quantization statistics line up and the
 clip-free bit-exactness argument of §5 carries over unchanged.
 
+**Sequence buffers are token rows.**  Inside the executor a sequence
+stage's buffer is the ``(B*T, N)`` row matrix its epilogue kernel
+writes, and each stage that reads it takes it as rows; the graph's
+``(B, T, N)`` shape is a view the executor takes only where a step
+works per image (DESIGN.md §9).  At DeiT-Ti's T = 197, off the TPU's
+8-row tiling, every change between the two shapes is a copy of the
+whole buffer, so XLA is left to place the ones the work needs and no
+others: the patch stage's ``embed`` builds the sequence per image and
+writes it as rows once; a class-token read takes rows ``0, T, 2T,
+...``; a pre-norm takes the per-image view, which XLA lays out with
+the normed axis major so that its pairwise row sums slice whole tiles
+(on the rows they slice lanes, which cost a v5e more than the
+relayout saves; PERF.md §6); attention splits q, k and v from the
+per-image view of the fused projection and writes its merged context
+back as rows.  Two ``optimization_barrier``s fix where the head moves
+happen: K is transposed into Kᵀ in f32, in one pass, before it is
+quantized (else XLA moves Kᵀ as int8, whose packed tiles a v5e
+relayouts slowly), and the merged context is built per image in f32
+before its rows are quantized.  Each step moves data only: the
+values, the scale sets and the order of every float sum are those of
+the oracle.  At the boundary the graph's shapes hold:
+``stage_outputs(feed=...)`` reads the oracle's ``(B, T, N)`` buffers
+as rows, and yields every ``StageOutput.value`` in the graph's shape
+(under a jit only the program's output is live, so those reshapes
+compile away).
+
 Intermediate buffers are dropped as soon as no later stage reads them
 (``src``, ``dyn_src`` or ``res_src``), so an eager forward holds the
 live frontier of the dataflow graph, not every activation.
@@ -87,7 +114,8 @@ program's ``op_name`` metadata says which stage and which phase each
 instruction serves:
 
 * ``im2col`` — im2col (a dense stage's tap concat on the int8
-  tensor), token and flatten reshapes, head splits;
+  tensor), token-row and flatten reshapes, a class-token read, head
+  splits and Kᵀ;
 * ``quantize`` — the activation's amax reduction and int8 convert,
   and inside it ``prenorm``, a pre-norm stage's layer norm of its
   input;
@@ -95,9 +123,9 @@ instruction serves:
   lane pad of an N under 128 and the slice back, and ``plane_pack`` of
   a dynamic stage's right-hand operand;
 * ``gemm`` — the ``mounted_gemm`` kernel;
-* ``epilogue`` — the scale product, the ``fb_epilogue`` kernel and the
-  output reshape, and inside it ``embed``, a patchify stage's class
-  token and position table.
+* ``epilogue`` — the scale product, the ``fb_epilogue`` kernel, a
+  conv's NHWC output reshape and a context's head merge, and inside it
+  ``embed``, a patchify stage's class token and position table.
 
 Scopes are metadata only: they change no op and cost nothing at run
 time.
@@ -123,7 +151,7 @@ from repro.kernels.ops import interpret_default
 
 from .compile import ProgramOp
 from .pack import PackedProgram, PackedStage, plane_pack, stage_layout
-from .sequence import embed_tokens, merge_heads, split_qkv_heads, tokens
+from .sequence import embed_tokens, merge_heads, split_qkv_heads
 
 
 class Kernels(NamedTuple):
@@ -149,8 +177,8 @@ def _last_reads(stages) -> dict[str, int]:
 
 
 def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
-               cfg, *, block_m: int | None, block_n: int | None,
-               interpret: bool, kernels: Kernels
+               cfg, batch: int, *, block_m: int | None,
+               block_n: int | None, interpret: bool, kernels: Kernels
                ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One dynamic-operand GEMM stage (attention Q·Kᵀ or P·V) ->
     (output, int32 GEMM result per batch*head).
@@ -162,11 +190,15 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
     """
     with jax.named_scope("im2col"):
         if gemm.dyn == "qk":
-            q, k, _ = split_qkv_heads(tokens(bufs[gemm.src]), gemm.heads)
-            a, w = q, jnp.swapaxes(k, 1, 2)      # (BH, T, hd), (BH, hd, T)
+            q, k, _ = split_qkv_heads(_tokens(bufs[gemm.src], batch),
+                                      gemm.heads)
+            # Kᵀ moved in f32, in one pass, before it is quantized
+            # (module docstring)
+            a = q                                # (BH, T, hd)
+            w = jax.lax.optimization_barrier(jnp.swapaxes(k, 1, 2))
         elif gemm.dyn == "pv":
             a = bufs[gemm.src]                   # (BH, T, T) probabilities
-            _, _, w = split_qkv_heads(tokens(bufs[gemm.dyn_src]),
+            _, _, w = split_qkv_heads(_tokens(bufs[gemm.dyn_src], batch),
                                       gemm.heads)
         else:  # pragma: no cover - compile_network emits only qk/pv
             raise ValueError(gemm.dyn)
@@ -197,9 +229,16 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
         out = jax.vmap(epilogue)(acc, ascale, wamax)
         if softmax:                    # XLA's softmax (module docstring)
             out = softmax_ordered(out)
-        if gemm.dyn == "pv":                     # heads rejoin the model dim
-            out = merge_heads(out, gemm.heads)
+        if gemm.dyn == "pv":           # heads rejoin the model dim, as rows
+            out = jax.lax.optimization_barrier(merge_heads(out, gemm.heads))
+            out = out.reshape(-1, out.shape[-1])
     return out, acc
+
+
+def _tokens(x: jnp.ndarray, batch: int) -> jnp.ndarray:
+    """A sequence buffer's per-image view ``(B, T, N)``: of its rows, of
+    an NHWC map (tokens row-major, ``sequence.tokens``) or as it is."""
+    return x.reshape(batch, -1, x.shape[-1])
 
 
 def _gemm_rows(x: jnp.ndarray, seq: bool) -> jnp.ndarray:
@@ -242,27 +281,30 @@ def _dense_operand(gemm: ProgramOp, src: jnp.ndarray, bits: int
 
 
 def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
-                  st: PackedStage, bufs: Mapping, cfg, *,
+                  st: PackedStage, bufs: Mapping, cfg, batch: int, *,
                   block_m: int | None, block_n: int | None, interpret: bool,
                   drop_softmax: bool, kernels: Kernels
-                  ) -> tuple[str, jnp.ndarray, jnp.ndarray]:
+                  ) -> tuple[str, jnp.ndarray, jnp.ndarray, bool]:
     """One weight-mounted GEMM stage + fused epilogue -> (dst, buffer,
-    int32 GEMM result)."""
+    int32 GEMM result, whether the buffer is sequence rows)."""
     src = bufs[gemm.src]
-    b = src.shape[0]
     t = 0
     if gemm.seq or gemm.select:
         with jax.named_scope("im2col"):
-            src = tokens(src)
-            if gemm.select == "cls":         # the class token, row 0
-                src = src[:, 0]
-        t = src.shape[1] if gemm.seq else 0
+            src = _gemm_rows(src, True)      # (B*T, D) token rows
+            t = src.shape[0] // batch
+            if gemm.select == "cls":         # the class token: row b*T
+                src = src[::t]
     if gemm.prenorm:
         if not gemm.seq:
             with jax.named_scope("im2col"):
                 src = _gemm_rows(src, False)
         with jax.named_scope("quantize"), jax.named_scope("prenorm"):
-            src = layer_norm_ordered(src, st.pre_g, st.pre_b, gemm.eps)
+            # tokens per image, where XLA lays the normed axis out
+            # major (module docstring)
+            x = _tokens(src, batch) if gemm.seq else src
+            src = layer_norm_ordered(x, st.pre_g, st.pre_b,
+                                     gemm.eps).reshape(src.shape)
     layout = stage_layout(gemm, cfg)
     if layout == "dense":
         xq, xs = _dense_operand(gemm, src, cfg.input_bits)
@@ -321,16 +363,18 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
                                softmax=softmax, norm=norm, gamma=st.ln_g,
                                beta=st.ln_b, eps=eps, block_m=block_m,
                                block_n=block_n, interpret=interpret)
-        if gemm.is_conv:
-            out = out.reshape(b, out_hw, out_hw, -1)
-        elif gemm.seq and pool != "seqmean":
-            out = out.reshape(b, t, -1)
+        # a sequence stage's (B*T, N) output is its buffer as it is
+        rows = embed or (gemm.seq and pool != "seqmean")
+        if gemm.is_conv and not embed:
+            out = out.reshape(batch, out_hw, out_hw, -1)
         if erf_gelu:
             out = gelu_erf(out)
-        if embed:
+        if embed:          # the one place a sequence is built per image
             with jax.named_scope("embed"):
-                out = embed_tokens(tokens(out), st.emb_cls, st.emb_pos)
-    return dst, out, y_int
+                d = out.shape[-1]
+                out = embed_tokens(_tokens(out, batch), st.emb_cls,
+                                   st.emb_pos).reshape(-1, d)
+    return dst, out, y_int, rows
 
 
 class StageOutput(NamedTuple):
@@ -367,6 +411,7 @@ def stage_outputs(packed: PackedProgram, x: jnp.ndarray, *,
         kernels = Kernels(mounted_gemm, fb_epilogue)
     program = packed.program
     cfg = program.cfg
+    batch = x.shape[0]
     bufs: dict[str, jnp.ndarray] = {program.input: x}
     src = bufs if feed is None else feed
     stages = program.stages()
@@ -375,17 +420,20 @@ def stage_outputs(packed: PackedProgram, x: jnp.ndarray, *,
         dst = posts[-1].dst if posts else gemm.dst
         with jax.named_scope(f"s{si:02d}.{dst.replace('@', '.')}"):
             if gemm.kind == "dyn_gemm":
-                out, acc = _dyn_stage(gemm, posts, src, cfg,
+                out, acc = _dyn_stage(gemm, posts, src, cfg, batch,
                                       block_m=block_m, block_n=block_n,
                                       interpret=interpret, kernels=kernels)
+                rows = gemm.dyn == "pv"
             else:
-                dst, out, acc = _static_stage(
-                    gemm, posts, st, src, cfg, block_m=block_m,
+                dst, out, acc, rows = _static_stage(
+                    gemm, posts, st, src, cfg, batch, block_m=block_m,
                     block_n=block_n, interpret=interpret,
                     drop_softmax=return_logits and si == len(stages) - 1,
                     kernels=kernels)
         bufs[dst] = out
-        yield StageOutput(dst, out, acc)
+        # the graph's (B, T, N) buffer; under a jit only the program's
+        # output is live, so the other reshapes compile away
+        yield StageOutput(dst, _tokens(out, batch) if rows else out, acc)
         # drop buffers no later stage reads: eager forwards hold only
         # the live dataflow frontier
         for name in [n for n, li in last.items() if li <= si]:

@@ -26,6 +26,7 @@ from repro.api.zoo import deit_graph, vit_tiny_graph
 from repro.core.crossbar import make_crossbar_matmul
 from repro.kernels import ref
 from repro.kernels.fb_epilogue import fb_epilogue, gelu_erf
+from repro.program.execute import stage_outputs
 
 from bench import program_trace as pt
 
@@ -317,3 +318,82 @@ def test_layer_norm_epsilon_reaches_the_kernel(eps):
                           beta=b, interpret=True)
     assert np.array_equal(np.asarray(out), np.asarray(default)) == (
         eps == 1e-5)
+
+
+# ---------------------------------------------------------------------------
+# sequence buffers held as (B*T, N) rows inside the executor
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("net", ["deit_ti", "vit_tiny"])
+def test_depth2_logits_bit_identical_to_the_oracle(net):
+    """DeiT-Ti at 224x224 (197 tokens, off the 8-row tiling) and
+    vit_tiny (64 tokens), two blocks each, batch 3: with every sequence
+    buffer held as (B*T, N) rows, the pre-norms and head splits on their
+    per-image views and Kᵀ and the merged context moved behind their
+    barriers, the program's logits and probabilities equal the jitted
+    oracle's bit for bit."""
+    graph = (deit_graph if net == "deit_ti" else vit_tiny_graph)(depth=2)
+    model = api.compile(graph, CLIP_FREE,
+                        params=smoke.random_params(graph, 0))
+    x = jax.random.normal(jax.random.PRNGKey(1), graph.input_shape(3))
+    for logits in (True, False):
+        np.testing.assert_array_equal(
+            np.asarray(model.run(x, logits=logits)),
+            np.asarray(_oracle(model, x, logits)))
+
+
+def test_stage_outputs_fed_the_oracle_match_it_stage_by_stage(deit):
+    """``stage_outputs(feed=...)`` takes the oracle's (B, T, N) buffers
+    and yields every stage's value in the oracle's shape: each stage,
+    fed the oracle's inputs, lands within ``FB_TAIL_ULP`` of the
+    oracle's buffer (softmax tails within their ``tail_bound``), and
+    its int32 GEMM equals an XLA integer dot's."""
+    model, x = deit
+    oracle = smoke.oracle_buffers(model.graph, CLIP_FREE, model.params, x)
+    feed = {name.replace(".", "@"): v for name, v in oracle.items()}
+    got = smoke.run_stages(model, x, feed=feed)
+    want = smoke.run_stages(model, x, feed=feed, kernels=smoke.Kernels(
+        smoke.xla_gemm, smoke.xla_epilogue))
+    stages = model.program.stages()
+    assert [name for name, _, _ in got] == [
+        posts[-1].dst if posts else g.dst for g, posts in stages]
+    for (name, value, acc), (_, _, ref_acc), (_, posts) in zip(
+            got, want, stages):
+        buf = oracle[smoke.oracle_name(name)]
+        assert value.shape == buf.shape, name
+        np.testing.assert_array_equal(np.asarray(acc), np.asarray(ref_acc))
+        softmax = any(op.kind == "softmax" for op in posts)
+        bound = smoke.tail_bound(buf) if softmax else smoke.FB_TAIL_ULP
+        assert smoke.ulp_error(value, buf) <= bound, name
+
+
+@pytest.mark.parametrize("batch", [3, 4])
+def test_class_token_select_reads_row_b_times_t(deit, batch):
+    """The head reads image b's class token, row b*T of the (B*T, D)
+    rows.  Fed the oracle's last block output, its logits are the
+    oracle's within ``FB_TAIL_ULP``, argmax for argmax; fed the same
+    buffer with the images in reverse order, they come out reversed bit
+    for bit.  A stride off by one reads other tokens and fails both."""
+    model, _ = deit
+    x = jax.random.normal(jax.random.PRNGKey(batch),
+                          model.graph.input_shape(batch))
+    oracle = smoke.oracle_buffers(model.graph, CLIP_FREE, model.params, x)
+    head, _ = model.program.stages()[-1]
+    assert head.select == "cls"
+    res = oracle[head.src]
+    assert res.shape[0] == batch and res.shape[1] % 8
+
+    feed = {name.replace(".", "@"): v for name, v in oracle.items()}
+
+    def logits(buf):
+        fed = dict(feed, **{head.src: buf})
+        return np.asarray(jax.jit(lambda pk, v, f: list(stage_outputs(
+            pk, v, feed=f, return_logits=True))[-1].value)(
+                model.packed, x, fed))
+
+    got = logits(res)
+    want = np.asarray(oracle[head.dst])
+    assert got.shape == want.shape == (batch, SMALL["classes"])
+    assert smoke.ulp_error(got, want) <= smoke.FB_TAIL_ULP
+    np.testing.assert_array_equal(got.argmax(-1), want.argmax(-1))
+    np.testing.assert_array_equal(logits(res[::-1]), got[::-1])

@@ -16,6 +16,7 @@ may load it.  All such tests live in this one file for that reason.
 """
 
 import importlib.util
+import math
 import os
 import pathlib
 import re
@@ -208,3 +209,53 @@ def test_deit_linear_stages_run_edge_blocks_unpadded(whole_program):
                               ("s09.b1_attn.ctx", "vmap(mounted_gemm", "pad")]
     assert all(shape.startswith("s8[") and ",128]" in shape
                for shape, *_ in ops)
+
+
+def _relayouts(text: str, dtype: str, tokens: int) -> list[tuple]:
+    """The ``reshape``s and ``copy``s of ``dtype`` in the entry
+    computation whose result or operand has an axis that is a multiple
+    of ``tokens`` (a sequence's T, or B*T rows) -> [(op, shape,
+    elements, op_name)]."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}\n")]
+    inst = re.compile(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* "
+                      r"([\w-]+)\(%?([\w.-]*)")
+    dims, found = {}, []
+    for line in entry.splitlines()[1:]:
+        m = inst.match(line)
+        if not m:
+            continue
+        name, dt, shape, op, arg = m.groups()
+        dims[name] = [int(d) for d in shape.split(",") if d]
+        if dt != dtype or op not in ("reshape", "copy"):
+            continue
+        if any(d % tokens == 0 for d in dims[name] + dims.get(arg, [])):
+            scope = re.search(r'op_name="([^"]*)"', line)
+            found.append((op, tuple(dims[name]), math.prod(dims[name]),
+                          scope.group(1) if scope else ""))
+    return found
+
+
+def test_deit_sequence_buffers_stay_token_rows(whole_program):
+    """DeiT's sequence stage buffers stay (B*T, N) rows from stage to
+    stage (T = 197, off the 8-row tiling, so a (B*T, N) <-> (B, T, N)
+    reshape is a copy of the whole buffer): no f32 relayout on the
+    token axis sits in a static sequence stage's ``epilogue/reshape``
+    scope.  Kᵀ is moved in f32 before its quantize, so no int8 Kᵀ
+    (B*H, hd, T) is relaid, nor an int8 context in heads order
+    (B, H, T, hd); and the f32 token-axis relayouts left, those the
+    pre-norms, the head splits and the softmax sums ask XLA for, are at
+    most 38 instructions writing 7.350 MB at batch 2 (PERF.md §5)."""
+    model, text = whole_program("deit_ti_depth2")
+    static_seq = {f"s{si:02d}.{(posts[-1].dst if posts else g.dst)}"
+                  .replace("@", ".")
+                  for si, (g, posts) in enumerate(model.program.stages())
+                  if g.seq and g.kind != "dyn_gemm"}
+    assert len(static_seq) == 8
+    ops = _relayouts(text, "f32", 197)
+    assert not [o for o in ops for stage in static_seq
+                if f"/{stage}/epilogue/reshape" in o[3]]
+    assert not [o for o in _relayouts(text, "s8", 197)
+                if o[1] in ((6, 64, 197), (2, 3, 197, 64))]
+    assert len(ops) <= 38
+    assert sum(4 * n for _, _, n, _ in ops) <= 7.351e6
