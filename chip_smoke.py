@@ -7,8 +7,10 @@ One process, one chip.  It drives what a user calls —
 ``api.compile(graph, HurryConfig) -> CompiledModel.run`` — for every
 network of the zoo at its full geometry: ``alexnet``, ``vgg16`` and
 ``resnet18`` at 32x32, ``vit_tiny`` at depth 12 (dim 192, 3 heads, MLP
-ratio 4, 64 tokens), and ``deit_ti`` (DeiT-Ti at 224x224: pre-norm
-blocks, 197 tokens with the class token, erf GELU).  ``--nets`` keeps
+ratio 4, 64 tokens), ``deit_ti`` (DeiT-Ti at 224x224: pre-norm
+blocks, 197 tokens with the class token, erf GELU) and ``swin_t``
+(Swin-T at 224x224: shifted windows, patch merging, served at batch 2).
+``--nets`` keeps
 the networks named (the kernel phase always runs).  Weights are random
 from a fixed seed.  Phases:
 
@@ -79,7 +81,9 @@ CLIP_FREE = HurryConfig(array_rows=511)      # every mount clip-free (§4)
 # kernel (kernels/crossbar_gemm.py::clip_possible)
 SLICED = HurryConfig(adc_bits=8)
 NETS = (("alexnet", 0), ("vgg16", 0), ("resnet18", 0), ("vit_tiny", 12),
-        ("deit_ti", 12))
+        ("deit_ti", 12), ("swin_t", 0))
+# request batches of a network that is not served at ``BATCHES``
+NET_BATCHES = {"swin_t": (2,)}
 SLICED_NETS = (("alexnet", 0), ("vit_tiny", 12))
 BATCHES = (1, 3, 8)
 STEADY_RUNS = 5
@@ -102,35 +106,33 @@ GEMM_CASES = (
     ("deit_qkv_b64", 64 * 197, 192, 576, 485),
     ("deit_fc2_b64", 64 * 197, 768, 192, 485),
 )
-# fb_epilogue modes (name, M, N, kwargs, residual?, layer norm?)
+# fb_epilogue modes (name, M, N, kwargs, residual?)
 EPILOGUE_CASES = (
-    ("plain_n1024", 8, 1024, dict(act="relu"), False, False),
+    ("plain_n1024", 8, 1024, dict(act="relu"), False),
     ("maxpool_32to16", 8 * 1024, 64, dict(act="relu", pool="max", window=2,
-                                          img_hw=32), False, False),
+                                          img_hw=32), False),
     ("maxpool_8to4", 8 * 64, 256, dict(act="relu", pool="max", window=2,
-                                       img_hw=8), False, False),
+                                       img_hw=8), False),
     ("maxpool_4to2", 8 * 16, 512, dict(act="relu", pool="max", window=2,
-                                       img_hw=4), False, False),
+                                       img_hw=4), False),
     ("maxpool_2to1", 8 * 4, 512, dict(act="relu", pool="max", window=2,
-                                      img_hw=2), False, False),
+                                      img_hw=2), False),
     ("avgpool_4x4", 8 * 16, 512, dict(act="relu", pool="avg", window=4,
-                                      img_hw=4), True, False),
-    ("seqmean_t64", 8 * 64, 192, dict(pool="seqmean", window=64,
-                                      norm="layer"), True, True),
-    ("layernorm_n192", 8 * 64, 192, dict(norm="layer"), True, True),
-    ("gelu_n768", 8 * 64, 768, dict(act="gelu"), False, False),
-    ("dequant_t197_n768", 8 * 197, 768, dict(), False, False),
-    ("layernorm_eps1e-6_n192", 8 * 197, 192, dict(norm="layer", eps=1e-6),
-     True, True),
-    ("softmax_n10", 8, 10, dict(softmax=True), False, False),
-    ("scores_t64", 64, 64, dict(softmax=True, post_scale=0.125), False,
-     False),
-    ("scores_t197", 197, 197, dict(softmax=True, post_scale=0.125), False,
-     False),
+                                      img_hw=4), True),
+    ("gelu_n768", 8 * 64, 768, dict(act="gelu"), False),
+    ("dequant_t197_n768", 8 * 197, 768, dict(), False),
+    ("softmax_n10", 8, 10, dict(softmax=True), False),
+    ("scores_t64", 64, 64, dict(softmax=True, post_scale=0.125), False),
+    ("scores_t197", 197, 197, dict(softmax=True, post_scale=0.125), False),
+    # Swin-T at batch 64: a windowed scores mount (7x7 tokens, head dim
+    # 32), the patch stage and fc1 of stage 1 before their XLA tails
+    # (200,704 token rows), and fc2 of stage 4 with its residual
+    ("scores_m49", 49, 49, dict(post_scale=32 ** -0.5), False),
+    ("swin_patch_b64", 64 * 3136, 96, dict(), False),
+    ("swin_fc1_b64", 64 * 3136, 384, dict(), False),
+    ("swin_fc2_res_s4_b64", 64 * 49, 768, dict(), True),
     # DeiT-Ti at batch 64: 12,608 rows end in an edge row block
-    ("deit_fc2_res_b64", 64 * 197, 192, dict(), True, False),
-    ("deit_layernorm_b64", 64 * 197, 192, dict(norm="layer", eps=1e-6),
-     True, True),
+    ("deit_fc2_res_b64", 64 * 197, 192, dict(), True),
 )
 
 
@@ -165,6 +167,7 @@ def ulp_error(a, b) -> float:
 
 
 def graph_of(net: str, depth: int):
+    """The zoo's network, cut to ``depth`` blocks where it takes one."""
     if net in ("vit_tiny", "deit_ti"):
         return GRAPHS[net](depth=depth)
     return GRAPHS[net]()
@@ -332,20 +335,15 @@ def kernel_phase(gemm_cases=GEMM_CASES, epilogue_cases=EPILOGUE_CASES,
         check(checks, f"gemm/{name}/sliced", np.array_equal(y, yr),
               "7-bit ADC, per-mount clipping vs crossbar_gemm_ref")
     f32 = jnp.float32
-    for name, m, n, kw, has_res, has_ln in epilogue_cases:
-        ks = jax.random.split(jax.random.fold_in(key, m * n), 5)
+    for name, m, n, kw, has_res in epilogue_cases:
+        ks = jax.random.split(jax.random.fold_in(key, m * n), 3)
         y = jax.random.randint(ks[0], (m, n), -20000, 20000, jnp.int32)
         scale = jnp.full((1, 1), 3e-4, f32)
         bias = jax.random.normal(ks[1], (n,), f32)
         res = jax.random.normal(ks[2], (m, n), f32) if has_res else None
-        ln = {}
-        if has_ln:
-            ln = dict(gamma=1 + 0.1 * jax.random.normal(ks[3], (n,), f32),
-                      beta=0.1 * jax.random.normal(ks[4], (n,), f32))
-        out = fb_epilogue(y, scale, bias, res, interpret=interpret, **kw,
-                          **ln)
-        want = jax.jit(lambda *a, kw=kw, ln=ln: ref.fb_epilogue_ref(
-            *a, **kw, **ln))(y, scale, bias, res)
+        out = fb_epilogue(y, scale, bias, res, interpret=interpret, **kw)
+        want = jax.jit(lambda *a, kw=kw: ref.fb_epilogue_ref(*a, **kw))(
+            y, scale, bias, res)
         e = ulp_error(out, want)
         check(checks, f"epilogue/{name}", e <= FB_TAIL_ULP,
               f"vs fb_epilogue_ref: normwise {e:.3g} ulp (bound "
@@ -506,9 +504,10 @@ def main(argv=()) -> int:
     for net, depth in NETS:
         if net not in nets:
             continue
-        model, c = serve_phase(net, depth)
+        model, c = serve_phase(net, depth,
+                               batches=NET_BATCHES.get(net, BATCHES))
         checks += c + compiled_phase(model)
-        if net in ("alexnet", "deit_ti"):
+        if net in ("alexnet", "deit_ti", "swin_t"):
             checks += save_load_phase(model)
     for net, depth in SLICED_NETS:
         if net in nets:

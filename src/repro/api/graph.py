@@ -28,6 +28,17 @@ blocks).  Pre-norm blocks (``x + f(LN(x))``) put
 ``attention`` or ``fc`` normalizes its input, and residuals that read
 that input still read it un-normed.
 
+Hierarchical, windowed transformers (Swin) work on a square grid of
+raster tokens, whose side the builder tracks through every sequence
+shape (a class token leaves a sequence without one): a
+``nb.layernorm()`` may follow a patchify conv (the patch norm), and its
+tokens keep the map's grid; ``nb.attention(heads, window=M, shift=s)``
+attends inside each ``M x M`` window of the map rolled by ``-s``, with
+a learned relative position bias per head and the shift's region mask;
+``nb.linear(features, merge=True, bias=False)`` is patch merging: its
+operand is the 2x2 space-to-depth of the map (half the grid side, four
+times the width), which a ``layernorm(pre=True)`` before it normalizes.
+
 The resulting ``NetworkGraph`` is the one source of truth for layer
 shapes: the scheduler consumes its ``LayerSpec`` list, ``init_params``
 derives the parameter pytree from it, and ``forward`` is a generic
@@ -52,9 +63,11 @@ from repro.core.crossbar import fp_matmul
 from repro.core.workload import (GEMM_KINDS, LayerSpec, POST_RANK,
                                  input_spec, layer_groups)
 from repro.kernels.fb_epilogue import (gelu, gelu_erf, layer_norm_ordered,
-                                       layer_norm_rows, softmax_ordered)
+                                       softmax_ordered, token_mean)
 from repro.program.sequence import (attn_scale, embed_tokens, merge_heads,
-                                    split_qkv_heads, tokens)
+                                    rel_bias, shift_mask, space_to_depth,
+                                    split_qkv_heads, tokens, window_bias,
+                                    window_merge, window_qkv_heads)
 
 # shapes are ("spatial", hw, ch) until an fc flattens to ("flat", features)
 # or a sequence op rasterizes to ("seq", tokens, dim); T == 0 marks a
@@ -116,7 +129,9 @@ class NetworkGraph:
                 w = jax.random.normal(
                     k, (l.features_in, l.features_out)
                 ) * jnp.sqrt(2.0 / l.features_in)
-                params[l.name] = {"w": w, "b": jnp.zeros((l.features_out,))}
+                params[l.name] = {"w": w}
+                if l.bias:
+                    params[l.name]["b"] = jnp.zeros((l.features_out,))
             elif l.kind == "attention":
                 d = l.features_in
                 k1, k2 = jax.random.split(k)
@@ -127,6 +142,10 @@ class NetworkGraph:
                     "wo": jax.random.normal(k2, (d, d)) * jnp.sqrt(2.0 / d),
                     "bo": jnp.zeros((d,)),
                 }
+                if l.window:     # Swin's init: std 0.02
+                    params[l.name]["relb"] = 0.02 * jax.random.normal(
+                        jax.random.fold_in(k, 2),
+                        ((2 * l.window - 1) ** 2, l.heads))
             elif l.kind == "layernorm":
                 params[l.name] = {"g": jnp.ones((l.features_out,)),
                                   "b": jnp.zeros((l.features_out,))}
@@ -166,8 +185,9 @@ class NetworkGraph:
         The oracle's dataflow laid bare, for setting it beside another
         implementation stage by stage.  An attention layer also records
         its inner results as ``<layer>.qkv`` (fused projection,
-        ``(B, T, 3D)``), ``<layer>.probs`` (per batch*head softmax) and
-        ``<layer>.ctx`` (merged context).  A GEMM head's pre-norm is
+        ``(B, T, 3D)``), ``<layer>.probs`` (per batch*head softmax; per
+        image*window*head for a windowed one) and ``<layer>.ctx``
+        (merged context, raster tokens).  A GEMM head's pre-norm is
         part of its layer (no buffer of its own).  Arguments as
         ``forward``.
         """
@@ -175,11 +195,14 @@ class NetworkGraph:
         cur = "input"
 
         def gemm_input(l):
-            """A linear/attention/fc layer's input: tokens, or a flat
-            row per image for fc; layer-normed under ``prenorm``."""
+            """A linear/attention/fc layer's input: tokens (their 2x2
+            space-to-depth for a merge), or a flat row per image for fc;
+            layer-normed under ``prenorm``."""
             src = bufs[l.input_from or cur]
             if l.kind != "fc":
                 src = tokens(src)
+                if l.merge:
+                    src = space_to_depth(src, l.in_hw)
             elif src.ndim == 4:
                 src = src.reshape(src.shape[0], -1)
             if l.prenorm:
@@ -200,18 +223,32 @@ class NetworkGraph:
                 src = gemm_input(l)
                 b, t, d = src.shape
                 p = params[l.name]
-                y = (mm(src.reshape(b * t, d), p["w"])
-                     + p["b"]).reshape(b, t, -1)
+                y = mm(src.reshape(b * t, d), p["w"])
+                if l.bias:
+                    y = y + p["b"]
+                y = y.reshape(b, t, -1)
             elif l.kind == "attention":
                 src = gemm_input(l)
                 b, t, d = src.shape
                 p = params[l.name]
                 qkv = mm(src.reshape(b * t, d), p["wqkv"]) + p["bqkv"]
-                q, kk, v = split_qkv_heads(qkv.reshape(b, t, 3 * d),
-                                           l.heads)
+                if l.window:
+                    win = (l.heads, l.in_hw, l.window, l.shift)
+                    q, kk, v = window_qkv_heads(qkv.reshape(b, t, 3 * d),
+                                                *win)
+                else:
+                    q, kk, v = split_qkv_heads(qkv.reshape(b, t, 3 * d),
+                                               l.heads)
                 scores = jax.vmap(lambda a, w: mm(a, w.T))(q, kk)
-                probs = softmax_ordered(scores * attn_scale(d // l.heads))
-                ctx = merge_heads(jax.vmap(mm)(probs, v), l.heads)
+                scores = scores * attn_scale(d // l.heads)
+                if l.window:
+                    scores = window_bias(
+                        scores, rel_bias(p["relb"], l.window),
+                        shift_mask(l.in_hw, l.window, l.shift))
+                probs = softmax_ordered(scores)
+                ctx = jax.vmap(mm)(probs, v)
+                ctx = (window_merge(ctx, *win) if l.window
+                       else merge_heads(ctx, l.heads))
                 y = (mm(ctx.reshape(b * t, d), p["wo"])
                      + p["bo"]).reshape(b, t, d)
                 bufs[f"{l.name}.qkv"] = qkv.reshape(b, t, 3 * d)
@@ -223,8 +260,8 @@ class NetworkGraph:
                 y = (gelu_erf if l.approx == "erf" else gelu)(bufs[cur])
             elif l.kind == "layernorm":
                 p = params[l.name]
-                y = layer_norm_rows(tokens(bufs[cur]), p["g"], p["b"],
-                                    l.eps)
+                y = layer_norm_ordered(tokens(bufs[cur]), p["g"], p["b"],
+                                       l.eps)
             elif l.kind == "embed":
                 p = params[l.name]
                 y = embed_tokens(tokens(bufs[cur]), p["cls"], p["pos"])
@@ -237,7 +274,7 @@ class NetworkGraph:
                               w_ // l.ksize, l.ksize, c).mean(axis=(2, 4))
             elif l.kind == "seqpool":
                 v = tokens(bufs[cur])
-                y = v[:, 0] if l.mode == "cls" else v.mean(axis=1)
+                y = v[:, 0] if l.mode == "cls" else token_mean(v)
             elif l.kind == "residual":
                 a = bufs[cur]
                 r = bufs[l.residual_from]
@@ -296,6 +333,7 @@ class NetworkBuilder:
             "input": ((_SEQ, 0, input_seq_dim) if input_seq_dim
                       else (_SPATIAL, input_hw, input_ch))}
         self._cur = "input"
+        self._grids: dict[str, int] = {}   # token buffer -> grid side
         self._finals = {"input"}      # materialized group-final buffers
         self._counts: dict[str, int] = {}
         self._has_gemm = False
@@ -324,6 +362,15 @@ class NetworkBuilder:
                 f"{name}: needs a {want} input, but {src!r} produces "
                 f"{shape[0]} output {shape[1:]}")
         return shape
+
+    def _grid(self, name: str) -> int:
+        """Side of the square grid of ``name``'s raster tokens: a map's
+        own side, a token buffer's as tracked, 0 without one (a class
+        token, flat features, a run-time sequence)."""
+        shape = self._shapes[name]
+        if shape[0] == _SPATIAL:
+            return shape[1]
+        return self._grids.get(name, 0) if shape[0] == _SEQ else 0
 
     def _require_gemm(self, name: str, kind: str) -> None:
         if not self._has_gemm:
@@ -375,9 +422,14 @@ class NetworkBuilder:
         self._prenorm = None
         return {"prenorm": norm, "eps": eps}
 
-    def _add(self, spec: LayerSpec, shape: tuple) -> str:
+    def _add(self, spec: LayerSpec, shape: tuple,
+             grid: int | None = None) -> str:
+        """Append ``spec``; its tokens' ``grid`` side defaults to the
+        current buffer's (post-ops keep the map)."""
         if self._prenorm is not None:       # a GEMM head took it already
             self._take_prenorm(spec.name, spec.kind)
+        self._grids[spec.name] = self._grid(self._cur) if grid is None \
+            else grid
         self._layers.append(spec)
         self._shapes[spec.name] = shape
         self._cur = spec.name
@@ -416,25 +468,48 @@ class NetworkBuilder:
                       **self._take_prenorm(name, "fc")),
             (_FLAT, features_out))
 
-    def linear(self, features_out: int, *, name: str | None = None,
+    def linear(self, features_out: int, *, merge: bool = False,
+               bias: bool = True, name: str | None = None,
                input_from: str = "") -> str:
-        """Sequence GEMM: (T, D) -> (T, features_out), tokens in M."""
+        """Sequence GEMM: (T, D) -> (T, features_out), tokens in M.
+
+        ``merge=True`` is patch merging: the operand is the 2x2
+        space-to-depth of the ``g x g`` token grid, (T/4, 4D), and the
+        output grid is ``g/2``.  ``bias=False`` drops the bias.
+        """
         name = self._name("linear", name)
         src = self._open_group(name, input_from, "linear")
         _, t, d = self._src_shape(name, src, _SEQ)
+        grid, in_hw = self._grid(src), 0
+        if merge:
+            if not grid or grid % 2:
+                raise ValueError(
+                    f"{name}: patch merging needs an even square token "
+                    f"grid; {src!r} has "
+                    + (f"a {grid}x{grid} one" if grid else "none"))
+            in_hw, t, d, grid = grid, t // 4, 4 * d, grid // 2
         return self._add(
             LayerSpec(name, "linear", features_in=d,
                       features_out=features_out, input_from=input_from,
+                      in_hw=in_hw, merge=merge, bias=bias,
                       **self._take_prenorm(name, "linear")),
-            (_SEQ, t, features_out))
+            (_SEQ, t, features_out), grid)
 
-    def attention(self, heads: int, *, name: str | None = None,
-                  input_from: str = "") -> str:
+    def attention(self, heads: int, *, window: int = 0, shift: int = 0,
+                  name: str | None = None, input_from: str = "") -> str:
         """Multi-head self-attention over the token buffer, (T, D)->(T, D).
 
         One builder op; the program compiler expands it into the fused
         qkv projection, the two dynamic-operand GEMM stages (Q·Kᵀ with a
         fused softmax FB, P·V), and the output projection (DESIGN.md §9).
+
+        ``window=M`` attends inside each ``M x M`` window of the square
+        token grid instead (Swin's W-MSA), with a learned relative
+        position bias of ``(2M-1)^2 x heads``; ``shift=s`` rolls the
+        map by ``-s`` first and masks pairs of tokens from different
+        regions (SW-MSA).  The window must tile a grid without a class
+        token, and ``0 <= shift < window``; a window as large as the
+        grid is one window, which takes no shift (as published).
         """
         name = self._name("attention", name)
         src = self._open_group(name, input_from, "attention")
@@ -442,11 +517,28 @@ class NetworkBuilder:
         if heads < 1 or d % heads:
             raise ValueError(
                 f"{name}: {heads} heads do not divide model dim {d}")
+        grid = self._grid(src)
+        if window:
+            if not grid:
+                raise ValueError(
+                    f"{name}: windowed attention needs a square token grid "
+                    f"without a class token; {src!r} has none")
+            if window < 1 or grid % window:
+                raise ValueError(f"{name}: a {window}x{window} window does "
+                                 f"not tile the {grid}x{grid} token grid")
+            if not 0 <= shift < window:
+                raise ValueError(f"{name}: shift {shift} is not in "
+                                 f"[0, {window})")
+            if window == grid:
+                shift = 0
+        elif shift:
+            raise ValueError(f"{name}: a shift needs a window")
         return self._add(
             LayerSpec(name, "attention", features_in=d, features_out=d,
                       heads=heads, input_from=input_from,
-                      **self._take_prenorm(name, "attention")),
-            (_SEQ, t, d))
+                      in_hw=grid if window else 0, window=window,
+                      shift=shift, **self._take_prenorm(name, "attention")),
+            (_SEQ, t, d), grid)
 
     def relu(self, *, name: str | None = None) -> str:
         name = self._name("relu", name)
@@ -476,7 +568,9 @@ class NetworkBuilder:
         """Layer norm over the feature axis, with epsilon ``eps``.
 
         By default an FB post-op of the current group's token buffer
-        (post-norm).  ``pre=True`` normalizes the input of the next
+        (post-norm), or of a patchify conv's map, whose tokens it
+        writes (the patch norm).  ``pre=True`` normalizes the input of
+        the next
         ``linear``, ``attention`` or ``fc`` op instead (pre-norm): it
         adds no layer and no buffer, the GEMM head carries it, and a
         residual that reads the same input reads it un-normed.
@@ -489,7 +583,9 @@ class NetworkBuilder:
             self._shapes[name] = self._shapes[self._cur]   # name taken
             self._prenorm = (name, eps)
             return name
-        self._require_seq_head(name, "layernorm")
+        self._require_gemm(name, "layernorm")
+        if self._head_kind != "conv":
+            self._require_seq_head(name, "layernorm")
         shape = self._src_shape(name, self._cur, _SEQ)
         return self._add(
             LayerSpec(name, "layernorm", features_out=shape[2], eps=eps),
@@ -522,7 +618,7 @@ class NetworkBuilder:
         _, hw, ch = self._src_shape(name, self._cur, _SPATIAL)
         return self._add(
             LayerSpec(name, "embed", in_hw=hw, out_ch=ch, features_out=ch),
-            (_SEQ, hw * hw + 1, ch))
+            (_SEQ, hw * hw + 1, ch), 0)
 
     def _pool(self, kind: str, k: int, stride: int,
               name: str | None) -> str:

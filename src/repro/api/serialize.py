@@ -1,6 +1,6 @@
 """Persist compiled models: ``CompiledModel.save`` / ``api.load``.
 
-Format (single ``.npz`` file, version 6):
+Format (single ``.npz`` file, version 7):
 
 * ``__meta__`` — a JSON document holding the graph (name, input spec,
   ``LayerSpec`` list), the ``HurryConfig``, the batch-bucket ladder,
@@ -21,6 +21,8 @@ Format (single ``.npz`` file, version 6):
 * ``wpg{i}/wpb{i}`` and ``wec{i}/wep{i}`` — (version 6) a stage's
   pre-norm gamma/beta (meta ``pre_stages``) and its ``embed`` FB's
   class token and position table (meta ``embed_stages``).
+* ``wrb{i}`` — (version 7) a windowed Q·Kᵀ stage's relative position
+  bias, gathered to ``(heads, M², M²)`` (meta ``bias_stages``).
 
 Version 3 extends version 2 for graphs containing **dynamic-operand
 stages** (attention, DESIGN.md §9): sequence fields ride on the graph /
@@ -58,6 +60,18 @@ per-stage arrays above, and the new fields of the graph's layers
 have none of them and load with their defaults, which are the meaning
 those files had (no pre-norm, LN epsilon 1e-5, tanh GELU, mean pool).
 
+Version 7 adds what windowed, hierarchical transformers need (Swin):
+the optional per-stage bias arrays above (the ``(2M-1)² x heads``
+tables themselves ride with the parameters), the new fields of the
+graph's layers (``window``, ``shift``, ``merge``, ``bias``) and of the
+program's ops (``merge``, ``shift``; a windowed dynamic stage's
+``window`` and ``in_hw``, a bias-free stage's empty ``b_key``), and a
+merge stage's bias-free planes.  Files of versions 2-6 have none of
+them and load with their defaults (global attention, no merge, a
+bias on every GEMM).  Version 7 also reads a mean pool as a ``select``
+of the head after it, where versions 2-6 stored a ``seqpool`` op on
+the stage before: such an op becomes that select at load.
+
 Version-1 files (pre-packing) still load: the packed planes are
 re-derived once from the saved params at load time (repack fallback).
 Version-2/3/4 files load without requantizing: each plane is cut back
@@ -87,13 +101,14 @@ from .config import HurryConfig
 from .graph import NetworkGraph
 
 FORMAT = "repro.api/compiled-model"
-VERSION = 6
-_LOADABLE = (1, 2, 3, 4, 5, 6)
+VERSION = 7
+_LOADABLE = (1, 2, 3, 4, 5, 6, 7)
 # meta key listing the stages that hold them -> (PackedStage field,
 # array prefix) of each optional per-stage array
 _OPTIONAL = {"ln_stages": (("ln_g", "wg"), ("ln_b", "wh")),
              "pre_stages": (("pre_g", "wpg"), ("pre_b", "wpb")),
-             "embed_stages": (("emb_cls", "wec"), ("emb_pos", "wep"))}
+             "embed_stages": (("emb_cls", "wec"), ("emb_pos", "wep")),
+             "bias_stages": (("rel_bias", "wrb"),)}
 _OLD_BLOCK_DEFAULT = 512      # versions <= 3 stored it for "no override"
 
 
@@ -111,7 +126,17 @@ def _program_meta(program: CrossbarProgram) -> dict:
             "in_seq": program.in_seq}
 
 
-def _program_from_meta(meta: dict) -> CrossbarProgram:
+def _mean_pools_as_selects(ops: list[ProgramOp]) -> tuple[ProgramOp, ...]:
+    """Versions 2-6 kept a mean pool as a ``seqpool`` post-op of the stage
+    before its head; version 7 has the head read the pooled buffer with
+    ``select="mean"``, as a class-token pool is read.  Same values."""
+    pools = {op.dst: op.src for op in ops if op.kind == "seqpool"}
+    return tuple(dataclasses.replace(op, src=pools[op.src], select="mean")
+                 if op.src in pools else op
+                 for op in ops if op.kind != "seqpool")
+
+
+def _program_from_meta(meta: dict, version: int) -> CrossbarProgram:
     from repro.core.crossbar import CrossbarConfig
     ops = []
     for d in meta["ops"]:
@@ -119,6 +144,8 @@ def _program_from_meta(meta: dict) -> CrossbarProgram:
         d["mount_rounds"] = tuple(MountRound(**r)
                                   for r in d["mount_rounds"])
         ops.append(ProgramOp(**d))
+    if version < 7:
+        ops = _mean_pools_as_selects(ops)
     return CrossbarProgram(
         net=meta["net"], cfg=CrossbarConfig(**meta["cfg"]),
         ops=tuple(ops), plans=(), input=meta["input"],
@@ -214,7 +241,7 @@ def load_model(path: str):
                         w_amax=jnp.asarray(z[f"wa{i}"]),
                         bias=jnp.asarray(z[f"wb{i}"]), **extras(i))
             for i in range(meta.get("packed_stages", 0)))
-    program = _program_from_meta(meta["program"])
+    program = _program_from_meta(meta["program"], version)
     config = dict(meta["config"])
     if version < 4:
         for key in ("block_m", "block_n"):

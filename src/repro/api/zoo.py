@@ -16,6 +16,10 @@ heads, MLP ratio 4); pass ``depth=12`` for the full-size model.
 ``deit_ti`` is DeiT-Ti at 224x224 as published: pre-norm blocks, a
 class token and a position table (``embed``), exact GELU, LN epsilon
 1e-6, and a head on the class token.
+
+``swin_t`` is Swin-T at 224x224 as published: a normed 4x4 patch
+embedding, four stages of shifted-window attention blocks joined by
+patch merging, and a mean-pooled head.
 """
 
 from __future__ import annotations
@@ -163,10 +167,64 @@ def deit_graph(depth: int = 12, dim: int = 192, heads: int = 3,
     return nb.build()
 
 
+def swin_graph(depths: tuple[int, ...] = (2, 2, 6, 2),
+               heads: tuple[int, ...] = (3, 6, 12, 24), dim: int = 96,
+               window: int = 7, patch: int = 4, mlp_ratio: int = 4,
+               input_hw: int = 224, input_ch: int = 3, classes: int = 1000,
+               eps: float = 1e-5, name: str = "swin_t") -> NetworkGraph:
+    """Swin Transformer (Liu et al., arXiv:2103.14030, §3.1-3.3;
+    timm's ``swin_tiny_patch4_window7_224`` by default)::
+
+        z   = LN(patches(x) E)                   # (input_hw/patch)^2 tokens
+        per stage s (width dim * 2^s):
+          z = Linear(LN(merge2x2(z)))            # s > 0: patch merging
+          per block i:
+            z = z + W-MSA(LN1(z))                # shift M//2 on odd i
+            z = z + MLP(LN2(z))                  # GELU (erf)
+        p   = softmax(head(mean(LN(z))))
+
+    Attention runs inside M x M windows of the token map with a relative
+    position bias; every odd block rolls the map by M//2 first (none
+    where the window covers the map).  Merging concatenates each 2x2
+    neighbourhood (4C), normalizes it and maps it to 2C without a bias.
+    The defaults are Swin-T: C = 96, depths {2, 2, 6, 2}, heads {3, 6,
+    12, 24} (head dim 32), window 7, MLP ratio 4, LN epsilon 1e-5.
+    """
+    if input_hw % patch:
+        raise ValueError(f"{name}: patch {patch} does not tile "
+                         f"{input_hw}x{input_hw}")
+    nb = NetworkBuilder(name, input_hw=input_hw, input_ch=input_ch)
+    nb.conv(dim, k=patch, stride=patch, padding=0, name="patch")
+    entry = nb.layernorm(eps=eps, name="patch_norm")
+    for s, (depth, h) in enumerate(zip(depths, heads)):
+        if s:
+            nb.layernorm(pre=True, eps=eps, name=f"s{s}_merge_norm")
+            dim *= 2
+            entry = nb.linear(dim, merge=True, bias=False,
+                              name=f"s{s}_merge")
+        for i in range(depth):
+            n = f"s{s}b{i}"
+            nb.layernorm(pre=True, eps=eps, name=f"{n}_ln1")
+            nb.attention(h, window=window, shift=window // 2 if i % 2 else 0,
+                         name=f"{n}_attn")
+            r1 = nb.residual(entry, name=f"{n}_res1")
+            nb.layernorm(pre=True, eps=eps, name=f"{n}_ln2")
+            nb.linear(dim * mlp_ratio, name=f"{n}_fc1")
+            nb.gelu(approx="erf", name=f"{n}_gelu")
+            nb.linear(dim, name=f"{n}_fc2")
+            entry = nb.residual(r1, name=f"{n}_res2")
+    nb.layernorm(eps=eps, name="norm")
+    nb.seqpool(mode="mean", name="pool")
+    nb.fc(classes, name="head")
+    nb.softmax(name="softmax")
+    return nb.build()
+
+
 GRAPHS = {
     "alexnet": alexnet_graph,
     "vgg16": vgg16_graph,
     "resnet18": resnet18_graph,
     "vit_tiny": vit_tiny_graph,
     "deit_ti": deit_graph,
+    "swin_t": swin_graph,
 }

@@ -29,6 +29,16 @@ A GEMM head with ``prenorm`` set normalizes its *input* (a pre-norm
 transformer block, ``x + f(LN(x))``): ``prenorm`` names the layer
 norm's parameters, and residuals that read the input still read it
 un-normed.
+
+Hierarchical vision transformers (Swin) add three fields over a square
+``in_hw x in_hw`` grid of raster tokens: an ``attention`` with
+``window`` attends inside each ``window x window`` window of the map,
+rolled by ``-shift`` on both axes first, with a learned relative
+position bias per head; a ``linear`` with ``merge`` reads the 2x2
+space-to-depth of the map (``4 x`` the width, a quarter of the tokens:
+patch merging); ``bias=False`` marks a GEMM without a bias.  A
+``layernorm`` may also follow a patchify conv (the patch norm): the
+map's tokens leave it.
 """
 
 from __future__ import annotations
@@ -65,6 +75,10 @@ class LayerSpec:
     eps: float = 1e-5          # layer-norm epsilon (layernorm, prenorm)
     approx: str = "tanh"       # gelu form: "tanh" | "erf"
     mode: str = "mean"         # seqpool: "mean" | "cls" (row 0)
+    window: int = 0            # attention: window edge M (0 = global)
+    shift: int = 0             # attention: cyclic shift of the map
+    merge: bool = False        # linear: reads the 2x2 space-to-depth
+    bias: bool = True          # GEMM heads: has a bias
 
     # -- workload numbers used by mapping/cycle models ----------------------
     @property

@@ -9,51 +9,48 @@ round-trips through a separate jnp op.  This extends
 `fused_gemm_epilogue.py` (which fuses fp GEMM + activation) to the
 crossbar's int32 -> f32 dequant chain and to window reductions.
 
-The sequence workload class (DESIGN.md §9) adds three FB ops on top of
-the CNN chain: **GELU** (a LUT activation like the softmax exp; the
-tanh form), **layer norm** (mean/variance row statistics in the SnA
-datapath, then a scale and shift — the transformer analogue of the
-shift-and-add requant; its epsilon ``eps`` is static), and **seq-mean
-pooling** (the classifier-head token reduction, a 1-D window average
-over one sequence's rows).  The exact GELU (``gelu_erf``) is no mode of
-the kernel: Mosaic has no lowering for ``erf``, so the executor applies
-it with XLA to the kernel's output (DESIGN.md §9).  A static
-``post_scale`` factor multiplies the dequantized tile before the
-activation — attention
-programs fold `1/sqrt(head_dim)` into the scores stage there, keeping
-the float op order identical to the functional oracle's
-``softmax(scores * sm_scale)``.
+The sequence workload class (DESIGN.md §9) adds one FB op to the
+kernel: **GELU** (a LUT activation like the softmax exp; the tanh
+form).  A static ``post_scale`` factor multiplies the dequantized tile
+before the activation — attention programs fold `1/sqrt(head_dim)`
+into the scores stage there, keeping the float op order identical to
+the functional oracle's ``softmax(scores * sm_scale)``.  The other
+sequence tails are no modes of the kernel: the executor evaluates them
+with XLA on the kernel's output, as fixed expression trees the oracle
+and the benchmark's reference write too (DESIGN.md §9) — the exact GELU
+(``gelu_erf``; Mosaic has no lowering for ``erf``), every layer norm
+(``layer_norm_ordered``), attention's softmax (``softmax_ordered``),
+and the mean over tokens (``token_mean``, in the operand build of the
+head that reads it), their row sums in one pairwise order
+(``ordered_sum``).
 
 Op order is the canonical FB chain order (the only order the paper's /
 transformer workloads produce, validated by the program compiler):
 
     dequant (SnA scale) -> + bias -> + residual -> [* post_scale]
-        -> ReLU | GELU -> layer norm
-        -> max/avg pool window | seq-mean  OR  softmax
+        -> ReLU | GELU -> max/avg pool window  OR  softmax
 
 The numeric bodies of the non-trivial FB ops (``gelu``,
-``layer_norm_rows``, ``softmax_rows``) are module-level jnp functions so
-the functional oracle (`api/graph.py::NetworkGraph.forward`) evaluates
-the *same expression tree* — bit-identical under jit (DESIGN.md §5).
+``softmax_rows``) are module-level jnp functions so the functional
+oracle (`api/graph.py::NetworkGraph.forward`) evaluates the *same
+expression tree* — bit-identical under jit (DESIGN.md §5).
 
 Pooling layout: rows of the (M, N) GEMM output are im2col vectors in
 (image, row, col) order, so one grid step owns whole images' ``ih*ih``
 rows and reduces ``window x window`` blocks via a leading-axis reshape
 — the column-parallel window tiling of Fig 5c.  Only ``stride ==
 window`` (non-overlapping) pooling is supported, which covers the
-paper's workloads (2x2/2 max pool, 4x4/4 global avg pool).  ``seqmean``
-treats ``window`` as the token count: a grid step owns whole sequences'
-rows and mean-reduces each to a single output row.  A step takes the
-fewest images (sequences) whose output rows fill a multiple of 8
+paper's workloads (2x2/2 max pool, 4x4/4 global avg pool).  A step
+takes the fewest images whose output rows fill a multiple of 8
 sublanes (``_groups_per_step``; the TPU tiling refuses an output block
 of 1 or 4 rows), or all of them when there are fewer; a batch that is
-not a multiple of that count is padded with zero images (sequences).
-Softmax and layer norm need the full feature axis in-tile, so
-``block_n`` is forced to N in those modes.
+not a multiple of that count is padded with zero images.  Softmax
+needs the full feature axis in-tile, so ``block_n`` is forced to N in
+that mode.
 
-The per-column operands (bias, layer-norm gamma/beta) enter the kernel
-as ``(1, N)`` rows with ``(1, block_n)`` blocks: a 1-D block has no
-layout the TPU tiling and XLA agree on.
+The per-column bias enters the kernel as a ``(1, N)`` row with
+``(1, block_n)`` blocks: a 1-D block has no layout the TPU tiling and
+XLA agree on.
 
 Edge blocks over rows: the grid takes ``cdiv(M, block_m)`` row blocks
 of the operands as they are, and the output is exactly (M, N).  Where M
@@ -63,10 +60,10 @@ The FB chain treats every row on its own, so each kept row is computed
 exactly as in a divisible grid; an M that fits one block is one block
 of the whole dimension.  Columns have no edge: an N that ``block_n``
 does not divide takes one full-width block (``block_n = N``, which the
-TPU tiling always accepts), as softmax and layer norm always do (they
-need the full feature axis in-tile).  The pooled and seq-mean modes
-fix M to ``B * img_hw^2`` (``B * T``) and pad the batch by whole images
-(sequences) to their images per step, then slice the padding off.
+TPU tiling always accepts), as softmax always does (it needs the full
+feature axis in-tile).  The pooled modes fix M to ``B * img_hw^2`` and
+pad the batch by whole images to their images per step, then slice the
+padding off.
 """
 
 from __future__ import annotations
@@ -98,20 +95,6 @@ def gelu_erf(x: jnp.ndarray) -> jnp.ndarray:
     return 0.5 * x * (1.0 + jax.lax.erf(x * 0.7071067811865476))
 
 
-def layer_norm_rows(x: jnp.ndarray, gamma: jnp.ndarray, beta: jnp.ndarray,
-                    eps: float = LN_EPS) -> jnp.ndarray:
-    """Per-row layer norm over the last axis, then scale and shift.
-
-    Mean/variance are the row statistics the SnA datapath accumulates;
-    the affine tail is the same multiply-add shape as the requant FB.
-    Shared kernel/oracle expression (DESIGN.md §5).
-    """
-    m = jnp.mean(x, axis=-1, keepdims=True)
-    d = x - m
-    v = jnp.mean(d * d, axis=-1, keepdims=True)
-    return d / jnp.sqrt(v + eps) * gamma + beta
-
-
 def ordered_sum(x: jnp.ndarray) -> jnp.ndarray:
     """Sum over the last axis (kept) in one fixed pairwise order, as
     elementwise adds of its halves.  XLA rounds an elementwise add the
@@ -128,8 +111,10 @@ def ordered_sum(x: jnp.ndarray) -> jnp.ndarray:
 def layer_norm_ordered(x: jnp.ndarray, gamma: jnp.ndarray,
                        beta: jnp.ndarray, eps: float = LN_EPS
                        ) -> jnp.ndarray:
-    """``layer_norm_rows`` with ``ordered_sum`` row sums: the pre-norm,
-    which XLA evaluates in the operand build of the stage it feeds."""
+    """Layer norm over the last axis with ``ordered_sum`` row sums, then
+    scale and shift: every layer norm of a sequence network, which XLA
+    evaluates in the operand build of the stage it feeds (a pre-norm) or
+    on the epilogue kernel's output (a post-norm, the patch norm)."""
     n = x.shape[-1]
     m = ordered_sum(x) / n
     d = x - m
@@ -144,6 +129,13 @@ def softmax_ordered(x: jnp.ndarray) -> jnp.ndarray:
     return e / ordered_sum(e)
 
 
+def token_mean(x: jnp.ndarray) -> jnp.ndarray:
+    """(B, T, D) tokens -> (B, D): the mean over the tokens, its sum an
+    ``ordered_sum`` (a mean-pooled head, which XLA evaluates in the
+    operand build of the stage that reads the pool)."""
+    return ordered_sum(jnp.swapaxes(x, 1, 2))[..., 0] / x.shape[1]
+
+
 def softmax_rows(x: jnp.ndarray) -> jnp.ndarray:
     """Max-subtracted per-row softmax (paper Eq. 1's stabilization).
 
@@ -156,9 +148,9 @@ def softmax_rows(x: jnp.ndarray) -> jnp.ndarray:
     return e / jnp.sum(e, axis=-1, keepdims=True)
 
 
-def _kernel(y_ref, scale_ref, b_ref, res_ref, g_ref, bt_ref, o_ref, *,
-            act: str, pool: str, window: int, img_hw: int, softmax: bool,
-            norm: str, eps: float, post_scale: float, has_residual: bool):
+def _kernel(y_ref, scale_ref, b_ref, res_ref, o_ref, *, act: str,
+            pool: str, window: int, img_hw: int, softmax: bool,
+            post_scale: float, has_residual: bool):
     y = (y_ref[...].astype(jnp.float32) * scale_ref[0, 0]
          + b_ref[...].astype(jnp.float32))
     if has_residual:
@@ -169,13 +161,8 @@ def _kernel(y_ref, scale_ref, b_ref, res_ref, g_ref, bt_ref, o_ref, *,
         y = jnp.maximum(y, 0.0)
     elif act == "gelu":
         y = gelu(y)
-    if norm == "layer":
-        y = layer_norm_rows(y, g_ref[...].astype(jnp.float32),
-                            bt_ref[...].astype(jnp.float32), eps)
     bn = y.shape[-1]
-    if pool == "seqmean":                # window = tokens per sequence
-        y = jnp.mean(y.reshape(-1, window, bn), axis=1)
-    elif pool != "none":
+    if pool != "none":
         oh = img_hw // window
         y = y.reshape(-1, oh, window, oh, window, bn)
         y = jnp.max(y, axis=(2, 4)) if pool == "max" else jnp.mean(y, axis=(2, 4))
@@ -186,29 +173,24 @@ def _kernel(y_ref, scale_ref, b_ref, res_ref, g_ref, bt_ref, o_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("act", "pool", "window",
-                                             "img_hw", "softmax", "norm",
-                                             "eps", "post_scale", "block_m",
+                                             "img_hw", "softmax",
+                                             "post_scale", "block_m",
                                              "block_n", "interpret"))
 def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
                 residual: jnp.ndarray | None = None, *, act: str = "none",
                 pool: str = "none", window: int = 0, img_hw: int = 0,
-                softmax: bool = False, norm: str = "none",
-                gamma: jnp.ndarray | None = None,
-                beta: jnp.ndarray | None = None, eps: float = LN_EPS,
-                post_scale: float = 0.0, block_m: int | None = None,
+                softmax: bool = False, post_scale: float = 0.0,
+                block_m: int | None = None,
                 block_n: int | None = None,
                 interpret: bool = False) -> jnp.ndarray:
     """y (M, N) int32 crossbar output -> fused FB chain -> f32.
 
     ``scale`` is the (1, 1) f32 shift-and-add requant factor (input scale
-    x weight scale); ``bias`` is (N,) (passed on as a (1, N) row).  ``act`` in {"none", "relu",
-    "gelu"}; ``pool`` in {"none", "max", "avg", "seqmean"} — max/avg use
-    ``window == stride`` over an ``img_hw x img_hw`` spatial grid per
-    image (M = B * img_hw^2, output (B * (img_hw//window)^2, N));
-    ``seqmean`` mean-reduces each sequence's ``window`` token rows
-    (M = B * window, output (B, N)).  ``norm="layer"`` applies
-    ``layer_norm_rows`` with ``gamma``/``beta`` (N,) and epsilon ``eps``
-    (static) after the activation.  ``post_scale`` (static) multiplies
+    x weight scale); ``bias`` is (N,) (passed on as a (1, N) row).
+    ``act`` in {"none", "relu", "gelu"}; ``pool`` in {"none", "max",
+    "avg"} — ``window == stride`` over an ``img_hw x img_hw`` spatial
+    grid per image (M = B * img_hw^2, output
+    (B * (img_hw//window)^2, N)).  ``post_scale`` (static) multiplies
     the dequantized tile before the activation — attention scores fold
     `1/sqrt(hd)` here.
     ``softmax=True`` (exclusive with pool) normalizes over the full
@@ -218,23 +200,15 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     M, N = y.shape
     assert scale.shape == (1, 1) and bias.shape == (N,)
     assert act in ("none", "relu", "gelu")
-    assert pool in ("none", "max", "avg", "seqmean")
-    assert norm in ("none", "layer")
+    assert pool in ("none", "max", "avg")
     has_residual = residual is not None
     res = residual if has_residual else jnp.zeros((1, 1), jnp.float32)
-    has_norm = norm == "layer"
     bias = bias.reshape(1, N)
-    if has_norm:
-        assert gamma is not None and beta is not None
-        assert gamma.shape == (N,) and beta.shape == (N,)
-        g, bt = gamma.reshape(1, N), beta.reshape(1, N)
-    else:
-        g = bt = jnp.zeros((1, 1), jnp.float32)
 
     dm, dn = default_blocks("epilogue")
     block_m, block_n = block_m or dm, block_n or dn
     # one full-width column block where block_n does not divide N
-    if softmax or has_norm or N % min(block_n, N):
+    if softmax or N % min(block_n, N):
         block_n = N              # the row reduction needs every column
     block_n = min(block_n, N)
     pm = 0
@@ -244,19 +218,15 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         row_spec = out_spec = pl.BlockSpec((block_m, block_n),
                                            lambda i, j: (i, j))
         out_shape = jax.ShapeDtypeStruct((M, N), jnp.float32)
-    else:                        # whole images / sequences per step
+    else:                        # whole images per step
         assert not softmax, "pool and softmax FBs never chain directly"
-        if pool == "seqmean":
-            assert window >= 1 and M % window == 0, (M, window)
-            group_rows, out_rows = window, 1
-        else:
-            assert window > 1 and img_hw % window == 0, (img_hw, window)
-            group_rows, out_rows = img_hw * img_hw, (img_hw // window) ** 2
-            assert M % group_rows == 0, (M, img_hw)
+        assert window > 1 and img_hw % window == 0, (img_hw, window)
+        group_rows, out_rows = img_hw * img_hw, (img_hw // window) ** 2
+        assert M % group_rows == 0, (M, img_hw)
         n_groups = M // group_rows
         k = _groups_per_step(n_groups, out_rows)
         pm = -n_groups % k * group_rows
-        if pm:                   # pad by whole zero images / sequences
+        if pm:                   # pad by whole zero images
             y = jnp.pad(y, ((0, pm), (0, 0)))
             if has_residual:
                 res = jnp.pad(res, ((0, pm), (0, 0)))
@@ -271,8 +241,8 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
     one = pl.BlockSpec((1, 1), lambda i, j: (0, 0))
     col_spec = pl.BlockSpec((1, block_n), lambda i, j: (0, j))
     kernel = functools.partial(_kernel, act=act, pool=pool, window=window,
-                               img_hw=img_hw, softmax=softmax, norm=norm,
-                               eps=eps, post_scale=post_scale,
+                               img_hw=img_hw, softmax=softmax,
+                               post_scale=post_scale,
                                has_residual=has_residual)
     out = pl.pallas_call(
         kernel,
@@ -282,8 +252,6 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
             one,
             col_spec,
             row_spec if has_residual else one,
-            col_spec if has_norm else one,
-            col_spec if has_norm else one,
         ],
         out_specs=out_spec,
         out_shape=out_shape,
@@ -291,12 +259,12 @@ def fb_epilogue(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         # names the custom call (``%fb_epilogue.N``) in the compiled
         # HLO, which trace readers match on
         name="fb_epilogue",
-    )(y, scale, bias, res, g, bt)
+    )(y, scale, bias, res)
     return out[:n_groups * out_rows] if pm else out
 
 
 def _groups_per_step(n_groups: int, out_rows: int) -> int:
-    """Images (or sequences) per pooled grid step.
+    """Images per pooled grid step.
 
     The fewest whose ``out_rows``-row outputs fill a multiple of 8
     sublanes, or all ``n_groups`` when there are fewer (a block equal to

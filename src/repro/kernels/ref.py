@@ -108,12 +108,10 @@ def fb_epilogue_ref(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
                     residual: jnp.ndarray | None = None, *,
                     act: str = "none", pool: str = "none", window: int = 0,
                     img_hw: int = 0, softmax: bool = False,
-                    norm: str = "none", gamma: jnp.ndarray | None = None,
-                    beta: jnp.ndarray | None = None, eps: float = 1e-5,
                     post_scale: float = 0.0) -> jnp.ndarray:
     """The unfused jnp composition the fb_epilogue kernel must equal:
     dequant -> +bias -> +residual -> [* post_scale] -> ReLU|GELU ->
-    layer norm -> pool window | seq-mean | softmax, written with the
+    pool window | softmax, written with the
     same ops the functional forwards use (``reduce_window`` max pool,
     window-mean avg pool, jax.nn.softmax / jax.nn.gelu).
     """
@@ -133,16 +131,7 @@ def fb_epilogue_ref(y: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
         out = gelu(out)
     elif act != "none":
         raise ValueError(act)
-    if norm == "layer":
-        mu = out.mean(axis=-1, keepdims=True)
-        var = ((out - mu) ** 2).mean(axis=-1, keepdims=True)
-        out = ((out - mu) / jnp.sqrt(var + eps)
-               * gamma.astype(jnp.float32) + beta.astype(jnp.float32))
-    elif norm != "none":
-        raise ValueError(norm)
-    if pool == "seqmean":
-        out = out.reshape(M // window, window, N).mean(axis=1)
-    elif pool != "none":
+    if pool != "none":
         b = M // (img_hw * img_hw)
         x4 = out.reshape(b, img_hw, img_hw, N)
         if pool == "max":

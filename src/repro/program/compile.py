@@ -42,7 +42,8 @@ Algorithm 1/2 vocabulary, and a dynamic stage's element count is
 unknown at compile time), so sequence groups skip ``plan_array``.
 
 The FB op vocabulary is ``gemm | dyn_gemm | relu | gelu | maxpool |
-avgpool | layernorm | embed | seqpool | residual | softmax``; post-ops
+avgpool | layernorm | embed | residual | softmax`` (a ``seqpool`` is read
+by the GEMM head after it, below); post-ops
 must follow the canonical FB chain order ``residual -> relu|gelu ->
 pool -> layernorm -> embed|seqpool -> softmax``
 (``core.workload.POST_RANK``).
@@ -52,10 +53,23 @@ lowers onto the stage that reads the normed input — for attention the
 fused qkv projection — as that op's ``prenorm``/``eps``: the executor
 normalizes the stage input while it builds the int8 operand, so LN(x)
 is never a buffer and the residual source stays x.  ``embed`` is a
-post-op of the patchify conv's stage.  A class-token pool
-(``seqpool(mode="cls")``) emits no op: the GEMM head that reads it
-takes its source's row 0 (``select="cls"``), so the stage before it
-writes the whole token buffer.
+post-op of the patchify conv's stage.  A token pool (``seqpool``)
+emits no op: the GEMM head that reads it takes its source's row 0
+(``select="cls"``, the class token) or the mean of its tokens
+(``select="mean"``) while it builds its operand, so the stage before
+it writes the whole token buffer.
+
+**Windows, shifts and merges (Swin).**  A windowed attention lowers to
+the same four stages; its two dynamic stages carry the window edge
+(``window``), the token grid's side (``in_hw``) and the ``shift``, so
+the executor mounts per (image, window, head) and the P·V contraction
+is the window's M² tokens, not T.  The Q·Kᵀ stage names the attention's
+parameters (``param``) for its relative position bias table, which
+``pack`` gathers once.  A patch-merging ``linear`` is a static stage
+whose operand build takes the 2x2 space-to-depth (``merge``) before its
+pre-norm over 4C; a bias-free GEMM has no ``b_key``.  A ``layernorm``
+after a patchify conv (the patch norm) is a post-op of the conv's
+stage, which then writes tokens.
 """
 
 from __future__ import annotations
@@ -109,14 +123,15 @@ class ProgramOp:
     """One FB op of the static program (see module docstring)."""
 
     kind: str                  # gemm|dyn_gemm|relu|gelu|maxpool|avgpool|
-                               # layernorm|embed|seqpool|residual|softmax
+                               # layernorm|embed|residual|softmax
     name: str                  # producing workload layer
     src: str                   # input buffer (a ProgramOp name or "input")
     dst: str                   # output buffer (== name)
     # gemm
     param: str = ""            # model params key ("" = no parameters)
     w_key: str = "w"           # weight/bias keys inside params[param]
-    b_key: str = "b"           # (attention packs wqkv/bqkv + wo/bo)
+    b_key: str = "b"           # (attention packs wqkv/bqkv + wo/bo;
+                               # "" = no bias)
     is_conv: bool = False
     seq: bool = False          # operates on (B, T, D) token buffers
     ksize: int = 1
@@ -136,10 +151,16 @@ class ProgramOp:
     prenorm: str = ""          # gemm: params key of its input's layer norm
     eps: float = 1e-5          # layer-norm epsilon (layernorm, prenorm)
     approx: str = "tanh"       # gelu form: "tanh" | "erf"
-    select: str = ""           # gemm: "cls" reads row 0 of each sequence
-    # pool
-    window: int = 0            # pool window edge (== stride; VALID)
-    in_hw: int = 0             # spatial extent entering the pool
+    select: str = ""           # gemm: "cls" reads row 0 of each sequence,
+                               # "mean" the mean of its tokens
+    merge: bool = False        # gemm: operand is the 2x2 space-to-depth
+                               # of the in_hw x in_hw token grid
+    shift: int = 0             # dyn_gemm: cyclic shift of a windowed map
+    # pool, and windowed attention's dynamic stages
+    window: int = 0            # pool window edge (== stride; VALID), or
+                               # attention's window edge M
+    in_hw: int = 0             # spatial extent entering the pool, or the
+                               # side of a merge's or window's token grid
     # residual
     res_src: str = ""          # buffer holding the residual addend
     # decoded FB placement (from the group's ArrayPlan; -1 = no FB,
@@ -212,6 +233,7 @@ class CrossbarProgram:
                  + (" + dynamic mounts" if self.has_dynamic_stages else "")]
         for gemm, posts in self.stages():
             pre = (([gemm.select] if gemm.select else [])
+                   + (["merge"] if gemm.merge else [])
                    + (["prenorm"] if gemm.prenorm else []))
             chain = "+".join(pre + [gemm.kind] + [p.kind for p in posts])
             mounts = (f"mounts {len(gemm.mount_rounds)}"
@@ -244,21 +266,23 @@ def _mount_rounds(K: int, N: int, tile_rows: int,
 
 
 def _is_seq_group(group) -> bool:
-    # embed is a patchify conv's post-op: that group lowers as a CNN one
+    # embed and the patch norm are a patchify conv's post-ops: that
+    # group lowers as a CNN one
+    patch_ops = ("embed", "layernorm") if group[0].kind == "conv" else ()
     return (group[0].kind in ("linear", "attention")
-            or any(l.kind in SEQ_KINDS and l.kind != "embed"
+            or any(l.kind in SEQ_KINDS and l.kind not in patch_ops
                    for l in group))
 
 
 def _source(head, prev: str, finals: set[str],
-            cls_pools: dict[str, str]) -> tuple[str, str]:
-    """A GEMM head's input buffer and ``select``: a class-token pool's
-    name resolves to the buffer it pools, read with ``select="cls"``."""
+            pools: dict[str, tuple[str, str]]) -> tuple[str, str]:
+    """A GEMM head's input buffer and ``select``: a token pool's name
+    resolves to the buffer it pools, read with the pool's mode."""
     src = head.input_from or prev
     if src not in finals:
         raise ValueError(f"{head.name} consumes unknown buffer {src!r}")
-    if src in cls_pools:
-        return cls_pools[src], "cls"
+    if src in pools:
+        return pools[src]
     return src, ""
 
 
@@ -268,15 +292,16 @@ def _norm_fields(head) -> dict:
 
 
 def _seq_posts(group, head_dst: str, finals: set[str],
-               ops: list[ProgramOp], cls_pools: dict[str, str]) -> str:
+               ops: list[ProgramOp],
+               pools: dict[str, tuple[str, str]]) -> str:
     """Emit the sequence group's post-op chain; returns the final buffer
-    (a class-token pool's name, recorded in ``cls_pools``, when the
-    chain ends in one)."""
+    (a token pool's name, recorded in ``pools``, when the chain ends in
+    one)."""
     rank = -1
     cur = head_dst
     for l in group[1:]:
-        if cur in cls_pools:
-            raise ValueError(f"{l.name}: a class-token pool ends its group "
+        if cur in pools:
+            raise ValueError(f"{l.name}: a token pool ends its group "
                              "(only a GEMM head reads it)")
         if l.kind not in POST_RANK:
             raise ValueError(f"unsupported FB op {l.kind} ({l.name})")
@@ -299,8 +324,8 @@ def _seq_posts(group, head_dst: str, finals: set[str],
         if l.kind == "gelu" and l.approx == "erf" and l is not group[-1]:
             raise ValueError(f"{l.name}: an erf GELU ends its FB chain (it "
                              "runs after the epilogue kernel, in XLA)")
-        if l.kind == "seqpool" and l.mode == "cls":
-            cls_pools[l.name] = cur
+        if l.kind == "seqpool":
+            pools[l.name] = (cur, l.mode)
             cur = l.name
             continue
         ops.append(ProgramOp(
@@ -311,13 +336,14 @@ def _seq_posts(group, head_dst: str, finals: set[str],
 
 
 def _lower_seq_group(group, chip: ChipConfig, finals: set[str], prev: str,
-                     ops: list[ProgramOp], cls_pools: dict[str, str]) -> str:
+                     ops: list[ProgramOp],
+                     pools: dict[str, tuple[str, str]]) -> str:
     """Lower one sequence group; returns its final buffer name."""
     head = group[0]
     planes = chip.weight_planes
     reserve = sum(_SEQ_FB_ROWS[l.kind] for l in group[1:]
                   if l.kind in _SEQ_FB_ROWS)
-    src, select = _source(head, prev, finals, cls_pools)
+    src, select = _source(head, prev, finals, pools)
 
     def seq_gemm(name, src, dst, *, K, N, w_key="w", b_key="b",
                  param=None, rows_reserve=reserve, **extra):
@@ -331,10 +357,13 @@ def _lower_seq_group(group, chip: ChipConfig, finals: set[str], prev: str,
             mount_rounds=_mount_rounds(K, N, tile_rows, tile_cols), **extra)
 
     if head.kind == "linear":
+        merge = ({"merge": True, "in_hw": head.in_hw} if head.merge
+                 else {})
         ops.append(seq_gemm(head.name, src, head.name,
                             K=head.features_in, N=head.features_out,
-                            select=select, **_norm_fields(head)))
-        return _seq_posts(group, head.name, finals, ops, cls_pools)
+                            b_key="b" if head.bias else "",
+                            select=select, **merge, **_norm_fields(head)))
+        return _seq_posts(group, head.name, finals, ops, pools)
 
     if head.kind != "attention":
         # raw LayerSpec lists can still reach here (the builder rejects
@@ -345,6 +374,9 @@ def _lower_seq_group(group, chip: ChipConfig, finals: set[str], prev: str,
             "attention group heads only")
     d, h = head.features_in, head.heads
     hd = d // h
+    # a windowed attention's geometry, on both dynamic stages
+    win = ({"window": head.window, "in_hw": head.in_hw,
+            "shift": head.shift} if head.window else {})
     qkv, scores = f"{head.name}@qkv", f"{head.name}@scores"
     probs, ctx = f"{head.name}@probs", f"{head.name}@ctx"
     # 1. fused qkv projection: one compile-time weight mount, N = 3D;
@@ -356,27 +388,27 @@ def _lower_seq_group(group, chip: ChipConfig, finals: set[str], prev: str,
     #    1/sqrt(hd) logit scale; contraction length is the head dim
     ops.append(ProgramOp(
         kind="dyn_gemm", name=scores, src=qkv, dst=scores, dyn="qk",
-        dyn_src=qkv, heads=h, seq=True,
-        post_scale=attn_scale(hd),
+        dyn_src=qkv, heads=h, seq=True, param=head.name if win else "",
+        post_scale=attn_scale(hd), **win,
         tile_rows=max(1, min(hd, chip.array_rows
                              - _SEQ_FB_ROWS["softmax"])),
         tile_cols=max(1, chip.array_cols // planes)))
     ops.append(ProgramOp(kind="softmax", name=probs, src=scores, dst=probs,
                          seq=True))
     # 3. P·V context: dynamic V-operand mount; the contraction length is
-    #    the RUNTIME sequence length, so only a row budget exists here —
-    #    the executor sizes the K grid to seq_len (dynamic block
-    #    activation), N = head dim
+    #    the RUNTIME sequence length (a window's M² tokens), so only a
+    #    row budget exists here — the executor sizes the K grid to it
+    #    (dynamic block activation), N = head dim
     ops.append(ProgramOp(
         kind="dyn_gemm", name=ctx, src=probs, dst=ctx, dyn="pv",
-        dyn_src=qkv, heads=h, seq=True,
+        dyn_src=qkv, heads=h, seq=True, **win,
         tile_rows=max(1, chip.array_rows - _SEQ_OR_ROWS),
         tile_cols=max(1, min(hd, chip.array_cols // planes))))
     # 4. output projection: compile-time weights again; the graph-level
     #    post-ops (residual/layernorm/...) fuse onto this stage
     ops.append(seq_gemm(head.name, ctx, head.name, K=d, N=d,
                         w_key="wo", b_key="bo"))
-    return _seq_posts(group, head.name, finals, ops, cls_pools)
+    return _seq_posts(group, head.name, finals, ops, pools)
 
 
 def compile_network(net, *, config=None,
@@ -408,12 +440,13 @@ def compile_network(net, *, config=None,
     ops: list[ProgramOp] = []
     plans: list[ArrayPlan] = []
     finals: set[str] = {"input"}
-    cls_pools: dict[str, str] = {}   # class-token pool -> buffer it pools
+    # token pool -> (buffer it pools, its mode)
+    pools: dict[str, tuple[str, str]] = {}
     prev = "input"
     for group in layer_groups(layers):
         head = group[0]
         if _is_seq_group(group):
-            cur = _lower_seq_group(group, chip, finals, prev, ops, cls_pools)
+            cur = _lower_seq_group(group, chip, finals, prev, ops, pools)
             prev = cur
             finals.add(cur)
             continue
@@ -430,7 +463,7 @@ def compile_network(net, *, config=None,
         tile_rows = reqs[0].req_rows
         tile_cols = max(1, reqs[0].req_cols // planes)
 
-        src, select = _source(head, prev, finals, cls_pools)
+        src, select = _source(head, prev, finals, pools)
         ops.append(ProgramOp(
             kind="gemm", name=head.name, src=src, dst=head.name,
             param=head.name, is_conv=head.kind == "conv",
@@ -466,6 +499,8 @@ def compile_network(net, *, config=None,
                 extra = {"res_src": l.residual_from}
             if l.kind == "embed":        # patch tokens: class token, pos
                 extra = {"param": l.name, "seq": True}
+            if l.kind == "layernorm":    # the patch norm: tokens leave
+                extra = {"param": l.name, "eps": l.eps, "seq": True}
             ops.append(ProgramOp(
                 kind=l.kind, name=l.name, src=cur, dst=l.name,
                 out_ch=l.out_ch or l.features_out, **extra,
@@ -474,8 +509,9 @@ def compile_network(net, *, config=None,
         prev = cur
         finals.add(cur)
 
-    if prev in cls_pools:
-        raise ValueError(f"{prev}: a class-token pool ends the network; "
+    if prev in pools:
+        kind = "class-token" if pools[prev][1] == "cls" else "token-mean"
+        raise ValueError(f"{prev}: a {kind} pool ends the network; "
                          "only a GEMM head reads it")
     logits = next(op.dst for op in reversed(ops) if op.kind == "gemm")
     if hasattr(net, "input_shape"):       # a NetworkGraph carries its spec

@@ -31,29 +31,32 @@ scheduled program is *the* thing that computes:
     one physical array read with per-mount ADC chunk semantics — there
     K order and mount membership are semantics;
 * every post-op chain (shift-and-add requant -> bias -> residual ->
-  ReLU/GELU -> layer norm -> max/avg/seq-mean pool window | softmax)
-  runs in ONE pass of the fused ``fb_epilogue`` Pallas kernel over the
-  GEMM output tile, so the crossbar output never round-trips through a
-  separate jnp op — the numeric analogue of HURRY hiding FB post-ops
-  inside the array;
+  ReLU/tanh GELU -> max/avg pool window | softmax) runs in ONE pass of
+  the fused ``fb_epilogue`` Pallas kernel over the GEMM output tile, so
+  the crossbar output never round-trips through a separate jnp op —
+  the numeric analogue of HURRY hiding FB post-ops inside the array;
 * sequence stages add steps around that (``compile.py``): a stage
-  that reads a class-token pool takes row 0 of each sequence of its
-  source (``select="cls"``: rows ``0, T, 2T, ...``); a pre-norm stage
+  that reads a token pool takes row 0 of each sequence of its source
+  (``select="cls"``: rows ``0, T, 2T, ...``) or the mean of its tokens
+  (``select="mean"``, ``fb_epilogue.token_mean``); a pre-norm stage
   layer-normalizes its
   input while it builds the operand, before ``amax`` and the int8
   convert, so LN(x) is never a stage buffer; and a patchify stage's
   ``embed`` prepends the class token to its output tokens and adds the
   position table (``sequence.embed_tokens``);
-* two float FB ops run as XLA ops on the epilogue kernel's output, in
-  its ``epilogue`` phase: the exact GELU (Mosaic cannot lower ``erf``;
-  it ends its stage's chain) and attention's softmax.  The next stage
-  re-quantizes both, so a result a few ulps apart from an XLA
-  evaluation of the same expression (Mosaic sums a row in another
+* the sequence tails run as XLA ops on the epilogue kernel's output,
+  in its ``epilogue`` phase, in chain order: the exact GELU (Mosaic
+  cannot lower ``erf``; it ends its stage's chain), a post-norm or
+  patch layer norm, and attention's softmax (after a windowed
+  attention's relative position bias and shift mask); and the mean
+  over tokens in the operand build of the head that reads it.  The
+  next stage re-quantizes them, so a result a few ulps apart from an
+  XLA evaluation of the same expression (Mosaic sums a row in another
   order) flips int8 roundings there, which a deep network amplifies:
   on a TPU v5e the 12-block DeiT-Ti read ``prob_err`` 0.087 against
-  the oracle with its softmax in Mosaic (PERF.md §6).  In XLA, the
-  program's float tails round as any XLA evaluation of the reference
-  does.
+  the oracle with its softmax in Mosaic (PERF.md §6).  In XLA, with their sums in one fixed pairwise
+  order (``fb_epilogue.ordered_sum``), the program's float tails round
+  as any XLA evaluation of the reference does.
 
 **Dynamic-operand stages** (``kind="dyn_gemm"``, DESIGN.md §9) extend
 the same machinery to attention's activation-side GEMMs: per (batch,
@@ -65,7 +68,16 @@ scores, seq_len for context — the paper's block-activation scheme on
 dynamically sized mounts).  The per-mount loop is a ``jax.vmap`` over
 the (batch*heads) axis — mirroring the functional oracle's vmapped
 ``mm`` exactly, so per-slice quantization statistics line up and the
-clip-free bit-exactness argument of §5 carries over unchanged.
+clip-free bit-exactness argument of §5 carries over unchanged.  A
+windowed attention (Swin) mounts per (image, window, head) instead: its
+Q·Kᵀ and P·V operand builds roll the token map by ``-shift`` and cut it
+into windows (``sequence.window_qkv_heads``), its scores get the
+relative position bias and then the shift mask before the softmax
+(``sequence.window_bias``), and its P·V epilogue puts the windows back
+and undoes the roll where the context rejoins the rows
+(``sequence.window_merge``).  A patch-merging stage builds its operand
+from the 2x2 space-to-depth of its input's token map
+(``sequence.space_to_depth``) before its pre-norm.
 
 **Sequence buffers are token rows.**  Inside the executor a sequence
 stage's buffer is the ``(B*T, N)`` row matrix its epilogue kernel
@@ -115,7 +127,8 @@ instruction serves:
 
 * ``im2col`` — im2col (a dense stage's tap concat on the int8
   tensor), token-row and flatten reshapes, a class-token read, head
-  splits and Kᵀ;
+  splits and Kᵀ, and inside it ``window``, a windowed attention's roll
+  and window partition, and ``merge``, a merge stage's space-to-depth;
 * ``quantize`` — the activation's amax reduction and int8 convert,
   and inside it ``prenorm``, a pre-norm stage's layer norm of its
   input;
@@ -123,9 +136,11 @@ instruction serves:
   lane pad of an N under 128 and the slice back, and ``plane_pack`` of
   a dynamic stage's right-hand operand;
 * ``gemm`` — the ``mounted_gemm`` kernel;
-* ``epilogue`` — the scale product, the ``fb_epilogue`` kernel, a
-  conv's NHWC output reshape and a context's head merge, and inside it
-  ``embed``, a patchify stage's class token and position table.
+* ``epilogue`` — the scale product, the ``fb_epilogue`` kernel, the
+  XLA tails, a conv's NHWC output reshape and a context's head merge,
+  and inside it ``embed``, a patchify stage's class token and position
+  table, ``relbias``, a windowed Q·Kᵀ stage's bias and mask, and
+  ``unwindow``, a windowed P·V stage's window reverse and un-roll.
 
 Scopes are metadata only: they change no op and cost nothing at run
 time.
@@ -145,13 +160,16 @@ import jax.numpy as jnp
 from repro.core.conv import im2col, im2col_read_mask
 from repro.core.crossbar import quantize_scale, quantize_symmetric
 from repro.kernels.crossbar_gemm import mounted_gemm
-from repro.kernels.fb_epilogue import (LN_EPS, fb_epilogue, gelu_erf,
-                                       layer_norm_ordered, softmax_ordered)
+from repro.kernels.fb_epilogue import (fb_epilogue, gelu_erf,
+                                       layer_norm_ordered, softmax_ordered,
+                                       token_mean)
 from repro.kernels.ops import interpret_default
 
 from .compile import ProgramOp
 from .pack import PackedProgram, PackedStage, plane_pack, stage_layout
-from .sequence import embed_tokens, merge_heads, split_qkv_heads
+from .sequence import (embed_tokens, merge_heads, shift_mask,
+                       space_to_depth, split_qkv_heads, window_bias,
+                       window_merge, window_qkv_heads)
 
 
 class Kernels(NamedTuple):
@@ -176,30 +194,41 @@ def _last_reads(stages) -> dict[str, int]:
     return last
 
 
-def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
-               cfg, batch: int, *, block_m: int | None,
+def _heads(gemm: ProgramOp, qkv: jnp.ndarray, batch: int
+           ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """q, k and v of every mount from the fused projection's rows: per
+    (image, head), or per (image, window, head) of the rolled map."""
+    x = _tokens(qkv, batch)
+    if not gemm.window:
+        return split_qkv_heads(x, gemm.heads)
+    with jax.named_scope("window"):
+        return window_qkv_heads(x, gemm.heads, gemm.in_hw, gemm.window,
+                                gemm.shift)
+
+
+def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], st: PackedStage,
+               bufs: Mapping, cfg, batch: int, *, block_m: int | None,
                block_n: int | None, interpret: bool, kernels: Kernels
                ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """One dynamic-operand GEMM stage (attention Q·Kᵀ or P·V) ->
-    (output, int32 GEMM result per batch*head).
+    (output, int32 GEMM result per mount).
 
-    Mounts the right-hand activation per (batch, head) with
-    ``plane_pack`` — the same helper that mounts weights at compile
-    time — and dispatches the same ``mounted_gemm`` kernel, its K grid
-    sized to the runtime contraction length (module docstring).
+    Mounts the right-hand activation per (batch, head) — per (batch,
+    window, head) in a windowed attention — with ``plane_pack``, the
+    same helper that mounts weights at compile time, and dispatches the
+    same ``mounted_gemm`` kernel, its K grid sized to the runtime
+    contraction length (module docstring).
     """
     with jax.named_scope("im2col"):
         if gemm.dyn == "qk":
-            q, k, _ = split_qkv_heads(_tokens(bufs[gemm.src], batch),
-                                      gemm.heads)
+            q, k, _ = _heads(gemm, bufs[gemm.src], batch)
             # Kᵀ moved in f32, in one pass, before it is quantized
             # (module docstring)
             a = q                                # (BH, T, hd)
             w = jax.lax.optimization_barrier(jnp.swapaxes(k, 1, 2))
         elif gemm.dyn == "pv":
             a = bufs[gemm.src]                   # (BH, T, T) probabilities
-            _, _, w = split_qkv_heads(_tokens(bufs[gemm.dyn_src], batch),
-                                      gemm.heads)
+            _, _, w = _heads(gemm, bufs[gemm.dyn_src], batch)
         else:  # pragma: no cover - compile_network emits only qk/pv
             raise ValueError(gemm.dyn)
     softmax = any(p.kind == "softmax" for p in posts)
@@ -219,18 +248,33 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], bufs: Mapping,
     with jax.named_scope("epilogue"):
         zeros = jnp.zeros((w.shape[2],), jnp.float32)
 
+        # a windowed stage scales its scores where it adds the bias
+        # (``window_bias``), as the oracle's one expression does
+        post_scale = 0.0 if gemm.window else gemm.post_scale
+
         def epilogue(y, s, wm):
             ws = quantize_scale(wm, cfg.weight_bits)
             scale = (s * ws).astype(jnp.float32).reshape(1, 1)
             return kernels.epilogue(
-                y, scale, zeros, None, post_scale=gemm.post_scale,
+                y, scale, zeros, None, post_scale=post_scale,
                 block_m=block_m, block_n=block_n, interpret=interpret)
 
         out = jax.vmap(epilogue)(acc, ascale, wamax)
+        if gemm.window and gemm.dyn == "qk":
+            with jax.named_scope("relbias"):
+                out = window_bias(out * gemm.post_scale, st.rel_bias,
+                                  shift_mask(gemm.in_hw, gemm.window,
+                                             gemm.shift))
         if softmax:                    # XLA's softmax (module docstring)
             out = softmax_ordered(out)
         if gemm.dyn == "pv":           # heads rejoin the model dim, as rows
-            out = jax.lax.optimization_barrier(merge_heads(out, gemm.heads))
+            if gemm.window:
+                with jax.named_scope("unwindow"):
+                    out = window_merge(out, gemm.heads, gemm.in_hw,
+                                       gemm.window, gemm.shift)
+            else:
+                out = merge_heads(out, gemm.heads)
+            out = jax.lax.optimization_barrier(out)
             out = out.reshape(-1, out.shape[-1])
     return out, acc
 
@@ -288,13 +332,17 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
     """One weight-mounted GEMM stage + fused epilogue -> (dst, buffer,
     int32 GEMM result, whether the buffer is sequence rows)."""
     src = bufs[gemm.src]
-    t = 0
     if gemm.seq or gemm.select:
         with jax.named_scope("im2col"):
             src = _gemm_rows(src, True)      # (B*T, D) token rows
-            t = src.shape[0] // batch
             if gemm.select == "cls":         # the class token: row b*T
-                src = src[::t]
+                src = src[::src.shape[0] // batch]
+            elif gemm.select == "mean":      # the mean of each sequence
+                src = token_mean(_tokens(src, batch))
+            if gemm.merge:                   # (B*T/4, 4D) merged rows
+                with jax.named_scope("merge"):
+                    src = space_to_depth(_tokens(src, batch), gemm.in_hw)
+                    src = src.reshape(-1, src.shape[-1])
     if gemm.prenorm:
         if not gemm.seq:
             with jax.named_scope("im2col"):
@@ -321,8 +369,9 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
     y_int = kernels.gemm(xq, st.w8, adc_bits=cfg.adc_bits,
                          rows=gemm.tile_rows, block_m=block_m,
                          block_n=block_n, interpret=interpret, layout=layout)
-    act, pool, window, img_hw, norm = "none", "none", 0, 0, "none"
-    softmax, res, eps, embed, erf_gelu = False, None, LN_EPS, False, False
+    act, pool, window, img_hw = "none", "none", 0, 0
+    softmax, res, embed, erf_gelu = False, None, False, False
+    ln_eps = None                      # XLA tail (module docstring)
     out_hw = gemm.out_hw
     dst = posts[-1].dst if posts else gemm.dst
     for op in posts:
@@ -333,7 +382,7 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
         elif op.kind == "gelu":
             act = "gelu"
         elif op.kind == "layernorm":
-            norm, eps = "layer", op.eps
+            ln_eps = op.eps
         elif op.kind == "embed":
             embed = True
         elif op.kind == "residual":
@@ -341,8 +390,6 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
         elif op.kind in ("maxpool", "avgpool"):
             pool = "max" if op.kind == "maxpool" else "avg"
             window, img_hw, out_hw = op.window, op.in_hw, op.out_hw
-        elif op.kind == "seqpool":
-            pool, window = "seqmean", t
         elif op.kind == "softmax":
             softmax = True
         else:  # pragma: no cover - compile_network validates kinds
@@ -360,15 +407,20 @@ def _static_stage(gemm: ProgramOp, posts: list[ProgramOp],
             res = res.reshape(-1, res.shape[-1])
         out = kernels.epilogue(y_int, scale, st.bias, res, act=act,
                                pool=pool, window=window, img_hw=img_hw,
-                               softmax=softmax, norm=norm, gamma=st.ln_g,
-                               beta=st.ln_b, eps=eps, block_m=block_m,
+                               softmax=softmax, block_m=block_m,
                                block_n=block_n, interpret=interpret)
         # a sequence stage's (B*T, N) output is its buffer as it is
-        rows = embed or (gemm.seq and pool != "seqmean")
-        if gemm.is_conv and not embed:
+        # a patchify conv with an embed or a patch norm writes tokens
+        tokens_out = embed or ln_eps is not None
+        rows = tokens_out or gemm.seq
+        if gemm.is_conv and not tokens_out:
             out = out.reshape(batch, out_hw, out_hw, -1)
         if erf_gelu:
             out = gelu_erf(out)
+        if ln_eps is not None:         # per image, as a pre-norm
+            d = out.shape[-1]
+            out = layer_norm_ordered(_tokens(out, batch), st.ln_g, st.ln_b,
+                                     ln_eps).reshape(-1, d)
         if embed:          # the one place a sequence is built per image
             with jax.named_scope("embed"):
                 d = out.shape[-1]
@@ -420,7 +472,7 @@ def stage_outputs(packed: PackedProgram, x: jnp.ndarray, *,
         dst = posts[-1].dst if posts else gemm.dst
         with jax.named_scope(f"s{si:02d}.{dst.replace('@', '.')}"):
             if gemm.kind == "dyn_gemm":
-                out, acc = _dyn_stage(gemm, posts, src, cfg, batch,
+                out, acc = _dyn_stage(gemm, posts, st, src, cfg, batch,
                                       block_m=block_m, block_n=block_n,
                                       interpret=interpret, kernels=kernels)
                 rows = gemm.dyn == "pv"

@@ -42,7 +42,7 @@ verbatim.
 
 The result is a ``PackedProgram`` — a jax pytree whose leaves are the
 per-stage ``(w8, w_amax, bias[, ln_g, ln_b, pre_g, pre_b, emb_cls,
-emb_pos])`` arrays and whose static treedef carries the (plan-free)
+emb_pos, rel_bias])`` arrays and whose static treedef carries the (plan-free)
 program — that ``execute_packed`` consumes directly.
 ``PackedProgram.layouts()`` says which layout each stage took.
 Layer-norm FBs fused onto a stage carry their gamma/beta here too, as
@@ -50,7 +50,10 @@ do a stage's pre-norm (the layer norm of its input) and a patchify
 stage's class token and position table, so the packed executor never
 reads the float param pytree.  Dynamic-operand stages own no
 weights: they pack as empty placeholders (their mounts materialize per
-batch in the executor).  The hot loop then only quantizes *activations* (the
+batch in the executor), except that a windowed attention's Q·Kᵀ stage
+holds its relative position bias, gathered once from the
+``(2M-1)^2 x heads`` table to ``(heads, M², M²)``
+(``sequence.rel_bias``).  A bias-free GEMM packs a zero bias.  The hot loop then only quantizes *activations* (the
 data-dependent quantities) and dispatches kernels; no weight touches
 float math again.  Packing eagerly and quantizing under jit produce
 bit-identical planes: ``quantize_symmetric`` is abs/max/divide/round —
@@ -73,6 +76,7 @@ from repro.kernels.crossbar_gemm import (clip_possible, dense_layout,
                                         mount_layout)
 
 from .compile import CrossbarProgram, ProgramOp
+from .sequence import rel_bias
 
 
 @jax.tree_util.register_dataclass
@@ -92,7 +96,8 @@ class PackedStage:
     and ``emb_cls``/``emb_pos`` the class token and position table of
     an ``embed`` FB (each ``None`` where the stage has no such op).
     Dynamic-operand stages are empty placeholders (0-sized ``w8``):
-    their operands mount per batch in the executor.
+    their operands mount per batch in the executor; ``rel_bias`` is a
+    windowed Q·Kᵀ stage's gathered ``(heads, M², M²)`` position bias.
     """
 
     w8: jnp.ndarray
@@ -104,6 +109,7 @@ class PackedStage:
     pre_b: jnp.ndarray | None = None
     emb_cls: jnp.ndarray | None = None
     emb_pos: jnp.ndarray | None = None
+    rel_bias: jnp.ndarray | None = None
 
 
 def dyn_placeholder() -> PackedStage:
@@ -212,7 +218,12 @@ def pack_program(program: CrossbarProgram, params: dict) -> PackedProgram:
     stages = []
     for gemm, posts in program.stages():
         if gemm.kind == "dyn_gemm":
-            stages.append(dyn_placeholder())
+            st = dyn_placeholder()
+            if gemm.param:              # a windowed Q·Kᵀ stage
+                st = dataclasses.replace(st, rel_bias=rel_bias(
+                    params[gemm.param]["relb"].astype(jnp.float32),
+                    gemm.window))
+            stages.append(st)
             continue
         p = params[gemm.param]
         w8, amax = pack_weight(p[gemm.w_key], is_conv=gemm.is_conv,
@@ -226,7 +237,8 @@ def pack_program(program: CrossbarProgram, params: dict) -> PackedProgram:
             (o.param for o in posts if o.kind == "embed"), ""))
         stages.append(PackedStage(
             w8=w8, w_amax=amax,
-            bias=p[gemm.b_key].astype(jnp.float32),
+            bias=(p[gemm.b_key].astype(jnp.float32) if gemm.b_key
+                  else jnp.zeros((gemm.out_ch,), jnp.float32)),
             ln_g=ln.get("g"), ln_b=ln.get("b"),
             pre_g=pre.get("g"), pre_b=pre.get("b"),
             emb_cls=emb.get("cls"), emb_pos=emb.get("pos")))

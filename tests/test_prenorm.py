@@ -7,9 +7,10 @@ oracle, through every stage; its structure (the pre-norms
 on the stages they feed, the class token read by the head, the embed on
 the patch stage); a save -> load round trip; the builder's checks of
 the new ops; and the float tails the model adds: the exact GELU, which
-XLA evaluates after the epilogue kernel, and the layer norm's epsilon.
+XLA evaluates after the epilogue kernel, and a layer norm's epsilon.
 """
 
+import dataclasses
 import importlib.util
 import json
 import pathlib
@@ -25,7 +26,8 @@ from repro.api import HurryConfig, NetworkBuilder
 from repro.api.zoo import deit_graph, vit_tiny_graph
 from repro.core.crossbar import make_crossbar_matmul
 from repro.kernels import ref
-from repro.kernels.fb_epilogue import fb_epilogue, gelu_erf
+from repro.kernels.fb_epilogue import gelu_erf
+from repro.program.compile import ProgramOp
 from repro.program.execute import stage_outputs
 
 from bench import program_trace as pt
@@ -157,7 +159,7 @@ def test_deit_save_load_bit_identical(deit, tmp_path):
     with np.load(path, allow_pickle=False) as z:
         meta = json.loads(str(z["__meta__"][()]))
     n = SMALL["depth"]
-    assert meta["version"] == 6
+    assert meta["version"] == 7
     assert meta["embed_stages"] == [0]
     assert len(meta["pre_stages"]) == 2 * n + 1
     loaded = api.load(path)
@@ -168,9 +170,11 @@ def test_deit_save_load_bit_identical(deit, tmp_path):
 
 
 def test_version5_file_loads_with_post_norm_defaults(tmp_path):
-    """A version-5 file has none of version 6's fields: its layers and
-    ops take the defaults (no pre-norm, epsilon 1e-5, tanh GELU, mean
-    pool), which are what it meant, and it runs bit-identically."""
+    """A version-5 file has none of version 6's or 7's fields: its layers
+    and ops take the defaults (no pre-norm, epsilon 1e-5, tanh GELU,
+    mean pool, global attention, no merge, biases), which are what it
+    meant, its mean pool's seqpool op becomes the head's select, and it
+    runs bit-identically."""
     graph = vit_tiny_graph(depth=1, dim=32, heads=2, input_hw=8, patch=4)
     model = api.compile(graph, CLIP_FREE, seed=3)
     x = jax.random.normal(jax.random.PRNGKey(0), graph.input_shape(2))
@@ -179,12 +183,23 @@ def test_version5_file_loads_with_post_norm_defaults(tmp_path):
         meta = json.loads(str(z["__meta__"][()]))
         arrays = {k: z[k] for k in z.files if k != "__meta__"}
     meta["version"] = 5
-    del meta["pre_stages"], meta["embed_stages"]
+    del meta["pre_stages"], meta["embed_stages"], meta["bias_stages"]
+    # a version-5 program kept its mean pool as a seqpool op on the stage
+    # before the head, which read the pool's buffer
+    ops = meta["program"]["ops"]
+    head = next(i for i, op in enumerate(ops) if op["select"] == "mean")
+    pool = dataclasses.asdict(ProgramOp(
+        kind="seqpool", name="pool", src=ops[head]["src"], dst="pool",
+        out_ch=ops[head - 1]["out_ch"], seq=True))
+    ops[head]["src"] = "pool"
+    ops.insert(head, pool)
     for layer in meta["graph"]["layers"]:
-        for key in ("prenorm", "eps", "approx", "mode"):
+        for key in ("prenorm", "eps", "approx", "mode", "window", "shift",
+                    "merge", "bias"):
             del layer[key]
-    for op in meta["program"]["ops"]:
-        for key in ("prenorm", "eps", "approx", "select"):
+    for op in ops:
+        for key in ("prenorm", "eps", "approx", "select", "merge",
+                    "shift"):
             del op[key]
     old = str(tmp_path / "v5.npz")
     with open(old, "wb") as f:
@@ -300,24 +315,22 @@ def test_xla_tails_follow_the_epilogue_kernel(deit):
 
 @pytest.mark.parametrize("eps", [1e-6, 1e-5, 1e-2])
 def test_layer_norm_epsilon_reaches_the_kernel(eps):
-    """The epilogue's layer norm uses its static ``eps``: equal to the
-    reference at that epsilon, and apart from the default where the
-    rows' variance is small against it."""
-    key = jax.random.PRNGKey(0)
-    M, N = 16, 64
-    y = jax.random.randint(key, (M, N), -40, 40, dtype=jnp.int32)
-    scale = jnp.full((1, 1), 1e-3, jnp.float32)      # variance ~1e-4
-    bias = jnp.zeros((N,), jnp.float32)
-    g, b = jnp.ones((N,)), jnp.zeros((N,))
-    out = fb_epilogue(y, scale, bias, None, norm="layer", gamma=g, beta=b,
-                      eps=eps, interpret=True)
-    want = jax.jit(lambda *a: ref.fb_epilogue_ref(
-        *a, norm="layer", gamma=g, beta=b, eps=eps))(y, scale, bias, None)
-    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
-    default = fb_epilogue(y, scale, bias, None, norm="layer", gamma=g,
-                          beta=b, interpret=True)
-    assert np.array_equal(np.asarray(out), np.asarray(default)) == (
-        eps == 1e-5)
+    """A post-norm layer norm's static ``eps`` reaches the tail that
+    normalizes the epilogue kernel's output (XLA's, DESIGN.md §9): the
+    program equals the oracle at that epsilon, and departs from the
+    default where the rows' variance is small against it."""
+    def model(e):
+        nb = NetworkBuilder("ln_eps", input_seq_dim=64)
+        nb.linear(64, name="l")
+        nb.layernorm(eps=e, name="ln")
+        return api.compile(nb.build(), CLIP_FREE, seed=0)
+
+    x = 1e-2 * jax.random.normal(jax.random.PRNGKey(0), (2, 8, 64))
+    m = model(eps)
+    out = np.asarray(m.run(x))
+    np.testing.assert_array_equal(out, np.asarray(_oracle(m, x)))
+    default = np.asarray(model(1e-5).run(x))
+    assert np.array_equal(out, default) == (eps == 1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -12,8 +12,8 @@ sweep within clip-free int8 tolerance.
 Also covers: the dynamic-operand GEMM program structure (qk/pv stages,
 empty packed placeholders, runtime-sized mounts), a linear/gelu/
 layernorm/seqpool MLP net isolated from attention, builder sequence-
-mode validation, and the new fb_epilogue FB modes vs their unfused
-oracle.
+mode validation, the fb_epilogue GELU modes vs their unfused oracle,
+and the XLA route of the post-norm layer norm and the token mean.
 """
 
 import functools
@@ -29,7 +29,8 @@ from repro.api.serialize import VERSION
 from repro.api.zoo import vit_tiny
 from repro.core.crossbar import make_crossbar_matmul
 from repro.kernels import ref
-from repro.kernels.fb_epilogue import fb_epilogue
+from repro.kernels.fb_epilogue import (fb_epilogue, layer_norm_ordered,
+                                       ordered_sum, token_mean)
 from repro.kernels.flash_attention import flash_attention
 from repro.program.sequence import split_qkv_heads
 
@@ -69,7 +70,7 @@ def test_vit_tiny_bit_exact_and_roundtrip(tmp_path):
 
     path = model.save(str(tmp_path / "vit.npz"))
     meta_version = VERSION
-    assert meta_version == 6
+    assert meta_version == 7
     loaded = api.load(path)
     assert loaded.program.ops == model.program.ops
     assert loaded.program.has_dynamic_stages
@@ -178,12 +179,6 @@ def test_softmax_epilogue_stable_on_large_logits():
 @pytest.mark.parametrize("kw", [
     dict(act="gelu"),
     dict(act="gelu", post_scale=0.125),
-    dict(norm="layer"),
-    dict(act="gelu", norm="layer"),
-    dict(norm="layer", pool="seqmean", window=16),
-    dict(pool="seqmean", window=8),
-    dict(norm="layer", eps=1e-6),
-    dict(act="gelu", norm="layer", eps=1e-6),
 ])
 @pytest.mark.parametrize("with_res", [False, True])
 def test_fb_epilogue_sequence_modes_match_oracle(kw, with_res):
@@ -194,15 +189,66 @@ def test_fb_epilogue_sequence_modes_match_oracle(kw, with_res):
     bias = jax.random.normal(jax.random.PRNGKey(1), (N,), jnp.float32)
     res = (jax.random.normal(jax.random.PRNGKey(2), (M, N), jnp.float32)
            if with_res else None)
-    lnkw = {}
-    if kw.get("norm") == "layer":
-        lnkw = dict(
-            gamma=jax.random.normal(jax.random.PRNGKey(3), (N,)) + 1.0,
-            beta=jax.random.normal(jax.random.PRNGKey(4), (N,)))
-    out = fb_epilogue(y, scale, bias, res, interpret=True, **kw, **lnkw)
+    out = fb_epilogue(y, scale, bias, res, interpret=True, **kw)
     oracle = jax.jit(functools.partial(ref.fb_epilogue_ref, **kw)
-                     )(y, scale, bias, res, **lnkw)
+                     )(y, scale, bias, res)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(oracle))
+
+
+def _pairwise_np(x):
+    """The ordered row sum written apart, in numpy float32: halves added
+    pairwise, an odd column carried."""
+    x = np.asarray(x, np.float32)
+    while x.shape[-1] > 1:
+        h = x.shape[-1] // 2
+        x = np.concatenate([x[..., :h] + x[..., h:2 * h], x[..., 2 * h:]],
+                           axis=-1)
+    return x
+
+
+@pytest.mark.parametrize("kw", [
+    dict(eps=1e-5),
+    dict(act="gelu", eps=1e-5),
+    dict(eps=1e-5, tokens=16),
+    dict(tokens=8),
+    dict(eps=1e-6),
+    dict(act="gelu", eps=1e-6),
+], ids=["layernorm", "gelu_layernorm", "layernorm_mean", "mean",
+        "layernorm_eps1e-6", "gelu_layernorm_eps1e-6"])
+@pytest.mark.parametrize("with_res", [False, True])
+def test_ordered_sequence_tails_match_oracle(kw, with_res):
+    """A post-norm layer norm and the token mean run in XLA on the
+    epilogue kernel's output (DESIGN.md §9): their sums are the fixed
+    pairwise tree (a numpy float32 copy of it gives the same bits), and
+    the results are float64 math's to float32 rounding."""
+    M, N = 32, 48
+    y = jax.random.randint(jax.random.PRNGKey(0), (M, N), -20000, 20000,
+                           dtype=jnp.int32)
+    scale = jnp.array([[0.0123]], jnp.float32)
+    bias = jax.random.normal(jax.random.PRNGKey(1), (N,), jnp.float32)
+    res = (jax.random.normal(jax.random.PRNGKey(2), (M, N), jnp.float32)
+           if with_res else None)
+    g = 1.0 + jax.random.normal(jax.random.PRNGKey(3), (N,))
+    b = jax.random.normal(jax.random.PRNGKey(4), (N,))
+    x = np.asarray(fb_epilogue(y, scale, bias, res,
+                               act=kw.get("act", "none"), interpret=True))
+    got, want = x, x.astype(np.float64)
+    if "eps" in kw:
+        got = np.asarray(jax.jit(lambda v: layer_norm_ordered(
+            v, g, b, kw["eps"]))(got))
+        d = want - want.mean(-1, keepdims=True)
+        want = (d / np.sqrt((d * d).mean(-1, keepdims=True) + kw["eps"])
+                * np.asarray(g, np.float64) + np.asarray(b, np.float64))
+        np.testing.assert_array_equal(np.asarray(jax.jit(ordered_sum)(x)),
+                                      _pairwise_np(x))
+    if "tokens" in kw:
+        t = kw["tokens"]
+        seq = got.reshape(-1, t, N)
+        got = np.asarray(jax.jit(token_mean)(seq))
+        np.testing.assert_array_equal(
+            got, _pairwise_np(seq.swapaxes(1, 2))[..., 0] / np.float32(t))
+        want = want.reshape(-1, t, N).mean(axis=1)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -299,8 +345,11 @@ def test_builder_sequence_validation():
     nbc.conv(16, name="c1")
     with pytest.raises(ValueError, match="'g1'.*conv"):
         nbc.gelu(name="g1")
-    with pytest.raises(ValueError, match="'ln1'.*conv"):
-        nbc.layernorm(name="ln1")
+    # a layer norm may follow a patchify conv (the patch norm), not an fc
+    nbf = NetworkBuilder("bad_fc", input_hw=8, input_ch=3)
+    nbf.fc(16, name="f1")
+    with pytest.raises(ValueError, match="'ln1'.*fc"):
+        nbf.layernorm(name="ln1")
     nb = NetworkBuilder("bad", input_seq_dim=16)
     with pytest.raises(ValueError, match="'ln0'.*precedes any GEMM"):
         nb.layernorm(name="ln0")

@@ -4,9 +4,9 @@ The TPU compiler is installed even where no chip is attached, and it
 refuses what interpret mode accepts: K blocks off the 128-lane tiling,
 output blocks of fewer than 8 rows, 1-D operand blocks, tiles that
 overflow VMEM.  These tests compile the main path's kernels at real
-stage shapes, and three whole programs (DeiT-Ti at its published size
-among them), for one chip of a described ``v5e:2x2`` topology, and
-check that the Pallas kernels lowered to Mosaic (``tpu_custom_call``)
+stage shapes, and whole programs (DeiT-Ti at its published size and a
+depth-cut Swin-T among them), for one chip of a described ``v5e:2x2``
+topology, and check that the Pallas kernels lowered to Mosaic (``tpu_custom_call``)
 rather than to the interpreter.
 
 The topology is described inside a module-scoped fixture, never at
@@ -27,7 +27,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import api
-from repro.api.zoo import deit_graph, vit_tiny_graph
+from repro.api.zoo import deit_graph, swin_graph, vit_tiny_graph
 from repro.kernels.crossbar_gemm import (dense_layout, mount_layout,
                                         mounted_gemm)
 from repro.kernels.fb_epilogue import fb_epilogue
@@ -95,29 +95,27 @@ def test_dense_gemm_compiles(one_chip, k, n):
 
 
 # batches off the pooled modes' images-per-step count and past the
-# serving bucket ladder (256): padded by whole images or sequences
+# serving bucket ladder (256): padded by whole images
 ODD_BATCH_CASES = (
-    ("seqmean_t64_b300", 300 * 64, 192, dict(pool="seqmean", window=64,
-                                             norm="layer"), True, True),
+    ("maxpool_2to1_b301", 301 * 4, 512, dict(act="relu", pool="max",
+                                             window=2, img_hw=2), True),
     ("avgpool_4x4_b301", 301 * 16, 512, dict(act="relu", pool="avg",
-                                             window=4, img_hw=4), True, False),
+                                             window=4, img_hw=4), True),
     ("maxpool_4to2_b301", 301 * 16, 512, dict(act="relu", pool="max",
-                                              window=2, img_hw=4), False,
-     False),
+                                              window=2, img_hw=4), False),
 )
 
 
 @pytest.mark.parametrize("case", smoke.EPILOGUE_CASES + ODD_BATCH_CASES,
                          ids=lambda c: c[0])
 def test_fb_epilogue_compiles(one_chip, case):
-    _, m, n, kw, has_res, has_ln = case
+    _, m, n, kw, has_res = case
     f32 = jnp.float32
-    vec = _shape(one_chip, (n,), f32) if has_ln else None
     res = _shape(one_chip, (m, n), f32) if has_res else None
-    _compile(lambda y, s, b, r, g, bt: fb_epilogue(
-        y, s, b, r, gamma=g, beta=bt, interpret=False, **kw),
-        _shape(one_chip, (m, n), jnp.int32), _shape(one_chip, (1, 1), f32),
-        _shape(one_chip, (n,), f32), res, vec, vec)
+    _compile(lambda y, s, b, r: fb_epilogue(y, s, b, r, interpret=False,
+                                            **kw),
+             _shape(one_chip, (m, n), jnp.int32),
+             _shape(one_chip, (1, 1), f32), _shape(one_chip, (n,), f32), res)
 
 
 # net -> (graph, batch) of its whole-program compile
@@ -125,7 +123,10 @@ WHOLE = {"resnet18": (lambda: "resnet18", 8),
          "vit_tiny": (lambda: vit_tiny_graph(depth=2), 8),
          "deit_ti": (deit_graph, 64),      # the benchmark cell's batch
          # DeiT-shaped and small: 197 tokens, width 192, 3 heads, 2 blocks
-         "deit_ti_depth2": (lambda: deit_graph(depth=2), 2)}
+         "deit_ti_depth2": (lambda: deit_graph(depth=2), 2),
+         # Swin-T at 224x224 with one block in stages 2-4: every kind of
+         # stage (shifted and one-window attention, three merges)
+         "swin_t_cut": (lambda: swin_graph(depths=(2, 1, 1, 1)), 2)}
 
 
 @pytest.fixture(scope="module")
@@ -191,6 +192,27 @@ def test_deit_program_lowers_its_new_modes(whole_program):
         assert re.search(rf' erf\(.*op_name="[^"]*/s\d+\.{name}/epilogue/',
                          text), name
     assert "/prenorm/" in text and "/embed/" in text
+
+
+def test_swin_program_lowers_its_windows(whole_program):
+    """Swin-T cut to depths (2, 1, 1, 1), batch 2: 35 stages, 10 of them
+    dynamic, one ``mounted_gemm`` and one ``fb_epilogue`` custom call
+    each; the window partition, bias and mask, window reverse and merge
+    named in their sub-scopes (``window``, ``relbias``, ``unwindow``,
+    ``merge``), and the patch norm an XLA tail of the patch stage."""
+    model, text = whole_program("swin_t_cut")
+    stages = model.program.stages()
+    assert len(stages) == 35
+    assert sum(g.kind == "dyn_gemm" for g, _ in stages) == 10
+    for kernel in ("mounted_gemm", "fb_epilogue"):
+        calls = re.findall(rf"^\s*(?:ROOT )?%{kernel}(?:\.\d+)? = .*"
+                           r"custom-call\(", text, re.M)
+        assert len(calls) == len(stages), kernel
+    for sub in ("im2col/window", "epilogue/relbias", "epilogue/unwindow",
+                "im2col/merge"):
+        assert f"/{sub}/" in text, sub
+    assert re.search(r' sqrt\(.*op_name="[^"]*/s00\.patch_norm/epilogue/',
+                     text)
 
 
 def test_deit_linear_stages_run_edge_blocks_unpadded(whole_program):
