@@ -243,8 +243,10 @@ class NetworkGraph:
                 scores = scores * attn_scale(d // l.heads)
                 if l.window:
                     scores = window_bias(
-                        scores, rel_bias(p["relb"], l.window),
-                        shift_mask(l.in_hw, l.window, l.shift))
+                        scores.reshape(b, -1, *scores.shape[1:]),
+                        rel_bias(p["relb"], l.window),
+                        shift_mask(l.in_hw, l.window, l.shift)
+                    ).reshape(scores.shape)
                 probs = softmax_ordered(scores)
                 ctx = jax.vmap(mm)(probs, v)
                 ctx = (window_merge(ctx, *win) if l.window

@@ -95,16 +95,22 @@ def gelu_erf(x: jnp.ndarray) -> jnp.ndarray:
     return 0.5 * x * (1.0 + jax.lax.erf(x * 0.7071067811865476))
 
 
-def ordered_sum(x: jnp.ndarray) -> jnp.ndarray:
-    """Sum over the last axis (kept) in one fixed pairwise order, as
-    elementwise adds of its halves.  XLA rounds an elementwise add the
-    same in any fusion and layout, while the order of its ``reduce``
-    follows the layout it picks for the operand: a row sum of an XLA
-    tail (below) must round as the reference's does (DESIGN.md §9)."""
-    while x.shape[-1] > 1:
-        h = x.shape[-1] // 2
-        y = x[..., :h] + x[..., h:2 * h]
-        x = jnp.concatenate([y, x[..., 2 * h:]], axis=-1)
+def ordered_sum(x: jnp.ndarray, axis: int = -1) -> jnp.ndarray:
+    """Sum over ``axis`` (kept) in one fixed pairwise order, as
+    elementwise adds of its halves, an odd last entry carried after
+    them.  XLA rounds an elementwise add the same in any fusion and
+    layout, while the order of its ``reduce`` follows the layout it
+    picks for the operand: a row sum of an XLA tail (below) must round
+    as the reference's does (DESIGN.md §9).  The axis only says where
+    the summed entries lie: every axis adds the same pairs in the same
+    order, so the sums are bit-identical."""
+    while x.shape[axis] > 1:
+        n = x.shape[axis]
+        h = n // 2
+        y = (jax.lax.slice_in_dim(x, 0, h, axis=axis)
+             + jax.lax.slice_in_dim(x, h, 2 * h, axis=axis))
+        x = jnp.concatenate([y, jax.lax.slice_in_dim(x, 2 * h, n, axis=axis)],
+                            axis=axis)
     return x
 
 

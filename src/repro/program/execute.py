@@ -105,6 +105,29 @@ as rows, and yields every ``StageOutput.value`` in the graph's shape
 (under a jit only the program's output is live, so those reshapes
 compile away).
 
+**Attention's scores tail runs keys-major** (``_probs``) where the keys
+fill under one 128-lane tile.  The epilogue kernel writes a Q·Kᵀ
+stage's scores as ``(mounts, q, k)``, the keys on the lanes: at Swin's
+49 keys every op after it, and each level of the ordered row sum (24,
+12, 6, ... keys wide), would write whole 128-lane tiles, most of them
+padding.  So the scores move once, in f32, to ``(k, q, mounts)``, the
+mounts dense on the lanes, where the row max, ``exp`` and the
+``ordered_sum`` tree over the leading axis run; ``exp`` and its row
+sums move back, and the divide runs in the kernel's layout.  There XLA
+folds it into the P·V stage's quantize, ``e / (sum * scale)``, as it
+does in the oracle: a quotient moved on its own would round apart from
+it (PERF.md §6).  Layout constraints hold both layouts (left free, XLA
+lays each transpose out as a bitcast of the kernel's output, the keys
+on the lanes again).  A windowed stage adds its scale, bias and mask
+before the move, in the kernel's layout, on the per-image view ``(B,
+nW*heads, q, k)`` behind an ``optimization_barrier``: each table is
+then added as one image's tile across the images (without the barrier
+XLA moves the view's reshape past the adds and writes both tables out
+for every mount).  Keys that fill a lane tile (DeiT-Ti's 197) stay on
+the lanes, where the two moves cost more than the levels save (PERF.md
+§6).  Each op, its values and the order of every float sum are the
+oracle's; only where the data lies changes.
+
 Intermediate buffers are dropped as soon as no later stage reads them
 (``src``, ``dyn_src`` or ``res_src``), so an eager forward holds the
 live frontier of the dataflow graph, not every activation.
@@ -156,13 +179,14 @@ from typing import Callable, Iterator, Mapping, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core.conv import im2col, im2col_read_mask
 from repro.core.crossbar import quantize_scale, quantize_symmetric
-from repro.kernels.crossbar_gemm import mounted_gemm
+from repro.kernels.crossbar_gemm import LANE, mounted_gemm
 from repro.kernels.fb_epilogue import (fb_epilogue, gelu_erf,
-                                       layer_norm_ordered, softmax_ordered,
-                                       token_mean)
+                                       layer_norm_ordered, ordered_sum,
+                                       softmax_ordered, token_mean)
 from repro.kernels.ops import interpret_default
 
 from .compile import ProgramOp
@@ -260,13 +284,8 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], st: PackedStage,
                 block_m=block_m, block_n=block_n, interpret=interpret)
 
         out = jax.vmap(epilogue)(acc, ascale, wamax)
-        if gemm.window and gemm.dyn == "qk":
-            with jax.named_scope("relbias"):
-                out = window_bias(out * gemm.post_scale, st.rel_bias,
-                                  shift_mask(gemm.in_hw, gemm.window,
-                                             gemm.shift))
         if softmax:                    # XLA's softmax (module docstring)
-            out = softmax_ordered(out)
+            out = _probs(gemm, st, out, batch)
         if gemm.dyn == "pv":           # heads rejoin the model dim, as rows
             if gemm.window:
                 with jax.named_scope("unwindow"):
@@ -277,6 +296,36 @@ def _dyn_stage(gemm: ProgramOp, posts: list[ProgramOp], st: PackedStage,
             out = jax.lax.optimization_barrier(out)
             out = out.reshape(-1, out.shape[-1])
     return out, acc
+
+
+def _row_major(x: jnp.ndarray) -> jnp.ndarray:
+    """``x`` laid out row-major: its last axis on the lanes."""
+    return with_layout_constraint(x, Layout(tuple(range(x.ndim))))
+
+
+def _probs(gemm: ProgramOp, st: PackedStage, scores: jnp.ndarray,
+           batch: int) -> jnp.ndarray:
+    """A Q·Kᵀ stage's (mounts, q, k) scores -> its probabilities, in the
+    same form: a windowed stage's scale, bias and mask, then
+    ``softmax_ordered`` over the keys, its max, ``exp`` and row sum
+    keys-major where the keys fill under one lane tile (module
+    docstring)."""
+    m, q, k = scores.shape
+    if gemm.window:
+        with jax.named_scope("relbias"):
+            s = jax.lax.optimization_barrier(scores.reshape(batch, -1, q, k))
+            s = window_bias(s * gemm.post_scale, st.rel_bias,
+                            shift_mask(gemm.in_hw, gemm.window, gemm.shift))
+            scores = _row_major(s).reshape(m, q, k)
+    if k >= LANE:
+        return softmax_ordered(scores)
+    x = _row_major(jnp.transpose(scores, (2, 1, 0)))        # (k, q, mounts)
+    e = jnp.exp(x - jnp.max(x, axis=0, keepdims=True))
+
+    def back(v):
+        return jnp.transpose(_row_major(v), (2, 1, 0))
+
+    return back(e) / back(ordered_sum(e, 0))
 
 
 def _tokens(x: jnp.ndarray, batch: int) -> jnp.ndarray:

@@ -171,15 +171,16 @@ def window_merge(ctx: jnp.ndarray, heads: int, grid: int, window: int,
 
 def window_bias(scores: jnp.ndarray, bias: jnp.ndarray,
                 mask: np.ndarray | None) -> jnp.ndarray:
-    """Scaled (B*nW*heads, M², M²) scores plus the relative position
-    bias (heads, M², M²), then plus the shift mask (nW, M², M²): two
-    adds, in that order (DESIGN.md §9)."""
-    heads, m2, _ = bias.shape
-    nw = 1 if mask is None else mask.shape[0]
-    s = scores.reshape(-1, nw, heads, m2, m2) + bias
+    """Scaled scores of each image's windows and heads, (B, nW*heads,
+    M², M²), plus the relative position bias (heads, M², M²), then plus
+    the shift mask (nW, M², M²): two adds, in that order (DESIGN.md §9).
+    Both tables are tiled to one image's windows and heads and added
+    across the images, a broadcast over a major axis only."""
+    heads = bias.shape[0]
+    s = scores + jnp.tile(bias, (scores.shape[1] // heads, 1, 1))
     if mask is not None:
-        s = s + mask[:, None]
-    return s.reshape(scores.shape)
+        s = s + np.repeat(mask, heads, axis=0)
+    return s
 
 
 def space_to_depth(x: jnp.ndarray, grid: int) -> jnp.ndarray:

@@ -17,6 +17,7 @@ and the XLA route of the post-norm layer norm and the token mean.
 """
 
 import functools
+import types
 
 import jax
 import jax.numpy as jnp
@@ -30,9 +31,12 @@ from repro.api.zoo import vit_tiny
 from repro.core.crossbar import make_crossbar_matmul
 from repro.kernels import ref
 from repro.kernels.fb_epilogue import (fb_epilogue, layer_norm_ordered,
-                                       ordered_sum, token_mean)
+                                       ordered_sum, softmax_ordered,
+                                       token_mean)
 from repro.kernels.flash_attention import flash_attention
-from repro.program.sequence import split_qkv_heads
+from repro.program import execute
+from repro.program.sequence import (attn_scale, shift_mask, split_qkv_heads,
+                                    window_bias)
 
 CLIP_FREE = HurryConfig(array_rows=511)      # DESIGN.md §4 predicate holds
 
@@ -249,6 +253,57 @@ def test_ordered_sequence_tails_match_oracle(kw, with_res):
             got, _pairwise_np(seq.swapaxes(1, 2))[..., 0] / np.float32(t))
         want = want.reshape(-1, t, N).mean(axis=1)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 49, 197])
+def test_ordered_sum_over_a_leading_axis_is_the_last_axis_sum(n):
+    """``ordered_sum`` over the leading axis of a keys-major array adds the
+    same pairs in the same order as over the last axis of the same
+    values: bit for bit, jitted, and as the numpy float32 tree."""
+    x = jnp.exp(3.0 * jax.random.normal(jax.random.PRNGKey(n), (5, 7, n)))
+    last = np.asarray(jax.jit(ordered_sum)(x))[..., 0]
+    lead = jax.jit(functools.partial(ordered_sum, axis=0))(
+        jnp.transpose(x, (2, 0, 1)))
+    assert lead.shape == (1, 5, 7)
+    np.testing.assert_array_equal(np.asarray(lead)[0], last)
+    np.testing.assert_array_equal(last, _pairwise_np(x)[..., 0])
+
+
+# name -> (images, heads, grid, window, shift); no window: DeiT-Ti's
+# (B*heads, 197, 197) scores
+KEYS_MAJOR_TAILS = {"swin_shifted": (2, 3, 14, 7, 3),
+                    "swin_unshifted": (2, 3, 14, 7, 0),
+                    "deit": (2, 3, 0, 0, 0)}
+
+
+@pytest.mark.parametrize("case", sorted(KEYS_MAJOR_TAILS))
+def test_keys_major_tail_is_softmax_ordered(case):
+    """The executor's scores tail (``execute._probs``: keys-major at 49
+    keys, on the lanes at DeiT's 197) gives the oracle's probabilities
+    bit for bit: a windowed stage's scale, relative bias and shift mask,
+    then ``softmax_ordered`` over the last axis, both jitted."""
+    b, heads, grid, window, shift = KEYS_MAJOR_TAILS[case]
+    t = window * window if window else 197
+    mounts = b * heads * ((grid // window) ** 2 if window else 1)
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 2)
+    scores = 4.0 * jax.random.normal(keys[0], (mounts, t, t))
+    bias = (0.1 * jax.random.normal(keys[1], (heads, t, t)) if window
+            else None)
+    gemm = types.SimpleNamespace(window=window, shift=shift, in_hw=grid,
+                                 post_scale=attn_scale(32))
+    got = jax.jit(lambda s: execute._probs(
+        gemm, types.SimpleNamespace(rel_bias=bias), s, b))(scores)
+
+    def oracle(s):
+        if window:
+            s = window_bias((s * gemm.post_scale).reshape(b, -1, t, t),
+                            bias, shift_mask(grid, window, shift)
+                            ).reshape(s.shape)
+        return softmax_ordered(s)
+
+    assert got.shape == scores.shape
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(jax.jit(oracle)(scores)))
 
 
 # ---------------------------------------------------------------------------

@@ -215,6 +215,122 @@ def test_swin_program_lowers_its_windows(whole_program):
                      text)
 
 
+# an f32 instruction: name, dtype, dims, layout (minor to major), op_name
+_F32 = re.compile(r'\s*(?:ROOT )?%(\S+) = (f32)\[([\d,]*)\]\{([\d,]*)[^}]*\}'
+                  r'\S* ([\w-]+)\(.*op_name="([^"]*)"')
+
+
+def test_swin_scores_tail_runs_keys_major(whole_program):
+    """Each windowed Q·Kᵀ stage's XLA tail runs with the keys on a major
+    axis (DESIGN.md §9): no f32 level of the ordered row sum over the 49
+    keys (24, 12, 6, 3 and 2 wide, 25, 13, 7 and 4 with the carry), in
+    any computation of the program, has the key axis on the lanes, as
+    ``f32[mounts,49,24]{2,1,0}`` had it, each level padded to 128 lanes;
+    and no broadcast of the relative bias or the shift mask to all the
+    stage's mounts is left in the entry computation: a table is tiled to
+    one image's windows and heads at most."""
+    model, text = whole_program("swin_t_cut")
+    batch = WHOLE["swin_t_cut"][1]
+    mounts = {f"s{si:02d}.{posts[-1].dst}".replace("@", "."):
+              batch * (g.in_hw // g.window) ** 2 * g.heads
+              for si, (g, posts) in enumerate(model.program.stages())
+              if g.kind == "dyn_gemm" and g.dyn == "qk"}
+    assert len(mounts) == 5 and all(s.endswith(".probs") for s in mounts)
+    levels = {24, 12, 6, 3, 2, 25, 13, 7, 4}
+    tails = 0
+    for line in text.splitlines():
+        m = _F32.match(line)
+        if not m or "/epilogue/" not in m.group(6):
+            continue
+        stage = m.group(6).split("/epilogue/")[0].rsplit("/", 1)[-1]
+        if stage not in mounts or "/relbias/" in m.group(6):
+            continue
+        dims = [int(d) for d in m.group(3).split(",") if d]
+        if len(dims) == 3 and 49 in dims and levels & set(dims):
+            tails += 1
+            minor = dims[int(m.group(4).split(",")[0])]
+            assert minor not in levels, line
+    assert tails >= 5 * 5          # five levels at least in each stage
+    entry = text[text.index("\nENTRY "):]
+    for line in entry[:entry.index("\n}\n")].splitlines():
+        m = _F32.match(line)
+        if not m or m.group(5) != "broadcast":
+            continue
+        stage = m.group(6).split("/epilogue/")[0].rsplit("/", 1)[-1]
+        if stage in mounts:
+            dims = [int(d) for d in m.group(3).split(",") if d]
+            assert math.prod(dims) <= mounts[stage] * 49 * 49 // batch, line
+
+
+# an HLO instruction: name, shape, opcode, operands, the rest of the line
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w-]+)\((.*?)\)(.*)")
+
+
+def _instrs(lines: str) -> dict:
+    """name -> (shape, opcode, operand names, op_name) of each line."""
+    out = {}
+    for m in map(_INSTR.match, lines.splitlines()):
+        if m:
+            scope = re.search(r'op_name="([^"]*)"', m.group(5))
+            out[m.group(1)] = (m.group(2), m.group(3),
+                               re.findall(r"%([\w.\-]+)", m.group(4)),
+                               scope.group(1) if scope else "")
+    return out
+
+
+def test_swin_probabilities_fold_into_the_pv_quantize(whole_program):
+    """XLA folds the softmax's divide by the row sum into the P·V stage's
+    quantize, ``e / (sum * scale)``, in the oracle as in the program, so
+    the probabilities are never rounded on their own.  The keys-major
+    tail moves ``e`` and its row sums back, not their quotient, and
+    leaves the divide where the fold reaches it: each windowed P·V
+    stage's int8 operand divides the ``.probs`` stage's ``exp`` by the
+    product of its row sums (one per mount and query) and the P·V
+    stage's per-mount quantize scale."""
+    _, text = whole_program("swin_t_cut")
+    batch = WHOLE["swin_t_cut"][1]
+    entry = text[text.index("\nENTRY "):]
+    entry = _instrs(entry[:entry.index("\n}\n")])
+    builds = [(name, shape, args) for name, (shape, op, args, _)
+              in entry.items() if op == "fusion"
+              and re.match(r"s8\[\d+,49,49\]", shape)]
+    mounts = sorted(int(re.match(r"s8\[(\d+)", s).group(1)) // batch
+                    for _, s, _ in builds)
+    assert mounts == [24, 48, 96, 192, 192]
+    for name, shape, args in builds:
+        m = int(re.match(r"s8\[(\d+)", shape).group(1))
+        called = re.search(r"calls=%([\w.\-]+)", text[
+            text.index(f"%{name} = "):].split("\n", 1)[0]).group(1)
+        body = text[text.index(f"\n%{called} "):]
+        body = _instrs(body[:body.index("\n}\n")])
+
+        def source(n):
+            """The entry operand a fusion value is read from: through
+            broadcasts and bitcasts to the fusion's parameter."""
+            while body[n][1] in ("broadcast", "bitcast"):
+                n = body[n][2][0]
+            assert body[n][1] == "parameter", (called, n)
+            k = int(re.search(rf"%{re.escape(n)} = .* parameter\((\d+)\)",
+                              text).group(1))
+            return entry[args[k]]
+
+        divides = [v for v in body.values() if v[1] == "divide"]
+        assert len(divides) == 1, called
+        exp, prod = divides[0][2]
+        assert body[prod][1] == "multiply", called
+        scale, sums = sorted((source(a) for a in body[prod][2]),
+                             key=lambda v: v[0].split("]")[0].count(","))
+        dividend = source(exp)
+        probs = dividend[3].split("/epilogue/")[0]
+        dims = re.match(r"f32\[([\d,]+)\]", dividend[0]).group(1)
+        assert probs.endswith("_attn.probs") and math.prod(
+            int(d) for d in dims.split(",")) == m * 49 * 49, dividend
+        assert sums[3].startswith(probs + "/epilogue/"), sums
+        assert sums[0].startswith(f"f32[{m},49]"), sums
+        ctx = probs.removesuffix(".probs").rsplit(".", 1)[-1] + ".ctx/quantize/"
+        assert ctx in scale[3] and scale[0].startswith(f"f32[{m}]"), scale
+
+
 def test_deit_linear_stages_run_edge_blocks_unpadded(whole_program):
     """DeiT's M = 394 token rows and N = 576, 768 and 192 divide no
     block: both kernels take a linear stage's operands as they are (edge
